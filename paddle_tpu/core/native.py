@@ -9,14 +9,19 @@ The XLA compute path never goes through here — jax owns device memory and
 kernels; this library is host-side runtime only (threadpool, channels, file
 parsing/shuffle/batch assembly, stats, host trace events).
 
-The library is built lazily with `make -C native` (g++ is in the image); if
-the toolchain or build fails, `available()` is False and callers fall back to
-pure-Python implementations.
+The library is a function of the committed sources: it is (re)built with
+`make -C native` whenever `native/build/` (git-ignored) is missing or older
+than any file under `native/src`, `native/include` or the Makefile.  Where
+there is no toolchain (`make`/`g++` absent) `available()` is False and
+callers use their pure-Python implementations; a build that *fails* raises
+with the compiler's output.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import shutil
 import subprocess
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -29,24 +34,46 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libpaddle_tpu_native.so")
 
 _lib = None
 _lib_lock = threading.Lock()
-_build_attempted = False
 
 
-def _try_build() -> bool:
-    global _build_attempted
-    if _build_attempted:
-        return os.path.exists(_LIB_PATH)
-    _build_attempted = True
-    if os.path.exists(_LIB_PATH):
-        return True
+def _sources_mtime() -> float:
+    newest = os.path.getmtime(os.path.join(_NATIVE_DIR, "Makefile"))
+    for sub in ("src", "include"):
+        for root, _dirs, files in os.walk(os.path.join(_NATIVE_DIR, sub)):
+            for f in files:
+                newest = max(newest, os.path.getmtime(os.path.join(root, f)))
+    return newest
+
+
+def _lib_current() -> bool:
+    return (os.path.exists(_LIB_PATH)
+            and os.path.getmtime(_LIB_PATH) >= _sources_mtime())
+
+
+def _ensure_built() -> bool:
+    """Bring the .so up to date with the sources; False only where it
+    cannot be built at all (no native tree, no toolchain)."""
     if not os.path.isdir(_NATIVE_DIR):
         return False
-    try:
-        subprocess.run(["make", "-C", _NATIVE_DIR, "-j4"], check=True,
-                       capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
+    if _lib_current():
+        return True
+    if not (shutil.which("make") and shutil.which(os.environ.get("CXX",
+                                                                  "g++"))):
         return False
-    return os.path.exists(_LIB_PATH)
+    build_dir = os.path.dirname(_LIB_PATH)
+    os.makedirs(build_dir, exist_ok=True)
+    # one builder at a time: tests and launch workers import concurrently
+    with open(os.path.join(build_dir, ".lock"), "w") as lk:
+        fcntl.flock(lk, fcntl.LOCK_EX)
+        if _lib_current():
+            return True
+        proc = subprocess.run(["make", "-C", _NATIVE_DIR, "-j4"],
+                              capture_output=True, text=True, timeout=600)
+    if proc.returncode != 0 or not os.path.exists(_LIB_PATH):
+        raise RuntimeError(
+            f"`make -C {_NATIVE_DIR}` failed (exit {proc.returncode}):\n"
+            + proc.stdout[-2000:] + proc.stderr[-4000:])
+    return True
 
 
 def _declare(lib: ctypes.CDLL) -> None:
@@ -66,14 +93,8 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.pt_prof_export_chrome.restype = c.c_int
     lib.pt_prof_summary.argtypes = [c.c_char_p, c.c_int]
     lib.pt_prof_summary.restype = c.c_int
-    try:
-        # newer symbol; a stale prebuilt .so may lack it — prof_summary
-        # falls back to the unsorted export in that case
-        lib.pt_prof_summary_sorted.argtypes = [c.c_char_p, c.c_char_p,
-                                               c.c_int]
-        lib.pt_prof_summary_sorted.restype = c.c_int
-    except AttributeError:
-        pass
+    lib.pt_prof_summary_sorted.argtypes = [c.c_char_p, c.c_char_p, c.c_int]
+    lib.pt_prof_summary_sorted.restype = c.c_int
 
     lib.pd_aes_ctr_crypt.argtypes = [c.c_char_p, c.c_int, c.c_char_p,
                                      c.POINTER(c.c_uint8), c.c_longlong]
@@ -108,13 +129,10 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not _try_build():
+        if not _ensure_built():
             return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            _declare(lib)
-        except OSError:
-            return None
+        lib = ctypes.CDLL(_LIB_PATH)
+        _declare(lib)
         _lib = lib
         return _lib
 
@@ -239,12 +257,8 @@ def prof_summary(sorted_key: Optional[str] = None) -> str:
     lib = get_lib()
     if lib is None:
         return ""
-    sorter = getattr(lib, "pt_prof_summary_sorted", None)
-    if sorter is not None:
-        key = (sorted_key or "total").encode()
-        fill = lambda buf, n: sorter(key, buf, n)  # noqa: E731
-    else:  # stale .so without the sorted entry point
-        fill = lib.pt_prof_summary
+    key = (sorted_key or "total").encode()
+    fill = lambda buf, n: lib.pt_prof_summary_sorted(key, buf, n)  # noqa: E731
     # Same grow-and-retry as stat_list: events can land between the size
     # query and the fill.
     need = fill(None, 0)

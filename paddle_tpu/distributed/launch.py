@@ -18,7 +18,10 @@ mesh).  The launcher therefore:
   * spawns and babysits the children: first failure kills the rest (the
     reference's watch loop), exit codes propagate.
 Multi-process-per-localhost remains supported for CPU simulation tests
-(the reference's own distributed tests run 2 trainers on 127.0.0.1).
+(the reference's own distributed tests run 2 trainers on 127.0.0.1).  On a
+TPU host it is refused: a chip belongs to one process, every local worker
+inherits the same environment and would try to take every chip, and the
+launcher itself never touches JAX for the same reason.
 """
 from __future__ import annotations
 
@@ -39,6 +42,22 @@ def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
+
+
+def _workers_take_tpu(env) -> bool:
+    """Whether a worker started with ``env`` would initialise the TPU
+    backend.  ``JAX_PLATFORMS`` without "tpu" answers it outright (every
+    CPU simulation); otherwise a throw-away child is asked, so the
+    launcher never holds the chip its workers need.  A probe that fails to
+    start JAX at all answers False — the workers then fail with JAX's own
+    message."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        env=env, capture_output=True, text=True, timeout=300)
+    return probe.returncode == 0 and probe.stdout.strip().endswith("tpu")
 
 
 def launch(training_script: str, script_args: List[str],
@@ -104,7 +123,7 @@ def launch(training_script: str, script_args: List[str],
     exit_code = 0
     restart_counts = {rank: 0 for rank in range(nproc)}
 
-    def _spawn(rank: int) -> subprocess.Popen:
+    def _rank_env(rank: int) -> dict:
         env = dict(os.environ)
         env.update({
             "PADDLE_TRAINER_ID": str(rank),
@@ -129,6 +148,19 @@ def launch(training_script: str, script_args: List[str],
             if "=" in kv:
                 k, v = kv.split("=", 1)
                 env[k] = v
+        return env
+
+    if nproc > 1 and _workers_take_tpu(_rank_env(0)):
+        raise RuntimeError(
+            f"launch: nproc={nproc} on a TPU host would start {nproc} "
+            "processes that each try to take every local chip, and a chip "
+            "belongs to one process.  The process unit is one per host "
+            "(in-process SPMD over the mesh drives all local chips): use "
+            "nproc=1 here, or --backend_env JAX_PLATFORMS=cpu for a CPU "
+            "simulation.")
+
+    def _spawn(rank: int) -> subprocess.Popen:
+        env = _rank_env(rank)
         cmd = [sys.executable, "-u", training_script] + list(script_args)
         if log_dir:
             # append so a restarted rank's output lands after its crash log

@@ -26,14 +26,6 @@ def create(model_path: str) -> int:
     """Load a model package; returns an opaque handle for run()."""
     import os
 
-    import jax
-
-    platform = os.environ.get("JAX_PLATFORMS")
-    if platform:
-        try:
-            jax.config.update("jax_platforms", platform)
-        except RuntimeError:
-            pass  # backend already initialized by the host process
     import paddle_tpu.static as static
 
     exe = static.Executor()

@@ -42,8 +42,51 @@ def selu(x):
     return jax.nn.selu(x)
 
 
+# GELU carries its own derivative rule.  Left to autodiff, exact GELU keeps
+# three FFN-wide residuals (x/sqrt2, the cdf, x) next to its output; under
+# lax.scan over stacked blocks those are materialized per layer between the
+# forward and backward loops — at ERNIE-base b64 x s512 that was 4 x
+# bf16[12,64,512,3072] = 9 GB and the step did not fit a 16 GB chip
+# (CHANGES PR 21).  With the rule below the only residual is gelu'(x), one
+# array, computed in the same elementwise pass as the forward.  custom_jvp
+# (not custom_vjp): forward mode, vmap and jax.checkpoint still compose.
+_SQRT_HALF = 0.7071067811865476
+_INV_SQRT_2PI = 0.3989422804014327
+_SQRT_2_OVER_PI = 0.7978845608028654
+_TANH_C = 0.044715
+
+
+@jax.custom_jvp
+def _gelu_exact(x):
+    return jax.nn.gelu(x, approximate=False)
+
+
+@_gelu_exact.defjvp
+def _gelu_exact_jvp(primals, tangents):
+    (x,), (t,) = primals, tangents
+    xf = x.astype(jnp.float32)
+    cdf = 0.5 * (1.0 + jax.lax.erf(xf * _SQRT_HALF))
+    pdf = jnp.exp(-0.5 * xf * xf) * _INV_SQRT_2PI
+    return _gelu_exact(x), t * (cdf + xf * pdf).astype(t.dtype)
+
+
+@jax.custom_jvp
+def _gelu_tanh(x):
+    return jax.nn.gelu(x, approximate=True)
+
+
+@_gelu_tanh.defjvp
+def _gelu_tanh_jvp(primals, tangents):
+    (x,), (t,) = primals, tangents
+    xf = x.astype(jnp.float32)
+    th = jnp.tanh(_SQRT_2_OVER_PI * (xf + _TANH_C * xf ** 3))
+    du = _SQRT_2_OVER_PI * (1.0 + 3.0 * _TANH_C * xf * xf)
+    grad = 0.5 * (1.0 + th) + 0.5 * xf * (1.0 - th * th) * du
+    return _gelu_tanh(x), t * grad.astype(t.dtype)
+
+
 def gelu(x, approximate=False):
-    return jax.nn.gelu(x, approximate=approximate)
+    return _gelu_tanh(x) if approximate else _gelu_exact(x)
 
 
 def sigmoid(x):

@@ -25,7 +25,7 @@ def _linear_core_bwd(res, dy):
     # dW via an EXPLICIT transpose + plain matmul: XLA's default lowering
     # of the dW contraction ((b,s,h),(b,s,k)->(h,k)) uses a transposing
     # convolution emitter measured at ~40-47% of MXU peak on v5e (the
-    # largest single perf tax in BASELINE.md r03); materializing x^T as a
+    # largest single perf tax in the r03 step budget, ROADMAP S3); materializing x^T as a
     # separate copy and feeding a standard matmul runs at ~56% — about
     # 0.5 ms saved per FFN-sized dW at b64 x s512 (r04 microbench; a
     # Pallas dW kernel measured at most 50%, so XLA's pair wins).
@@ -50,7 +50,7 @@ def linear(x, weight, bias=None):
     174.3k tok/s): in context XLA fuses the dW conv with the Adam update,
     reading x/dy once, and the split schedule's extra HBM pass over the
     activations outweighs the emitter win.  Recorded so it is not retried
-    blindly (BASELINE.md measured non-wins).  Note: the toggle path is a
+    blindly (ROADMAP "Recorded non-wins"; r04, earlier setup).  Note: the toggle path is a
     custom_vjp, so forward-mode AD (jax.jvp/jacfwd) is unsupported under
     it — reverse-mode only, fine for training."""
     if os.environ.get("PDTPU_LINEAR_DW") == "transpose":

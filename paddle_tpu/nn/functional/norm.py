@@ -23,7 +23,7 @@ def _bn_train(x, weight, bias, axes, epsilon):
     conv; the apply input-fuses into the consumer.  Backward: the
     classic two-pass schedule (one fused pass for dβ=Σdy and
     dγ=Σdy·x̂, one elementwise pass for dx) instead of leaving AD to
-    schedule the passes (r05 ResNet ladder, BASELINE.md).
+    schedule the passes (r05 ResNet ladder, ROADMAP S1).
 
     Returns (out, mean_f32, var_f32); weight/bias may be None.
     """
@@ -125,7 +125,8 @@ def _use_fused_bn_act(x, act, data_format) -> bool:
     from ...ops.pallas import conv_fused as _cf
 
     return (_pcfg.kernel_enabled("use_pallas_conv_fused")
-            and _cf.train_supported(x, act, data_format))
+            and _pcfg.counted("bn_act_train",
+                              _cf.train_supported(x, act, data_format)))
 
 
 def batch_norm_act(x, running_mean, running_var, weight=None, bias=None,
@@ -186,28 +187,36 @@ def bn_inference_scale_bias(mean, var, weight, bias, epsilon):
     return a, b
 
 
-def _use_fused_ln(x, normalized_shape) -> bool:
-    """Gate for the Pallas fused-LN kernel (separate so tests can exercise
-    the dispatch on the CPU backend by patching this module's backend
-    check without touching the kernel's own interpret-mode switch)."""
-    import jax
-
-    from ...core import flags
+def _fused_ln_shards(x, normalized_shape) -> int:
+    """Gate for the Pallas fused-LN kernel: the number of data-parallel
+    shards to dispatch it over (parallel.mesh.batch_shards) when the flag,
+    backend and PER-SHARD shape allow it, else 0."""
+    from ...ops.pallas import config as _pcfg
     from ...ops.pallas import layer_norm as _fused
+    from ...parallel import mesh as _mesh
 
-    return (flags.get_flag("use_fused_layer_norm")
-            and jax.default_backend() not in ("cpu", "gpu")
-            and _fused.supported(x, normalized_shape))
+    if not _pcfg.kernel_enabled("use_fused_layer_norm") or x.ndim < 2:
+        return 0
+    n = _mesh.batch_shards(x.shape[0])
+    if not n:
+        _pcfg.record_fallback("fused_layer_norm", "partial_manual_mesh")
+        return 0
+    local = jax.ShapeDtypeStruct((x.shape[0] // n,) + x.shape[1:], x.dtype)
+    return n if _fused.supported(local, normalized_shape) else 0
 
 
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5):
     if isinstance(normalized_shape, int):
         normalized_shape = (normalized_shape,)
-    if (weight is not None and bias is not None
-            and _use_fused_ln(x, tuple(normalized_shape))):
-        from ...ops.pallas import layer_norm as _fused
+    if weight is not None and bias is not None:
+        n = _fused_ln_shards(x, tuple(normalized_shape))
+        if n:
+            from ...ops.pallas import layer_norm as _fused
+            from ...parallel import mesh as _mesh
 
-        return _fused.fused_layer_norm(x, weight, bias, epsilon)
+            return _mesh.per_batch_shard(
+                lambda x, w, b: _fused.fused_layer_norm(x, w, b, epsilon),
+                n, (x,), (weight, bias))
     axes = tuple(range(x.ndim - len(normalized_shape), x.ndim))
     # compute in float32 for bf16 stability (TPU-native AMP practice)
     xf = x.astype(jnp.float32)

@@ -22,8 +22,9 @@ def _use_pallas_pool(x, kernel, stride, pads, mode, exclusive,
         return False
     from ...ops.pallas import pooling as _pool
 
-    return _pool.supported(x, kernel, stride, pads, mode, exclusive,
-                           data_format)
+    return _pcfg.counted(
+        f"{mode}_pool2d", _pool.supported(x, kernel, stride, pads, mode,
+                                          exclusive, data_format))
 
 
 def _pool2d(x, kernel, stride, padding, init, op, norm=None,

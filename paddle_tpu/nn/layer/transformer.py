@@ -8,13 +8,13 @@ feeding the MXU; norm/residual math runs in float32 under bf16 params.
 from __future__ import annotations
 
 import collections
-import jax
 from typing import Optional
 
 import jax.numpy as jnp
 
 from ...ops import attention as attn_ops
 from .. import functional as F
+from ..functional.norm import _fused_ln_shards
 from .base import Layer, LayerList
 from .common import Dropout, Linear
 from .norm import LayerNorm
@@ -131,19 +131,25 @@ def _sublayer_epilogue(layer, out, residual, norm, dropout_layer):
     fused Pallas kernel (one HBM pass per direction, in-kernel replayable
     dropout); elsewhere or for unsupported shapes it composes the
     reference chain."""
-    from ...core import flags as _flags
     from ...ops.pallas import layer_norm as _fln
+    from ...parallel import mesh as _mesh
 
     rate = float(dropout_layer.p) if layer.training else 0.0
+    n = 0
     if (not layer.normalize_before
-            and norm.weight is not None and norm.bias is not None
-            and _flags.get_flag("use_fused_layer_norm")
-            and jax.default_backend() not in ("cpu", "gpu")
-            and _fln.supported(out, norm.normalized_shape)):
-        seed = attn_ops.draw_dropout_seed() if rate > 0.0 else None
-        return _fln.fused_residual_dropout_layer_norm(
-            out, residual, norm.weight.value, norm.bias.value,
-            dropout_rate=rate, seed=seed, epsilon=norm.epsilon)
+            and norm.weight is not None and norm.bias is not None):
+        n = _fused_ln_shards(out, tuple(norm.normalized_shape))
+    if n:
+        seed = attn_ops.draw_dropout_seed(n, rate)
+
+        def kernel(x, res, seed, w, b):
+            return _fln.fused_residual_dropout_layer_norm(
+                x, res, w, b, dropout_rate=rate, seed=seed,
+                epsilon=norm.epsilon)
+
+        return _mesh.per_batch_shard(
+            kernel, n, (out, residual, seed),
+            (norm.weight.value, norm.bias.value))
     src = residual + dropout_layer(out)
     if not layer.normalize_before:
         src = norm(src)

@@ -11,9 +11,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-
-def _is_tpu() -> bool:
-    return jax.default_backend() not in ("cpu", "gpu")
+from .pallas import config as _pcfg
 
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
@@ -61,13 +59,18 @@ def _as_padding_bias(attn_mask, b, s):
     return jnp.broadcast_to(m.astype(jnp.float32), (b, s))
 
 
-def draw_dropout_seed():
-    """One int32 seed from the framework key stream for in-kernel dropout.
-    Single definition so the seeding convention used by the flash and
+def draw_dropout_seed(n_shards: int = 1, rate: float = 1.0):
+    """int32 seeds from the framework key stream for in-kernel dropout, one
+    per data-parallel shard the kernel is dispatched over (the kernels key
+    their streams on shard-LOCAL coordinates, so shards sharing a seed
+    would draw the same masks); zeros, and no key drawn, when ``rate`` is
+    0.  Single definition so the seeding convention used by the flash and
     fused-LN kernels cannot drift between call sites."""
     from ..core import random as _random
 
-    return jax.random.randint(_random.next_key(), (1,),
+    if rate <= 0.0:
+        return jnp.zeros((n_shards,), jnp.int32)
+    return jax.random.randint(_random.next_key(), (n_shards,),
                               jnp.iinfo(jnp.int32).min,
                               jnp.iinfo(jnp.int32).max, jnp.int32)
 
@@ -80,26 +83,33 @@ def flash_attention_packed(q, k, v, num_heads, attn_mask=None,
     pallas/flash_attention_packed.py).  Returns (batch, seq, heads*head_dim)
     or None when the kernel path is not eligible (caller falls back to the
     standard split-head path)."""
-    from ..core import flags
+    from ..parallel import mesh as _mesh
     from .pallas import flash_attention_packed as fap
 
     b, s, packed = q.shape
     hd = packed // num_heads
     # cheap gates first: every eager fallback call would otherwise build
     # and discard the mask conversion
-    if not (flags.get_flag("use_flash_attention")
-            and _is_tpu()
+    if not (_pcfg.kernel_enabled("use_flash_attention")
             and q.shape == k.shape == v.shape
             and fap.supported(s, num_heads, hd)):
         return None
     bias = _as_padding_bias(attn_mask, b, s)
     if bias is None:
         return None
+    n = _mesh.batch_shards(b)
+    if not n:
+        _pcfg.record_fallback("flash_attention_packed", "partial_manual_mesh")
+        return None
     rate = float(dropout_p) if training else 0.0
-    seed = draw_dropout_seed() if rate > 0.0 else None
-    return fap.flash_attention_packed(q, k, v, num_heads, bias=bias,
-                                      sm_scale=scale, causal=is_causal,
-                                      dropout_rate=rate, seed=seed)
+    seed = draw_dropout_seed(n, rate)
+
+    def kernel(q, k, v, bias, seed):
+        return fap.flash_attention_packed(q, k, v, num_heads, bias=bias,
+                                          sm_scale=scale, causal=is_causal,
+                                          dropout_rate=rate, seed=seed)
+
+    return _mesh.per_batch_shard(kernel, n, (q, k, v, bias, seed))
 
 
 def flash_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
@@ -110,24 +120,30 @@ def flash_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
     Kernel-eligible masks are k-position padding masks (shape (b,1,1,s));
     arbitrary (b,h,sq,sk) masks fall back.  Dropout runs in-kernel with a
     replayable position-keyed RNG."""
-    from ..core import flags
+    from ..parallel import mesh as _mesh
     from .pallas import flash_attention as fa
 
     b, h, s, d = q.shape
     rate = float(dropout_p) if training else 0.0
     bias = _as_padding_bias(attn_mask, b, s)
     use_kernel = (
-        flags.get_flag("use_flash_attention")
-        and _is_tpu()
+        _pcfg.kernel_enabled("use_flash_attention")
         and bias is not None
         and q.shape == k.shape == v.shape
         and fa.supported(s, d)
     )
+    n = _mesh.batch_shards(b) if use_kernel else 0
+    if n:
+        seed = draw_dropout_seed(n, rate)
+
+        def kernel(q, k, v, bias, seed):
+            return fa.flash_attention(q, k, v, bias=bias, sm_scale=scale,
+                                      causal=is_causal, dropout_rate=rate,
+                                      seed=seed)
+
+        return _mesh.per_batch_shard(kernel, n, (q, k, v, bias, seed))
     if use_kernel:
-        seed = draw_dropout_seed() if rate > 0.0 else None
-        return fa.flash_attention(q, k, v, bias=bias, sm_scale=scale,
-                                  causal=is_causal, dropout_rate=rate,
-                                  seed=seed)
+        _pcfg.record_fallback("flash_attention", "partial_manual_mesh")
     return scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
                                         dropout_p=dropout_p, is_causal=is_causal,
                                         scale=scale, training=training)

@@ -3,11 +3,13 @@
 Three concerns live here so every kernel module and every dispatch site
 agrees on them:
 
-* **Gating** — `kernel_enabled(flag)` is the single backend+flag gate the
-  functional dispatch sites use.  Kernels run in interpret mode off-TPU
-  for tests, but production CPU/GPU paths should not pay the interpret
-  overhead, so the gate requires a TPU backend; tests monkeypatch
-  `backend_is_tpu` to force the Pallas branch on CPU CI.
+* **Gating** — `backend_is_tpu()` is the one platform predicate:
+  `kernel_enabled(flag)` (the backend+flag gate every dispatch site uses)
+  and `interpret()` (what every `pallas_call` passes as `interpret=`) both
+  read it, so a dispatch that says "TPU" can never meet a kernel that says
+  "interpret".  Production CPU paths never pay the interpret overhead;
+  tests that want the Pallas branch on CPU CI monkeypatch `kernel_enabled`
+  (the gate), which leaves the kernels in interpret mode.
 * **Cache identity** — `fingerprint()` folds the *effective* kernel set
   (flag AND backend) into a short string the Executor joins into both its
   in-memory and persistent compile-cache keys.  Kernel selection happens
@@ -48,9 +50,14 @@ _KERNEL_FLAGS: Tuple[Tuple[str, str], ...] = (
 
 
 def backend_is_tpu() -> bool:
-    """Separated from `kernel_enabled` so tests can monkeypatch it and run
-    the kernels in interpret mode on CPU CI."""
+    """The one "am I on a TPU" predicate (platform name "tpu")."""
     return jax.default_backend() == "tpu"
+
+
+def interpret() -> bool:
+    """`interpret=` for every `pallas_call`: Mosaic on a TPU, the Pallas
+    interpreter everywhere else."""
+    return not backend_is_tpu()
 
 
 def kernel_enabled(flag_name: str) -> bool:
@@ -94,6 +101,14 @@ def record_call(kernel: str) -> None:
 
 def record_fallback(kernel: str, reason: str = "unsupported") -> None:
     _m_fallbacks.inc(kernel=kernel, reason=reason)
+
+
+def counted(kernel: str, supported: bool) -> bool:
+    """A kernel's `supported()` verdict, passed through; a refusal is
+    counted in `pallas.fallbacks` (the dispatch then takes the XLA path)."""
+    if not supported:
+        record_fallback(kernel)
+    return supported
 
 
 # ---------------------------------------------------------------------------

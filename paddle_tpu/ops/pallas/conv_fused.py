@@ -42,10 +42,6 @@ from jax.experimental import pallas as pl
 from paddle_tpu.ops.pallas import config as _cfg
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 DEFAULT_BLOCK_ROWS = 256
 # Per-grid-step VMEM budget for the whole-image conv blocks (input +
 # filter + fp32 accumulator + output), conservative vs the ~16 MB/core.
@@ -139,7 +135,14 @@ def supported(x, w_shape, stride, padding, dilation=(1, 1), groups=1,
         return False
     sh, sw = stride
     ph, pw = padding
-    if sh not in (1, 2) or sw not in (1, 2):
+    # Stride 1 only.  The tap loop takes each window with a strided
+    # `lax.slice` of the in-VMEM image, and Mosaic refuses a stride-2
+    # `vector.extract_strided_slice` ("expected strides to be confined to
+    # [1, 2)", jax 0.9.0 / libtpu 0.0.34).  The ref-side alternative
+    # (`pl.ds(..., stride=2)`) compiles for 32-bit data only ("Strided load
+    # with non 32-bit data" for bf16/int8), so stride 2 stays on XLA
+    # (ROADMAP S1).
+    if (sh, sw) != (1, 1):
         return False
     out_h, out_w = _out_hw(h, kh, sh, ph), _out_hw(w, kw, sw, pw)
     if out_h <= 0 or out_w <= 0:
@@ -177,7 +180,7 @@ def conv2d_bn_act(x, w, a, b, *, stride=(1, 1), padding=(0, 0), act=""):
             out_specs=pl.BlockSpec((1, out_h, out_w, o),
                                    lambda i: (i, 0, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((n, out_h, out_w, o), x.dtype),
-            interpret=_interpret(),
+            interpret=_cfg.interpret(),
         )(xp, wk, a.reshape(1, -1).astype(jnp.float32),
           b.reshape(1, -1).astype(jnp.float32))
 
@@ -243,7 +246,7 @@ def _batch_stats(x2, block_rows):
                    pl.BlockSpec((1, 8, c), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((grid, 8, c), jnp.float32),
                    jax.ShapeDtypeStruct((grid, 8, c), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
     )(x2)
     return s.sum(axis=(0, 1)), ss.sum(axis=(0, 1))
 
@@ -260,7 +263,7 @@ def scale_act(x2, a, b, act, block_rows, out_dtype):
                   pl.BlockSpec((1, c), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_rows, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c), out_dtype),
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
     )(x2, a.reshape(1, -1), b.reshape(1, -1))
 
 

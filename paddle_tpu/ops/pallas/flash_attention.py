@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import config as _cfg
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -110,7 +112,7 @@ def _dropout_keep_hw(seed, bh, qi, kv_idx, shape, rate):
 
 def _keep_mask(seed, bh, qi, kv_idx, q_pos, k_pos, rate):
     """Dispatch: hardware PRNG on real TPUs, position hash in interpret."""
-    if _interpret():
+    if _cfg.interpret():
         return _dropout_keep(seed, bh, q_pos, k_pos, rate)
     return _dropout_keep_hw(seed, bh, qi, kv_idx, q_pos.shape, rate)
 
@@ -198,7 +200,8 @@ def _flash_forward(q, k, v, bias, seed, sm_scale, causal, dropout_rate,
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="flash_fwd",
     )(seed, q, k, v, bias.reshape(b, 1, seq_len))
 
 
@@ -325,7 +328,8 @@ def _flash_backward(q, k, v, bias, seed, o, lse, do, sm_scale, causal,
         ],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="flash_dkdv",
     )(seed, q, k, v, bias3, do, lse, delta)
 
     dq = pl.pallas_call(
@@ -343,7 +347,8 @@ def _flash_backward(q, k, v, bias, seed, o, lse, do, sm_scale, causal,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh_i, i: (bh_i, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="flash_dq",
     )(seed, q, k, v, bias3, do, lse, delta)
     return dq, dk, dv
 
@@ -352,14 +357,6 @@ def _smem():
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.SMEM
-
-
-_INTERPRET = False
-
-
-def _interpret() -> bool:
-    """Interpret mode for CPU testing (TPU-only Mosaic otherwise)."""
-    return _INTERPRET or jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -429,7 +426,7 @@ def flash_attention(q, k, v, bias=None, sm_scale=None, causal=False,
         bq //= 2
     while s % bk:
         bk //= 2
-    if not _interpret() and (bq < 128 or bk < 128):
+    if not _cfg.interpret() and (bq < 128 or bk < 128):
         # Mosaic lane constraint: the (1, 1, block) lse/bias/delta blocks
         # need block % 128 == 0.  supported() guarantees s % 128 == 0, so
         # 128 always divides s here; reject explicit smaller blocks.
@@ -441,6 +438,9 @@ def flash_attention(q, k, v, bias=None, sm_scale=None, causal=False,
     # the docstring carries the learned-bias warning)
     bias, seed = _normalize_bias_seed(bias, seed, b, s)
     merged = lambda x: x.reshape(b * h, s, d)
-    out = _flash_attention_bhsd(merged(q), merged(k), merged(v), bias, seed,
-                                sm_scale, causal, float(dropout_rate), bq, bk)
+    _cfg.record_call("flash_attention")
+    with jax.named_scope("pallas.flash_attention"):
+        out = _flash_attention_bhsd(merged(q), merged(k), merged(v), bias,
+                                    seed, sm_scale, causal,
+                                    float(dropout_rate), bq, bk)
     return out.reshape(b, h, s, d)

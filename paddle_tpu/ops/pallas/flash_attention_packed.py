@@ -26,11 +26,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import config as _cfg
+
 from .flash_attention import (
     DEFAULT_BLOCK_K,
     DEFAULT_BLOCK_Q,
     NEG_INF,
-    _interpret,
     _keep_mask,
     _normalize_bias_seed,
     _smem,
@@ -239,7 +240,8 @@ def _forward(q, k, v, bias, seed, num_heads, sm_scale, causal, dropout_rate,
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((b, pairs, hpg, seq_len), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="flash_packed_fwd",
     )(seed, q, k, v, bias.reshape(b, 1, seq_len))
 
 
@@ -280,7 +282,8 @@ def _backward(q, k, v, bias, seed, num_heads, o, lse, do, sm_scale, causal,
         ],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="flash_packed_dkdv",
     )(seed, q, k, v, bias3, do, lse, delta)
 
     dq = pl.pallas_call(
@@ -298,7 +301,8 @@ def _backward(q, k, v, bias, seed, num_heads, o, lse, do, sm_scale, causal,
         ],
         out_specs=_specs(seq_len, pairs, block_q),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="flash_packed_dq",
     )(seed, q, k, v, bias3, do, lse, delta)
     return dq, dk, dv
 
@@ -367,12 +371,14 @@ def flash_attention_packed(q, k, v, num_heads, bias=None, sm_scale=None,
         bq //= 2
     while s % bk:
         bk //= 2
-    if not _interpret():
+    if not _cfg.interpret():
         if s % 128:
             raise ValueError(
                 f"flash_attention_packed requires seq_len % 128 == 0 on "
                 f"TPU, got {s}")
         bq, bk = max(bq, 128), max(bk, 128)
     bias, seed = _normalize_bias_seed(bias, seed, b, s)
-    return _flash_packed(q, k, v, bias, seed, int(num_heads), sm_scale,
-                         causal, float(dropout_rate), bq, bk)
+    _cfg.record_call("flash_attention_packed")
+    with jax.named_scope("pallas.flash_attention_packed"):
+        return _flash_packed(q, k, v, bias, seed, int(num_heads), sm_scale,
+                             causal, float(dropout_rate), bq, bk)

@@ -34,10 +34,6 @@ from jax.experimental import pallas as pl
 from paddle_tpu.ops.pallas import config as _cfg
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 DEFAULT_BLOCK_ROWS = 256
 VMEM_CAP_BYTES = 12 * 1024 * 1024
 
@@ -110,7 +106,7 @@ def int8_matmul_dequant(x_q, w_q, scale, bias=None, act="",
                       pl.BlockSpec((1, n), lambda i: (0, 0))],
             out_specs=pl.BlockSpec((block_m, n), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-            interpret=_interpret(),
+            interpret=_cfg.interpret(),
         )(x_q, w_q, scale.reshape(1, -1).astype(jnp.float32),
           b.reshape(1, -1))
 
@@ -153,7 +149,9 @@ def conv_supported(x_q, w_shape, stride, padding, dilation=(1, 1), groups=1,
         return False
     sh, sw = stride
     ph, pw = padding
-    if sh not in (1, 2) or sw not in (1, 2):
+    # stride 1 only: Mosaic refuses the tap loop's stride-2 window slice
+    # (see conv_fused.supported for the two refusals; ROADMAP S1)
+    if (sh, sw) != (1, 1):
         return False
     out_h, out_w = _out_hw(h, kh, sh, ph), _out_hw(w, kw, sw, pw)
     if out_h <= 0 or out_w <= 0:
@@ -194,7 +192,7 @@ def int8_conv2d_dequant(x_q, w_q, scale, bias=None, *, stride=(1, 1),
             out_specs=pl.BlockSpec((1, out_h, out_w, o),
                                    lambda i: (i, 0, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((n, out_h, out_w, o), out_dtype),
-            interpret=_interpret(),
+            interpret=_cfg.interpret(),
         )(xp, wk, scale.reshape(1, -1).astype(jnp.float32), b.reshape(1, -1))
 
 

@@ -28,11 +28,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import config as _cfg
+
 DEFAULT_BLOCK_ROWS = 256
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _ln_fwd_kernel(x_ref, w_ref, b_ref, o_ref, mean_ref, rstd_ref, *, eps):
@@ -104,7 +102,8 @@ def _fwd(x2, w, b, eps, block_rows, out_dtype):
             jax.ShapeDtypeStruct((1, n), jnp.float32),
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="ln_fwd",
     )(x2, w.reshape(1, dim), b.reshape(1, dim))
 
 
@@ -131,7 +130,8 @@ def _bwd(x2, w, mean, rstd, dy2, block_rows):
             jax.ShapeDtypeStruct((n_blocks, 8, dim), jnp.float32),
             jax.ShapeDtypeStruct((n_blocks, 8, dim), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="ln_bwd",
     )(x2, w.reshape(1, dim), mean, rstd, dy2)
     return dx, dw_part.sum(axis=(0, 1)), db_part.sum(axis=(0, 1))
 
@@ -181,7 +181,10 @@ def fused_layer_norm(x, weight, bias, epsilon=1e-5):
     out_dtype = jnp.result_type(x.dtype, weight.dtype, bias.dtype)
     x2 = x.reshape(n, dim)
     block_rows = _rows_block(n)
-    out = _fused_ln(x2, weight, bias, float(epsilon), block_rows, out_dtype)
+    _cfg.record_call("fused_layer_norm")
+    with jax.named_scope("pallas.fused_layer_norm"):
+        out = _fused_ln(x2, weight, bias, float(epsilon), block_rows,
+                        out_dtype)
     return out.reshape(orig_shape)
 
 
@@ -199,7 +202,7 @@ def _keep_tile(seed, tile_idx, shape, rate):
     """Keep-mask for one (block_rows, dim) tile; hardware PRNG on TPU
     (re-seeded per tile => replayable in backward), position hash in
     interpret mode (same contract as flash_attention's dropout)."""
-    if not _interpret():
+    if not _cfg.interpret():
         from .flash_attention import _keep_from_hw_bits
 
         return _keep_from_hw_bits((seed, tile_idx), shape, rate)
@@ -285,7 +288,8 @@ def _rdln_fwd(x2, res2, w, b, seed, eps, rate, block_rows, out_dtype):
             jax.ShapeDtypeStruct((1, n), jnp.float32),
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="rdln_fwd",
     )(seed, x2, res2, w.reshape(1, dim), b.reshape(1, dim))
 
 
@@ -316,7 +320,8 @@ def _rdln_bwd(x2, res2, w, mean, rstd, seed, dy2, rate, block_rows):
             jax.ShapeDtypeStruct((n_blocks, 8, dim), jnp.float32),
             jax.ShapeDtypeStruct((n_blocks, 8, dim), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_cfg.interpret(),
+        name="rdln_bwd",
     )(seed, x2, res2, w.reshape(1, dim), mean, rstd, dy2)
     return dx, dres, dw_part.sum(axis=(0, 1)), db_part.sum(axis=(0, 1))
 
@@ -367,7 +372,9 @@ def fused_residual_dropout_layer_norm(x, residual, weight, bias,
         seed = jnp.zeros((1,), jnp.int32)
     else:
         seed = jnp.asarray(seed, jnp.int32).reshape((1,))
-    out = _fused_rdln(x.reshape(n, dim), residual.reshape(n, dim), weight,
-                      bias, seed, float(epsilon), float(dropout_rate),
-                      _rows_block(n), out_dtype)
+    _cfg.record_call("fused_rdln")
+    with jax.named_scope("pallas.fused_rdln"):
+        out = _fused_rdln(x.reshape(n, dim), residual.reshape(n, dim),
+                          weight, bias, seed, float(epsilon),
+                          float(dropout_rate), _rows_block(n), out_dtype)
     return out.reshape(orig_shape)

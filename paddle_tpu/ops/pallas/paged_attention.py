@@ -52,10 +52,6 @@ from paddle_tpu.ops.pallas import config as _cfg
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def supported(num_seqs: int, block_size: int, head_dim: int,
               dtype) -> bool:
     """Shapes the kernel handles on real TPUs: lane-aligned head_dim,
@@ -78,15 +74,16 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, scale_ref,
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        m_ref[0, 0] = NEG_INF    # SMEM takes scalar stores only
+        l_ref[0, 0] = 0.0
 
     q = q_ref[0]                       # (1, d) native dtype
     k = k_ref[0]                       # (block_size, d)
     v = v_ref[0]
     if quantized:
-        k = k.astype(jnp.float32) * scale_ref[0, 0]
-        v = v.astype(jnp.float32) * scale_ref[0, 1]
+        blk = tbl_ref[s, j]
+        k = k.astype(jnp.float32) * scale_ref[2 * blk]
+        v = v.astype(jnp.float32) * scale_ref[2 * blk + 1]
         q = q.astype(jnp.float32)
     scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
     pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, scores.shape,
@@ -142,7 +139,10 @@ def paged_attention_kernel(q, k_cache, v_cache, block_tables, context_lens,
                          lambda s, j, tbl, lens: (tbl[s, j], 0, 0)),
             pl.BlockSpec((1, block_size, d),
                          lambda s, j, tbl, lens: (tbl[s, j], 0, 0)),
-            pl.BlockSpec((1, 2), lambda s, j, tbl, lens: (tbl[s, j], 0)),
+            # per-block (k, v) scales, flat in SMEM and indexed by the
+            # physical block id: a (1, 2) VMEM block of the (num_blocks, 2)
+            # array is refused by Mosaic (second-minor block of 1)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, d), lambda s, j, tbl, lens: (s, 0, 0)),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32),
@@ -154,9 +154,9 @@ def paged_attention_kernel(q, k_cache, v_cache, block_tables, context_lens,
         out = pl.pallas_call(
             kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((num_seqs, 1, d), q.dtype),
-            interpret=_interpret(),
+            interpret=_cfg.interpret(),
         )(block_tables, context_lens, q.reshape(num_seqs, 1, d),
-          k_cache, v_cache, kv_scales)
+          k_cache, v_cache, kv_scales.reshape(-1))
     return out.reshape(num_seqs, d)
 
 
@@ -197,7 +197,7 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                     kv_scales: Optional[jax.Array] = None):
     """Gated dispatch: the Pallas kernel when the ``use_paged_attention``
     flag is on, the backend is TPU (tests monkeypatch
-    ``config.backend_is_tpu`` to exercise interpret mode on CPU CI) and
+    ``config.kernel_enabled`` to exercise interpret mode on CPU CI) and
     the shapes pass :func:`supported`; the jnp reference otherwise."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])

@@ -32,10 +32,6 @@ from jax.experimental import pallas as pl
 from paddle_tpu.ops.pallas import config as _cfg
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 VMEM_CAP_BYTES = 12 * 1024 * 1024
 
 
@@ -72,7 +68,10 @@ def supported(x, kernel, stride, padding, mode="max", exclusive=True,
     kh, kw = kernel
     sh, sw = stride
     ph, pw = padding
-    if kh > 8 or kw > 8 or sh not in (1, 2) or sw not in (1, 2):
+    # stride 1 only: Mosaic refuses the tap loop's stride-2 window slice
+    # (see conv_fused.supported for the two refusals; ROADMAP S1) — which
+    # leaves every ResNet/YOLO pool on lax.reduce_window
+    if kh > 8 or kw > 8 or (sh, sw) != (1, 1):
         return False
     if mode == "avg" and exclusive and (ph or pw):
         return False  # needs per-position counts — XLA fallback
@@ -110,7 +109,7 @@ def _pool2d_nhwc(x, kernel, stride, padding, mode, name):
             out_specs=pl.BlockSpec((1, out_h, out_w, c),
                                    lambda i: (i, 0, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((n, out_h, out_w, c), x.dtype),
-            interpret=_interpret(),
+            interpret=_cfg.interpret(),
         )(xp)
 
 

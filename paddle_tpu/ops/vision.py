@@ -361,7 +361,7 @@ def yolo_loss(x, gt_box, gt_label, anchors: Sequence[int],
     t_scale = scat(box_scale)
     # class targets scattered DIRECTLY in the head's (N, A, C, H, W)
     # layout: the [..., C]-last form needed an 83 MB fp32 transpose of the
-    # prediction tensor per head per step (r05 YOLO ladder, BASELINE.md)
+    # prediction tensor per head per step (r05 YOLO ladder; ROADMAP "Recorded non-wins")
     cls_idx = jnp.clip(gt_label, 0, C - 1)
     t_cls = jnp.zeros((N, A, C, H, W), jnp.float32).at[
         (bidx, local_anchor, cls_idx, gj, gi)].set(1.0, mode="drop")

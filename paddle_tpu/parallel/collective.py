@@ -26,20 +26,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec
 
-try:
-    from jax import shard_map as _jax_shard_map  # jax >= 0.8
-    _VMA_KW = "check_vma"
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-    _VMA_KW = "check_rep"
 
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_rep=False):
-    """Version-stable shard_map: always disables replication/VMA checking
-    (our collectives manage replication semantics explicitly)."""
-    kw = {_VMA_KW: check_rep}
-    return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
+def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=False):
+    """`jax.shard_map` with VMA checking off by default (our collectives
+    manage replication semantics explicitly)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 from . import mesh as _mesh
 
@@ -221,7 +213,7 @@ def _eager_collective(fn, x, axes, scatter_dim: Optional[int] = None):
     while out and out[-1] is None:
         out.pop()
     f = shard_map(fn, mesh=m, in_specs=(in_spec,),
-                  out_specs=PartitionSpec(*out), check_rep=False)
+                  out_specs=PartitionSpec(*out), check_vma=False)
     return f(jnp.asarray(x))
 
 
@@ -415,7 +407,7 @@ def all_to_all(in_tensor_list, out_tensor_list=None, group=None,
             out = shard_map(lambda v: _a2a(v, ax), mesh=m,
                             in_specs=(PartitionSpec(*spec_in),),
                             out_specs=PartitionSpec(*_moved(spec_in, concat_axis, split_axis)),
-                            check_rep=False)(jnp.asarray(x))
+                            check_vma=False)(jnp.asarray(x))
     if listed and out_tensor_list is not None:
         out_tensor_list.extend(list(out))
         return out_tensor_list

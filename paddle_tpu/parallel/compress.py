@@ -440,17 +440,12 @@ def bucketed_all_reduce(grads, axis, *, buffer_mb: float = 25.0,
 
 def _leaf_varying(leaf, axis) -> bool:
     """Whether a value still varies over the axis (needs a true allreduce)
-    vs arrives pre-summed (replicated-param backward under VMA-checking
-    jax).  Older jax has no vma tracking: assume varying, which is correct
-    there (no automatic backward psum insertion)."""
-    try:
-        aval = jax.typeof(leaf)  # jax >= 0.6
-    except AttributeError:
-        return True
-    vma = getattr(aval, "vma", None)
-    if vma is None:
-        return True
-    return axis in vma
+    vs arrives pre-summed (replicated-param backward under a
+    ``check_vma=True`` shard_map).  Under ``check_vma=False`` nothing is
+    tracked and nothing is pre-summed — even ``axis_index`` then reports an
+    empty vma — so every leaf counts as varying."""
+    tracked = axis in jax.typeof(lax.axis_index(axis)).vma
+    return not tracked or axis in jax.typeof(leaf).vma
 
 
 def sync_gradients(grads, axis, *, compress: Optional[str] = None,

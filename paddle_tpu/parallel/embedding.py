@@ -341,8 +341,6 @@ def sharded_lookup(weight, ids, *, mesh: Mesh, axis: str,
     ``axis``.  Falls back to the dedup'd single-device path when the axis
     is degree-1.  ``batch_axes`` shard the id batch (data parallelism);
     the table is replicated over them."""
-    from jax.experimental.shard_map import shard_map
-
     vocab, dim = int(weight.shape[0]), int(weight.shape[1])
     k = int(mesh.shape[axis]) if axis in mesh.axis_names else 1
     if k <= 1:
@@ -365,10 +363,10 @@ def sharded_lookup(weight, ids, *, mesh: Mesh, axis: str,
     body = _make_body(k, axis, vocab // k, vocab, cap, quantize,
                       jnp.result_type(weight))
     b = (bspec if len(bspec) > 1 else bspec[0]) if bspec else None
-    out = shard_map(
-        body, mesh,
+    out = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(PartitionSpec(axis, None), PartitionSpec(b)),
-        out_specs=PartitionSpec(b, None), check_rep=False)(
+        out_specs=PartitionSpec(b, None), check_vma=False)(
         weight, ids)
     return out
 

@@ -254,7 +254,10 @@ class Fleet:
 
     # -- lifecycle -----------------------------------------------------------
     def init(self, role_maker=None, is_collective: bool = True,
-             strategy: Optional[DistributedStrategy] = None) -> "Fleet":
+             strategy: Optional[DistributedStrategy] = None,
+             devices=None) -> "Fleet":
+        """``devices`` restricts the mesh to a subset of ``jax.devices()``
+        (e.g. one chip of a four-chip host); default all of them."""
         self._role_maker = role_maker or _RoleMaker()
         self._strategy = strategy or DistributedStrategy()
         hc = self._strategy.hybrid_configs
@@ -265,7 +268,7 @@ class Fleet:
         self._mesh = _mesh.init_parallel_env(
             dp=None if hc.dp_degree == -1 else hc.dp_degree,
             pp=hc.pp_degree, tp=hc.mp_degree, sp=hc.sp_degree,
-            ep=hc.ep_degree)
+            ep=hc.ep_degree, devices=devices)
         ec = self._strategy.elastic_configs
         if self._strategy.elastic and ec.save_every > 0 and ec.ckpt_dir:
             # surface the cadence through the flags Model.fit reads, so

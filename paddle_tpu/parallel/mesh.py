@@ -12,6 +12,7 @@ devices span hosts.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -89,6 +90,20 @@ def get_mesh() -> Optional[Mesh]:
     return _global_mesh
 
 
+@contextlib.contextmanager
+def mesh_scope(mesh: Optional[Mesh]):
+    """Make ``mesh`` the current mesh while a step that was built for it is
+    traced, whatever `set_mesh` said last (a trainer holding an explicit
+    mesh traces at its first call, which may come after another
+    `fleet.init`)."""
+    global _global_mesh
+    prev, _global_mesh = _global_mesh, mesh
+    try:
+        yield
+    finally:
+        _global_mesh = prev
+
+
 def current_mesh() -> Mesh:
     """The active mesh, creating a default all-`dp` mesh on first use (the
     reference's lazy ring-0 `NCCLCommContext` bootstrap equivalent)."""
@@ -137,7 +152,8 @@ def mesh_fingerprint(mesh: Optional[Mesh] = None) -> str:
 
 
 def init_parallel_env(strategy=None, *, dp: Optional[int] = None, pp: int = 1,
-                      tp: int = 1, sp: int = 1, ep: int = 1) -> Mesh:
+                      tp: int = 1, sp: int = 1, ep: int = 1,
+                      devices: Optional[Sequence] = None) -> Mesh:
     """Initialize the distributed environment (ref:
     python/paddle/distributed/parallel.py:32 ``init_parallel_env`` — which
     exchanges NCCL ids over TCP and builds per-process communicators).
@@ -145,7 +161,8 @@ def init_parallel_env(strategy=None, *, dp: Optional[int] = None, pp: int = 1,
     TPU-native: multi-host coordination is jax.distributed (PJRT handles the
     DCN bootstrap; no id exchange), and the "environment" is just the global
     mesh.  Single-host virtual meshes (xla_force_host_platform_device_count)
-    work identically.
+    work identically.  ``devices`` restricts the mesh to a subset of
+    ``jax.devices()`` (default: all of them).
     """
     if int(os.environ.get("PADDLE_TRAINERS_NUM", "1")) > 1:
         # fleetrun-style multi-process launch: defer to jax.distributed using
@@ -166,7 +183,8 @@ def init_parallel_env(strategy=None, *, dp: Optional[int] = None, pp: int = 1,
             # silently degrade to single-host (wrong topology, divergence).
             if "already initialized" not in str(e).lower():
                 raise
-    cfg = MeshConfig(dp=-1 if dp is None else dp, pp=pp, tp=tp, sp=sp, ep=ep)
+    cfg = MeshConfig(dp=-1 if dp is None else dp, pp=pp, tp=tp, sp=sp, ep=ep,
+                     devices=devices)
     mesh = build_mesh(cfg)
     set_mesh(mesh)
     return mesh
@@ -188,3 +206,52 @@ def data_sharding(mesh: Optional[Mesh] = None, batch_axes: Sequence[str] = (DP_A
     if seq_axis is not None and seq_axis in mesh.axis_names:
         spec.append(seq_axis)
     return NamedSharding(mesh, PartitionSpec(*spec))
+
+
+def batch_shards(batch: int) -> int:
+    """How a Pallas kernel is dispatched from the current trace, for a
+    batch of ``batch`` rows: the number of data-parallel shards the batch
+    splits into (the dp size of the current mesh; 1 when there is no mesh,
+    no dp, dp is already manual, or dp does not divide the batch) — or 0
+    when the kernel cannot be dispatched at all.
+
+    That last case is a trace that is manual over SOME mesh axes only (the
+    pp pipeline's shard_map, with dp/tp left to GSPMD): the kernel would
+    need a shard_map nested in the partial-manual one, which XLA's SPMD
+    partitioner does not survive under the 1F1B schedule (a CHECK failure
+    in spmd_partitioner_util on jaxlib 0.9.0).  Callers fall back to the
+    XLA lowering and count it (`pallas.fallbacks`)."""
+    mesh = _global_mesh
+    if mesh is None:
+        return 1
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    free = [a for a in mesh.axis_names if a not in manual]
+    if manual and math.prod(mesh.shape[a] for a in free) > 1:
+        return 0
+    if DP_AXIS not in free or batch % mesh.shape[DP_AXIS]:
+        return 1
+    return mesh.shape[DP_AXIS]
+
+
+def per_batch_shard(fn, n_shards: int, batched: Sequence,
+                    replicated: Sequence = ()):
+    """``fn(*batched, *replicated)`` for a Pallas kernel inside a step that
+    GSPMD partitions over the current mesh: run once per data-parallel
+    shard of the leading (batch) dim of every ``batched`` argument
+    (``n_shards`` from `batch_shards`; 1 = the batch is not split).
+
+    A Mosaic call has no SPMD partitioning rule — jax refuses to lower one
+    bare in a multi-device program ("cannot be automatically partitioned")
+    — so every mesh axis becomes manual here: dp carries the batch, the
+    others see replicated operands, which is what GSPMD does for any op it
+    cannot partition.  On a one-device mesh, or inside a shard_map that is
+    already manual over every axis, ``fn`` is called directly."""
+    mesh = _global_mesh
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return fn(*batched, *replicated)
+    over_dp = PartitionSpec(DP_AXIS) if n_shards > 1 else PartitionSpec()
+    specs = (over_dp,) * len(batched) + (PartitionSpec(),) * len(replicated)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=specs, out_specs=over_dp,
+        check_vma=False)(*batched, *replicated)

@@ -325,5 +325,5 @@ class PipelineStage:
         f = shard_map(
             run, mesh=self.mesh,
             in_specs=(in_param_spec, PartitionSpec()),
-            out_specs=PartitionSpec(), check_rep=False)
+            out_specs=PartitionSpec(), check_vma=False)
         return unmicrobatch(f(params, xs))

@@ -61,9 +61,8 @@ def _donation_async_safe() -> bool:
     full step time, undonated dispatch ~10us).  Donating there would
     serialize the steady-state pipeline the fast path exists to build, so
     with `donate_state` on, CPU keeps device-resident state + async
-    dispatch but skips `donate_argnums`; accelerator backends (tpu, gpu,
-    and tunneled PJRT plugins) alias the buffers without giving up async
-    dispatch and donate for real — hence exclude-cpu, not include-known."""
+    dispatch but skips `donate_argnums`; accelerator backends alias the
+    buffers without giving up async dispatch and donate for real."""
     global _DONATE_PLATFORM_OK
     if _FORCE_DONATION:
         return True
@@ -1093,11 +1092,8 @@ class Executor:
         if (status == "hit" or not _flags.get_flag("xprof_scopes")
                 or Executor._SCOPED_META_RE.search(aot.as_text())):
             return aot
-        try:
-            fresh = core.lower(*example).compile(
-                compiler_options={"xla_embed_ir_in_executable": False})
-        except Exception:
-            return aot  # a backend rejecting the option keeps the original
+        fresh = core.lower(*example).compile(
+            compiler_options={"xla_embed_ir_in_executable": False})
         return (fresh if Executor._SCOPED_META_RE.search(fresh.as_text())
                 else aot)
 
@@ -1122,11 +1118,11 @@ class Executor:
                                                 disk_key)
         if example is None or not _monitor.enabled():
             return core, status, None, None
-        try:
-            aot = core.lower(*example).compile()
-            aot = Executor._refresh_stale_metadata(core, example, aot, status)
-        except Exception:
-            return core, status, None, None
+        # a compile failure (a Mosaic refusal, an HBM OOM) surfaces here,
+        # once, with its own message — never swallowed into a second
+        # compile at first dispatch
+        aot = core.lower(*example).compile()
+        aot = Executor._refresh_stale_metadata(core, example, aot, status)
         cost = None
         try:
             ca = aot.cost_analysis()
@@ -1140,9 +1136,11 @@ class Executor:
         def call(feeds, donated, carried, step):
             try:
                 return aot(feeds, donated, carried, step)
-            except Exception:
-                # structure mismatches raise host-side before execution, so
-                # the donated buffers are still live for the jitted retry
+            except TypeError:
+                # the one structural case: a state pytree / aval that no
+                # longer matches the example the AOT handle is pinned to.
+                # It raises host-side before execution, so the donated
+                # buffers are still live for the jitted (retracing) call.
                 return core(feeds, donated, carried, step)
 
         return call, status, cost, aot
@@ -1211,10 +1209,7 @@ class Executor:
         # to the addressable-shard sum).  Dispatch stays on the jitted
         # `core`: the AOT handle is observability-only here, the
         # per-shard attribution story remains a roadmap item.
-        try:
-            aot = core.lower(*placed_example).compile()
-        except Exception:
-            return call, status, None, None
+        aot = core.lower(*placed_example).compile()
         cost = None
         try:
             ca = aot.cost_analysis()

@@ -349,13 +349,10 @@ def _hbm_capacity(capacity_bytes: Optional[int] = None
     """(capacity bytes or None, device kind).  Precedence: explicit arg >
     memcheck_capacity_gb flag > xprof.resolve_peaks table for the local
     device kind (None on CPU — no table entry, MC001 stays quiet)."""
-    kind = "unknown"
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        pass
+    dev = jax.devices()[0]
+    kind = dev.device_kind
     if capacity_bytes is not None:
         return int(capacity_bytes), kind
     flag_gb = float(_flags.get_flag("memcheck_capacity_gb"))
@@ -363,7 +360,7 @@ def _hbm_capacity(capacity_bytes: Optional[int] = None
         return int(flag_gb * (1 << 30)), kind
     from ..utils import xprof as _xprof
 
-    spec = _xprof.resolve_peaks(kind)
+    spec = _xprof.resolve_peaks(kind, platform=dev.platform)
     return spec.hbm_bytes, kind
 
 

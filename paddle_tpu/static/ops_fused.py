@@ -69,7 +69,7 @@ def _apply_act(out, act, attrs, op):
 def _use_pallas_conv(x, w, stride, padding, dilation, groups, act,
                      data_format) -> bool:
     """Gate for the fused conv+BN+act epilogue kernel (flag + TPU backend
-    via ops.pallas.config — tests patch `config.backend_is_tpu` — plus the
+    via ops.pallas.config — tests patch `config.kernel_enabled` — plus the
     kernel's own shape gates).  String paddings (SAME/VALID) stay on XLA."""
     if not (isinstance(padding, tuple) and data_format == "NHWC"):
         return False
@@ -79,8 +79,9 @@ def _use_pallas_conv(x, w, stride, padding, dilation, groups, act,
         return False
     from ..ops.pallas import conv_fused as _cf
 
-    return _cf.supported(x, w.shape, stride, padding, dilation, groups, act,
-                         data_format)
+    return _pcfg.counted(
+        "conv2d_bn_act", _cf.supported(x, w.shape, stride, padding, dilation,
+                                       groups, act, data_format))
 
 
 @register_op("fused_conv2d_bn_act")
@@ -179,9 +180,9 @@ def _quant_conv2d(ins, attrs, op):
         if _pcfg.kernel_enabled("use_pallas_int8"):
             from ..ops.pallas import int8 as _int8
 
-            use_pallas = _int8.conv_supported(
+            use_pallas = _pcfg.counted("int8_conv2d", _int8.conv_supported(
                 jax.ShapeDtypeStruct(x.shape, jnp.int8), w.shape, stride,
-                padding, dilation, groups, act, data_format)
+                padding, dilation, groups, act, data_format))
     if use_pallas:
         from ..ops.pallas import int8 as _int8
 
@@ -227,8 +228,8 @@ def _quant_mul(ins, attrs, op):
         if _pcfg.kernel_enabled("use_pallas_int8"):
             from ..ops.pallas import int8 as _int8
 
-            use_pallas = _int8.matmul_supported(
-                jax.ShapeDtypeStruct(x2_shape, jnp.int8), y2_shape, act)
+            use_pallas = _pcfg.counted("int8_matmul", _int8.matmul_supported(
+                jax.ShapeDtypeStruct(x2_shape, jnp.int8), y2_shape, act))
     if use_pallas:
         from ..ops.pallas import int8 as _int8
 

@@ -17,6 +17,7 @@ as ONE pjit'd train step over a dp×pp×ep×sp×tp mesh:
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -28,7 +29,6 @@ from .. import nn
 from ..autograd import functional_call, parameters_dict
 from ..core import random as _random
 from ..parallel import mesh as _mesh
-from ..parallel.collective import shard_map as _shard_map, _VMA_KW, _jax_shard_map
 from ..parallel.pipeline import (
     blockwise_stage_fn,
     microbatch,
@@ -211,15 +211,16 @@ class HybridPretrainer:
 
         blk_specs = jax.tree_util.tree_map(
             lambda _: PartitionSpec(_mesh.PP_AXIS), blocks)
-        f = _jax_shard_map(
+        f = jax.shard_map(
             run, mesh=self.mesh, in_specs=(blk_specs, PartitionSpec()),
             out_specs=PartitionSpec(),
-            axis_names={_mesh.PP_AXIS}, **{_VMA_KW: False})
+            axis_names={_mesh.PP_AXIS}, check_vma=False)
         return unmicrobatch(f(blocks, xs))
 
     def loss_fn(self, params, batch, key):
         cfg = self.cfg
-        with _random.rng_scope(key):
+        # kernel dispatch shards over THIS trainer's mesh (mesh_scope)
+        with _mesh.mesh_scope(self.mesh), _random.rng_scope(key):
             h = functional_call(self.embeddings, params["embed"],
                                 (batch["input_ids"], batch["token_type_ids"]))
             h = self._data_constraint(h)
@@ -353,13 +354,13 @@ class HybridPretrainer:
                     stage_fn, loss_fn, blk, hp, xs_, ts_,
                     axis=_mesh.PP_AXIS)
 
-            f = _jax_shard_map(
+            f = jax.shard_map(
                 run, mesh=self.mesh,
                 in_specs=(blk_specs, PartitionSpec(), PartitionSpec(),
                           PartitionSpec()),
                 out_specs=(PartitionSpec(), blk_specs, PartitionSpec(),
                            PartitionSpec()),
-                axis_names={_mesh.PP_AXIS}, **{_VMA_KW: False})
+                axis_names={_mesh.PP_AXIS}, check_vma=False)
             loss, sgrads, hgrads, dxs = f(p["blocks"], head_params, xs,
                                           targets)
             (egrads,) = vjp_embed(unmicrobatch(dxs))
@@ -377,7 +378,13 @@ class HybridPretrainer:
             new_state = self._zero_constrain(new_state)
             return new_params, new_state, loss
 
-        return train_step
+        @functools.wraps(train_step)
+        def scoped(*args):
+            # kernel dispatch shards over THIS trainer's mesh (mesh_scope)
+            with _mesh.mesh_scope(self.mesh):
+                return train_step(*args)
+
+        return scoped
 
     def data_shardings(self, mesh=None):
         m = mesh or self.mesh

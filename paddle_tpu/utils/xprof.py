@@ -129,17 +129,19 @@ class PeakSpec:
 
 def resolve_peaks(device_kind: Optional[str] = None,
                   peak_flops: Optional[float] = None,
-                  peak_bytes_per_sec: Optional[float] = None) -> PeakSpec:
-    """The peak spec for ``device_kind`` (default: the first jax device).
-    Explicit ``peak_flops``/``peak_bytes_per_sec`` override the table —
-    the escape hatch for new hardware."""
+                  peak_bytes_per_sec: Optional[float] = None,
+                  platform: Optional[str] = None) -> PeakSpec:
+    """The peak spec for ``device_kind`` (default: the first jax device,
+    whose ``platform`` is then read too).  Explicit ``peak_flops``/
+    ``peak_bytes_per_sec`` override the table — the escape hatch for new
+    hardware.  A kind the table does not know gets the order-of-magnitude
+    CPU spec on a CPU platform only: on any other platform it raises —
+    a device that is not in the table is an error, not a default."""
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "unknown"
+        dev = jax.devices()[0]
+        device_kind, platform = dev.device_kind, dev.platform
     if peak_flops is not None and peak_bytes_per_sec is not None:
         return PeakSpec(device_kind, peak_flops, peak_bytes_per_sec,
                         "override")
@@ -147,6 +149,11 @@ def resolve_peaks(device_kind: Optional[str] = None,
     for sub, fl, bw, hbm in _TPU_PEAKS:
         if sub in low:
             return PeakSpec(device_kind, fl, bw, "table", hbm_bytes=hbm)
+    if platform not in (None, "cpu"):
+        raise ValueError(
+            f"no peak spec for device_kind {device_kind!r} on platform "
+            f"{platform!r}: add it to utils/xprof._TPU_PEAKS or pass "
+            "peak_flops/peak_bytes_per_sec")
     fl, bw, hbm = _CPU_PEAK
     return PeakSpec(device_kind, fl, bw, "fallback", hbm_bytes=hbm)
 

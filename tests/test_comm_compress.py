@@ -45,11 +45,6 @@ from paddle_tpu.parallel.sharding import ShardingPlan
 from paddle_tpu.static import layers as L
 from paddle_tpu.utils import monitor
 
-try:
-    from jax import shard_map as _smap
-except ImportError:  # pragma: no cover - older jax spelling
-    from jax.experimental.shard_map import shard_map as _smap
-
 
 needs_devices = pytest.mark.skipif(
     jax.device_count() < 8, reason="needs the 8-device virtual CPU mesh")
@@ -73,11 +68,8 @@ def _mesh(n: int) -> Mesh:
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        return _smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-    except TypeError:  # newer jax renamed the replication-check kwarg
-        return _smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -621,9 +613,10 @@ def test_compile_cache_warm_start_under_comm_quantize(_flags_guard, tmp_path):
 @needs_devices
 def test_collbench_selfcheck():
     repo = Path(__file__).resolve().parents[1]
-    env = dict(os.environ, PYTHONPATH=str(repo) + os.pathsep
+    # the selfcheck is a CPU host-topology smoke (8 forced host devices)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(repo) + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
-    env.pop("JAX_PLATFORMS", None)  # collbench forces its own host topology
     proc = subprocess.run(
         [sys.executable, "-m", "tools.collbench", "--selfcheck"],
         cwd=repo, capture_output=True, text=True, timeout=580, env=env)

@@ -58,11 +58,11 @@ def test_dp_grads_match_single_device():
             g = jax.grad(loss_fn)(p, x, y)
             return apply_collective_grads(g)
 
-    # check_rep=True: apply_collective_grads reads each value's vma set to
+    # check_vma=True: apply_collective_grads reads each value's vma set to
     # pick pmean vs divide-by-n, so VMA tracking must stay on.
     sharded = shard_map(
         shard_step, mesh=mesh,
-        in_specs=(P(), P("dp"), P("dp")), out_specs=P(), check_rep=True)
+        in_specs=(P(), P("dp"), P("dp")), out_specs=P(), check_vma=True)
     dp_grads = sharded(params, jnp.asarray(X), jnp.asarray(Y))
     for k in ref_grads:
         np.testing.assert_allclose(np.asarray(dp_grads[k]),
@@ -81,7 +81,7 @@ def test_scale_loss_under_shard_map():
             return scale_loss(x.sum())[None]
 
     out = shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                    check_rep=True)(jnp.ones(8))
+                    check_vma=True)(jnp.ones(8))
     np.testing.assert_allclose(np.asarray(out), np.full(8, 1.0 / 8))
 
 

@@ -11,8 +11,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.core import flags
 from paddle_tpu.ops import attention as attn_ops
 from paddle_tpu.ops.attention import scaled_dot_product_attention as sdpa
+from paddle_tpu.ops.pallas import config as pcfg
 from paddle_tpu.ops.pallas import flash_attention as fa
 
 B, H, S, D = 2, 3, 128, 64
@@ -288,7 +290,8 @@ class TestPackedLayout:
             return out
 
         monkeypatch.setattr(attn_mod, "flash_attention_packed", spy)
-        monkeypatch.setattr(attn_mod, "_is_tpu", lambda: True)
+        monkeypatch.setattr(pcfg, "kernel_enabled",
+                            lambda name: bool(flags.get_flag(name)))
         out = functional_call(mha, p, (x,))
         assert calls == [True], "packed path did not engage"
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

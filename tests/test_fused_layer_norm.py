@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.core import flags
 from paddle_tpu.nn import functional as F
+from paddle_tpu.ops.pallas import config as pcfg
 from paddle_tpu.ops.pallas import layer_norm as fln
 
 
@@ -84,16 +86,16 @@ def test_functional_dispatch_respects_flag(monkeypatch):
     x = jnp.asarray(np.random.default_rng(2).normal(0, 1, (256, 128)), jnp.float32)
     w, b = jnp.ones((128,)), jnp.zeros((128,))
     # predicate: flag on + supported shape, but CPU backend -> False
-    assert not norm_mod._use_fused_ln(x, (128,))
+    assert not norm_mod._fused_ln_shards(x, (128,))
     # open the backend gate; keep the kernel itself in interpret mode
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(fln, "_interpret", lambda: True)
-    assert norm_mod._use_fused_ln(x, (128,))
+    monkeypatch.setattr(pcfg, "kernel_enabled",
+                        lambda name: bool(flags.get_flag(name)))
+    assert norm_mod._fused_ln_shards(x, (128,))
     out_fused = F.layer_norm(x, 128, w, b)   # dispatches to spy -> interpret kernel
     assert calls, "fused branch did not engage"
     flags.set_flags({"use_fused_layer_norm": False})
     try:
-        assert not norm_mod._use_fused_ln(x, (128,))
+        assert not norm_mod._fused_ln_shards(x, (128,))
         out_ref = F.layer_norm(x, 128, w, b)
     finally:
         flags.set_flags({"use_fused_layer_norm": True})
@@ -194,8 +196,8 @@ def test_encoder_layer_epilogue_fused_dispatch(monkeypatch):
         return orig(*a, **k)
 
     monkeypatch.setattr(fln, "fused_residual_dropout_layer_norm", spy)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(fln, "_interpret", lambda: True)
+    monkeypatch.setattr(pcfg, "kernel_enabled",
+                        lambda name: bool(flags.get_flag(name)))
     out = functional_call(enc, p, (x,))
     assert len(calls) == 2  # both sublayer epilogues fused
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,

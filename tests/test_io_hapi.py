@@ -28,6 +28,17 @@ class RangeDataset(Dataset):
         return self.n
 
 
+class _WorkerPlatformDataset(Dataset):
+    """1.0 where the worker process was started with JAX_PLATFORMS=cpu."""
+
+    def __getitem__(self, i):
+        import os
+        return np.float32(os.environ.get("JAX_PLATFORMS") == "cpu")
+
+    def __len__(self):
+        return 4
+
+
 class _FailingDataset(Dataset):
     def __init__(self, n):
         self.n = n
@@ -102,6 +113,18 @@ class TestDataLoader:
         np.testing.assert_allclose(xs, np.arange(23, dtype=np.float32))
         ys = np.concatenate([b[1] for b in got])
         np.testing.assert_array_equal(ys, np.arange(23) % 3)
+
+    def test_multiprocess_workers_pinned_to_cpu(self, monkeypatch):
+        """A chip belongs to one process: spawned workers start with
+        JAX_PLATFORMS=cpu whatever the parent runs on, and the parent's
+        environment is left as it was."""
+        import os
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        dl = DataLoader(_WorkerPlatformDataset(), batch_size=2,
+                        num_workers=2, use_shared_memory=True)
+        got = np.concatenate([b for b in dl])
+        np.testing.assert_array_equal(got, np.ones(4, np.float32))
+        assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"
 
     def test_multiprocess_worker_error_propagates(self):
         dl = DataLoader(_FailingDataset(10), batch_size=2, num_workers=2,

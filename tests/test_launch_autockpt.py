@@ -47,6 +47,25 @@ def test_launch_sets_trainer_env_and_collects_all(tmp_path):
     assert (tmp_path / "logs" / "worker.0.log").exists()
 
 
+def test_launch_refuses_multiprocess_on_tpu_host(tmp_path, monkeypatch):
+    """A chip belongs to one process: nproc>1 where the workers would take
+    the TPU is refused before anything is spawned; a CPU environment is
+    answered from JAX_PLATFORMS alone (no probe child)."""
+    from paddle_tpu.distributed import launch as launch_mod
+
+    assert not launch_mod._workers_take_tpu({"JAX_PLATFORMS": "cpu"})
+    marker = tmp_path / "ran.txt"
+    script = _worker_script(tmp_path, f"""
+        open({str(marker)!r}, "w").write("ran")
+    """)
+    monkeypatch.setattr(launch_mod, "_workers_take_tpu", lambda env: True)
+    with pytest.raises(RuntimeError, match="one process"):
+        launch(script, [], nproc=2)
+    assert not marker.exists()
+    assert launch(script, [], nproc=1) == 0   # one process per host is fine
+    assert marker.exists()
+
+
 def test_launch_propagates_failure_and_kills_peers(tmp_path):
     marker = tmp_path / "late.txt"
     script = _worker_script(tmp_path, f"""

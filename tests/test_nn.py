@@ -256,7 +256,7 @@ class TestAutogradBridge:
 
 def test_linear_transpose_dw_schedule_matches_default(monkeypatch):
     """PDTPU_LINEAR_DW=transpose (the recorded dW-schedule experiment,
-    BASELINE.md r04) must be numerically identical to the default path."""
+    ROADMAP "Recorded non-wins", r04) must be numerically identical to the default path."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -311,8 +311,7 @@ def _kernel_case(rng, dtype, num_seqs=4, max_blocks=3, block_size=8, d=128):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
-def test_paged_attention_kernel_matches_reference(monkeypatch, dtype):
-    monkeypatch.setattr(pcfg, "backend_is_tpu", lambda: True)
+def test_paged_attention_kernel_matches_reference(dtype):
     rng = np.random.default_rng(6)
     args, kw, lens = _kernel_case(rng, dtype)
     assert pa.supported(args[0].shape[0], args[1].shape[1],

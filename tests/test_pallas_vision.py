@@ -77,7 +77,8 @@ def _flags_guard():
 def _tpu_gate(monkeypatch):
     """Force `kernel_enabled` open on CPU CI: kernels run in Pallas
     interpret mode, exercising the exact code a TPU compiles."""
-    monkeypatch.setattr(pcfg, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pcfg, "kernel_enabled",
+                        lambda name: bool(flags.get_flag(name)))
 
 
 def _init_state(startup):
@@ -182,17 +183,19 @@ def test_pooling_kernel_parity(shape, kernel, stride, padding):
 
 def test_avg_pool_exclusive_with_padding_is_gated_out(_tpu_gate):
     x = jnp.zeros((1, 8, 8, 128), jnp.float32)
-    assert ppool.supported(x, (2, 2), (2, 2), (0, 0), "avg", True)
+    assert ppool.supported(x, (2, 2), (1, 1), (0, 0), "avg", True)
     # exclusive + padding needs per-position counts: XLA fallback
-    assert not ppool.supported(x, (3, 3), (2, 2), (1, 1), "avg", True)
-    assert ppool.supported(x, (3, 3), (2, 2), (1, 1), "avg", False)
+    assert not ppool.supported(x, (3, 3), (1, 1), (1, 1), "avg", True)
+    assert ppool.supported(x, (3, 3), (1, 1), (1, 1), "avg", False)
+    # stride 2: Mosaic refuses the strided window slice (gated, not tried)
+    assert not ppool.supported(x, (2, 2), (2, 2), (0, 0), "max", True)
 
     xr = RNG.normal(size=(1, 8, 8, 128)).astype(np.float32)
-    got = F.avg_pool2d(xr, 3, stride=2, padding=1, exclusive=True,
+    got = F.avg_pool2d(xr, 3, stride=1, padding=1, exclusive=True,
                        data_format="NHWC")
     flags.set_flags({"use_pallas_pool": False})
     try:
-        want = F.avg_pool2d(xr, 3, stride=2, padding=1, exclusive=True,
+        want = F.avg_pool2d(xr, 3, stride=1, padding=1, exclusive=True,
                             data_format="NHWC")
     finally:
         flags.set_flags({"use_pallas_pool": True})
@@ -208,14 +211,18 @@ def test_functional_pool_dispatch_parity(_flags_guard, _tpu_gate):
     base = reg.get("pallas.kernel_calls")
     calls0 = sum(v for _l, v in base.samples()) if base is not None else 0
 
-    got = F.max_pool2d(x, 2, stride=2, data_format="NHWC")
-    flags.set_flags({"use_pallas_pool": False})
-    want = F.max_pool2d(x, 2, stride=2, data_format="NHWC")
-    np.testing.assert_array_equal(got, want)
-
+    got = F.max_pool2d(x, 2, stride=1, data_format="NHWC")
     calls = reg.get("pallas.kernel_calls")
     calls1 = sum(v for _l, v in calls.samples()) if calls is not None else 0
     assert calls1 > calls0  # the Pallas branch actually ran
+    # stride 2 is gated out (a Mosaic refusal): same entry, XLA lowering
+    got2 = F.max_pool2d(x, 2, stride=2, data_format="NHWC")
+    assert sum(v for _l, v in calls.samples()) == calls1
+    flags.set_flags({"use_pallas_pool": False})
+    np.testing.assert_array_equal(
+        got, F.max_pool2d(x, 2, stride=1, data_format="NHWC"))
+    np.testing.assert_array_equal(
+        got2, F.max_pool2d(x, 2, stride=2, data_format="NHWC"))
 
 
 # ---------------------------------------------------------------------------
@@ -424,7 +431,8 @@ def test_kernel_fingerprint_zero_retraces_and_flag_flip(_flags_guard,
         assert reg.get("executor.traces").value() == t0  # steady state
 
         # flag flip (gate opens) -> different executable -> ONE recompile
-        monkeypatch.setattr(pcfg, "backend_is_tpu", lambda: True)
+        monkeypatch.setattr(pcfg, "kernel_enabled",
+                            lambda name: bool(flags.get_flag(name)))
         assert pcfg.cache_key_part() != ""
         gated_out, = exe.run(main, feed=feed, fetch_list=[y])
         t1 = reg.get("executor.traces").value()
@@ -434,7 +442,7 @@ def test_kernel_fingerprint_zero_retraces_and_flag_flip(_flags_guard,
 
         # flip back: the pre-flip executable is still cold-cached — no
         # retrace, and no stale cross-config hit either direction
-        monkeypatch.setattr(pcfg, "backend_is_tpu", lambda: False)
+        monkeypatch.setattr(pcfg, "kernel_enabled", lambda name: False)
         assert pcfg.cache_key_part() == ""
         back_out, = exe.run(main, feed=feed, fetch_list=[y])
         assert reg.get("executor.traces").value() == t1

@@ -61,7 +61,7 @@ def test_all_reduce_traced_psum():
         return collective.all_reduce(x, group="tp")
 
     g = shard_map(f, mesh=m, in_specs=(PartitionSpec("tp"),),
-                  out_specs=PartitionSpec("tp"), check_rep=False)
+                  out_specs=PartitionSpec("tp"), check_vma=False)
     x = jnp.arange(4.0)
     out = g(x)  # two tp shards [0,1],[2,3] -> each psums to [2,4]
     np.testing.assert_allclose(np.asarray(out), [2., 4., 2., 4.])
@@ -74,7 +74,7 @@ def test_all_reduce_ops():
         def f(x):
             return collective.all_reduce(x, op=op, group="tp")
         return shard_map(f, mesh=m, in_specs=(PartitionSpec("tp"),),
-                         out_specs=PartitionSpec("tp"), check_rep=False)(
+                         out_specs=PartitionSpec("tp"), check_vma=False)(
             jnp.array([1.0, 2.0, 3.0, 4.0]))
 
     np.testing.assert_allclose(np.asarray(run("max")), [3, 4, 3, 4])
@@ -90,7 +90,7 @@ def test_all_gather_traced_and_eager():
         return collective.all_gather(x, group="tp")
 
     out = shard_map(f, mesh=m, in_specs=(PartitionSpec("tp"),),
-                    out_specs=PartitionSpec(("dp", "tp")), check_rep=False)(
+                    out_specs=PartitionSpec(("dp", "tp")), check_vma=False)(
         jnp.arange(4.0))
     # every tp rank gathers the full [0..3]; dp=2 ranks each contribute a copy
     assert out.shape == (32,) or out.shape == (16,)
@@ -109,7 +109,7 @@ def test_reduce_scatter_traced():
         return collective.reduce_scatter(x, group="tp")
 
     out = shard_map(f, mesh=m, in_specs=(PartitionSpec(None),),
-                    out_specs=PartitionSpec("tp"), check_rep=False)(
+                    out_specs=PartitionSpec("tp"), check_vma=False)(
         jnp.arange(4.0))
     # each rank holds replicated [0,1,2,3]; psum_scatter -> rank0 [0,2] rank1 [4,6]
     np.testing.assert_allclose(np.asarray(out), [0., 2., 4., 6.])
@@ -122,7 +122,7 @@ def test_broadcast_traced():
         return collective.broadcast(x, src=1, group="tp")
 
     out = shard_map(f, mesh=m, in_specs=(PartitionSpec("tp"),),
-                    out_specs=PartitionSpec("tp"), check_rep=False)(
+                    out_specs=PartitionSpec("tp"), check_vma=False)(
         jnp.array([10.0, 20.0]))
     np.testing.assert_allclose(np.asarray(out), [20., 20.])
 
@@ -135,7 +135,7 @@ def test_all_to_all_traced():
 
     x = jnp.arange(8.0).reshape(4, 2)  # per rank: (2,2) after tp split on dim0
     out = shard_map(f, mesh=m, in_specs=(PartitionSpec("tp", None),),
-                    out_specs=PartitionSpec("tp", None), check_rep=False)(x)
+                    out_specs=PartitionSpec("tp", None), check_vma=False)(x)
     assert out.shape == (2, 4)
 
 

@@ -66,7 +66,7 @@ def test_pipeline_matches_sequential():
     f = shard_map(run, mesh=m,
                   in_specs=({"w": PartitionSpec("pp"), "b": PartitionSpec("pp")},
                             PartitionSpec()),
-                  out_specs=PartitionSpec(), check_rep=False)
+                  out_specs=PartitionSpec(), check_vma=False)
     out = unmicrobatch(f(stacked, microbatch(x, 4)))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
@@ -93,7 +93,7 @@ def test_pipeline_gradients_match_sequential():
         f = shard_map(run, mesh=m,
                       in_specs=({"w": PartitionSpec("pp"), "b": PartitionSpec("pp")},
                                 PartitionSpec()),
-                      out_specs=PartitionSpec(), check_rep=False)
+                      out_specs=PartitionSpec(), check_vma=False)
         out = unmicrobatch(f(p, microbatch(x, 2)))
         return jnp.sum(out ** 2)
 
@@ -159,7 +159,7 @@ def _run_1f1b(m, stacked, head, x, tgts, num_micro):
                             PartitionSpec()),
                   out_specs=(PartitionSpec(), pspec, PartitionSpec(),
                              PartitionSpec()),
-                  check_rep=False)
+                  check_vma=False)
     return f(stacked, head, microbatch(x, num_micro),
              microbatch(tgts, num_micro))
 
@@ -233,14 +233,14 @@ def test_pipeline_1f1b_peak_memory_below_gpipe():
                                      PartitionSpec()),
                            out_specs=(PartitionSpec(), pspec, PartitionSpec(),
                                       PartitionSpec()),
-                           check_rep=False))
+                           check_vma=False))
 
     def gpipe_loss(p, hp, xs):
         def run(pp_params, xs_):
             return pipeline_apply(gstage, pp_params, xs_, axis="pp")
 
         g = shard_map(run, mesh=m, in_specs=(pspec, PartitionSpec()),
-                      out_specs=PartitionSpec(), check_rep=False)
+                      out_specs=PartitionSpec(), check_vma=False)
         ys = g(p, xs)
         pred = ys @ hp["w_out"]
         return jnp.mean((pred - microbatch(tgts, num_micro)) ** 2)

@@ -43,7 +43,7 @@ def test_ring_attention_matches_full(causal):
         lambda q_, k_, v_: ring_attention(q_, k_, v_, axis="sp", causal=causal),
         mesh=m,
         in_specs=(PartitionSpec(None, None, "sp"),) * 3,
-        out_specs=PartitionSpec(None, None, "sp"), check_rep=False)
+        out_specs=PartitionSpec(None, None, "sp"), check_vma=False)
     out = f(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
@@ -62,7 +62,7 @@ def test_ring_attention_grads_match_full():
         f = shard_map(
             lambda a, b, c: ring_attention(a, b, c, axis="sp", causal=True),
             mesh=m, in_specs=(PartitionSpec(None, None, "sp"),) * 3,
-            out_specs=PartitionSpec(None, None, "sp"), check_rep=False)
+            out_specs=PartitionSpec(None, None, "sp"), check_vma=False)
         return jnp.sum(f(q_, k_, v_) ** 2)
 
     g_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
@@ -81,7 +81,7 @@ def test_ulysses_matches_full(causal):
         lambda q_, k_, v_: ulysses_attention(q_, k_, v_, axis="sp",
                                              causal=causal),
         mesh=m, in_specs=(PartitionSpec(None, None, "sp"),) * 3,
-        out_specs=PartitionSpec(None, None, "sp"), check_rep=False)
+        out_specs=PartitionSpec(None, None, "sp"), check_vma=False)
     out = f(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
@@ -93,6 +93,6 @@ def test_ulysses_rejects_indivisible_heads():
     f = shard_map(
         lambda q_, k_, v_: ulysses_attention(q_, k_, v_, axis="sp"),
         mesh=m, in_specs=(PartitionSpec(None, None, "sp"),) * 3,
-        out_specs=PartitionSpec(None, None, "sp"), check_rep=False)
+        out_specs=PartitionSpec(None, None, "sp"), check_vma=False)
     with pytest.raises(ValueError, match="not divisible"):
         f(q, k, v)

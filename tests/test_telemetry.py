@@ -361,9 +361,21 @@ def test_benchdiff_passes_identical_fails_seeded_regression(tmp_path):
 def test_benchdiff_reads_real_bench_ledger_and_record_schema(tmp_path):
     from tools import benchdiff
 
-    # the repo's own ledger files parse (all three schemas)
-    for f in ("BENCH_r05.json", "BENCH_VISION.json", "BENCH_SERVE.json"):
-        metrics = benchdiff.extract_metrics(os.path.join(REPO, f))
+    # all three record schemas parse: the driver's {"parsed": {...}} line,
+    # a {"results": [...]} table (both built here with made-up values),
+    # and the repo's own nested BENCH_SERVE.json
+    driver = _bench(tmp_path, "driver.json", {
+        "n": 1, "cmd": "python bench.py", "rc": 0,
+        "parsed": {"metric": "ernie_base_pretrain_throughput",
+                   "value": 1000.0, "unit": "tokens/sec/chip",
+                   "platform": "tpu", "batch": 64, "seq_len": 512}})
+    table = _bench(tmp_path, "table.json", {"results": [
+        {"metric": "resnet50_train_throughput", "value": 10.0,
+         "unit": "imgs/sec/chip", "platform": "tpu", "mfu_est": 0.1},
+        {"metric": "conv_infer_throughput", "value": 20.0,
+         "unit": "imgs/sec/chip", "platform": "cpu", "mfu_est": None}]})
+    for f in (driver, table, os.path.join(REPO, "BENCH_SERVE.json")):
+        metrics = benchdiff.extract_metrics(f)
         assert metrics, f
     serve = benchdiff.extract_metrics(os.path.join(REPO, "BENCH_SERVE.json"))
     assert "batched.qps" in serve            # nested record flattening

@@ -146,9 +146,14 @@ def test_peak_table_and_overrides():
     over = xprof.resolve_peaks(device_kind="x", peak_flops=1e12,
                                peak_bytes_per_sec=1e11)
     assert over.source == "override" and over.ridge == 10.0
-    cpu = xprof.resolve_peaks(device_kind="epyc rome 9000")
-    assert cpu.kind == "epyc rome 9000"  # unknown -> CPU fallback peaks
+    cpu = xprof.resolve_peaks(device_kind="epyc rome 9000", platform="cpu")
+    assert cpu.kind == "epyc rome 9000"  # unknown CPU -> order-of-magnitude
     assert cpu.flops_per_sec > 0 and cpu.bytes_per_sec > 0
+    assert xprof.resolve_peaks().source == "fallback"  # the local CPU
+    with pytest.raises(ValueError, match="no peak spec"):
+        xprof.resolve_peaks(device_kind="TPU v9 mega", platform="tpu")
+    assert xprof.resolve_peaks(device_kind="TPU v5 lite",
+                               platform="tpu").source == "table"
 
 
 # ---------------------------------------------------------------------------

@@ -304,6 +304,9 @@ def main(argv=None) -> int:
                    help="small sizes + acceptance gates; exit 0/1")
     args = p.parse_args(argv)
     _ensure_cpu_devices(args.devices)
+    from paddle_tpu.core.jax_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.selfcheck:
         args.mb, args.iters, args.steps = 0.25, 3, 12
         args.batch, args.dim = 64, 16

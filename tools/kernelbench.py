@@ -206,8 +206,10 @@ def main(argv=None) -> int:
 
     import jax
 
+    from paddle_tpu.core.jax_cache import configure_compile_cache
     from paddle_tpu.ops.pallas import config as _pcfg
 
+    configure_compile_cache()
     rows = run_bench(args.iters, args.batch, args.hw, args.ch, args.mk)
     result = {
         "backend": jax.default_backend(),

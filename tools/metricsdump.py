@@ -96,7 +96,10 @@ _KNOWN_NAMES = frozenset({
     "ledger.drift_alarms",
     "ledger.drift_ratio",
     "ledger.records",
-    # ops/pallas/config.py (kernel dispatch telemetry)
+    # ops/pallas/config.py (kernel dispatch telemetry); kernel= label
+    # values: flash_attention, flash_attention_packed, fused_layer_norm,
+    # fused_rdln, conv2d_bn_act, bn_act_train, max_pool2d, avg_pool2d,
+    # int8_matmul, int8_conv2d, paged_attention
     "pallas.fallbacks",
     "pallas.kernel_calls",
     # static/passes.py (graph-rewrite pipeline)

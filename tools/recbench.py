@@ -249,6 +249,9 @@ def main(argv=None) -> int:
                    help="small sizes + acceptance gates; exit 0/1")
     args = p.parse_args(argv)
     _ensure_cpu_devices(args.devices)
+    from paddle_tpu.core.jax_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.selfcheck:
         args.vocab, args.dim, args.slots = 64, 8, 4
         args.batch, args.steps, args.serve_rows = 32, 6, 64

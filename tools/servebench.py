@@ -461,6 +461,9 @@ def _parser():
 
 def main(argv=None) -> int:
     args = _parser().parse_args(argv)
+    from paddle_tpu.core.jax_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.selfcheck:
         return _selfcheck()
     rec = run_bench(args)

@@ -504,10 +504,14 @@ def main(argv=None) -> int:
     parser.add_argument("--selfcheck", action="store_true",
                         help="tiny smoke run with field/parity checks")
     args = parser.parse_args(argv)
+    n_dev = 2 if args.selfcheck else max(args.mesh, args.autoplan)
+    if n_dev > 1:
+        _ensure_cpu_devices(n_dev)  # before anything imports jax
+    from paddle_tpu.core.jax_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.selfcheck:
         return selfcheck()
-    if max(args.mesh, args.autoplan) > 1:
-        _ensure_cpu_devices(max(args.mesh, args.autoplan))
     if args.cache == "":
         with tempfile.TemporaryDirectory(prefix="pdtpu_stepbench_cc_") as cc:
             r = run_bench(steps=args.steps, batch=args.batch,
