@@ -9,6 +9,7 @@ replaces both reference adapters and is where the MXU actually gets fed.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -94,6 +95,18 @@ class _LazyLogs(dict):
 
     def copy(self):
         return dict(self.materialize())
+
+
+def _traced_steps(batches, step_nums):
+    """Each batch's turn of the fit loop inside a
+    ``jax.profiler.StepTraceAnnotation``: a ``start_device_trace`` capture
+    then holds the product's steps on the device trace's clock (the wait
+    for the next batch lies between two steps).  Costs nothing measurable
+    while no capture runs."""
+    for batch in batches:
+        with jax.profiler.StepTraceAnnotation("train",
+                                              step_num=next(step_nums)):
+            yield batch
 
 
 class Model:
@@ -247,6 +260,7 @@ class Model:
                                           "log_freq": log_freq})
         cbs.on_train_begin()
         self.stop_training = False
+        step_nums = itertools.count()
         for epoch in range(epochs):
             cbs.on_epoch_begin(epoch)
             self.network.train()
@@ -261,7 +275,7 @@ class Model:
                 batches = device_prefetch(
                     loader, device=None if prefetch_to_device is True
                     else prefetch_to_device)
-            for step, batch in enumerate(batches):
+            for step, batch in enumerate(_traced_steps(batches, step_nums)):
                 cbs.on_train_batch_begin(step)
                 inputs, labels = self._split_batch(batch)
                 if _tape.enabled():

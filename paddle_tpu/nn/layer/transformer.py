@@ -10,9 +10,11 @@ from __future__ import annotations
 import collections
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ...ops import attention as attn_ops
+from ...utils import xprof as _xprof
 from .. import functional as F
 from ..functional.norm import _fused_ln_shards
 from .base import Layer, LayerList
@@ -58,9 +60,10 @@ class MultiHeadAttention(Layer):
             qp = self.q_proj(query)
             kp = self.k_proj(key)
             vp = self.v_proj(value)
-            out = attn_ops.flash_attention_packed(
-                qp, kp, vp, self.num_heads, attn_mask=attn_mask,
-                dropout_p=self.dropout, training=self.training)
+            with jax.named_scope(_xprof.ATTN_CORE):
+                out = attn_ops.flash_attention_packed(
+                    qp, kp, vp, self.num_heads, attn_mask=attn_mask,
+                    dropout_p=self.dropout, training=self.training)
             if out is not None:
                 return self.out_proj(out)
             q = self._split_heads(qp)
@@ -81,6 +84,20 @@ class MultiHeadAttention(Layer):
         return self._attend(q, k, v, attn_mask, cache)
 
     def _attend(self, q, k, v, attn_mask, cache):
+        with jax.named_scope(_xprof.ATTN_CORE):
+            out, weights = self._attend_core(q, k, v, attn_mask)
+        b, h, s, d = out.shape
+        out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+        out = self.out_proj(out)
+        outs = (out,)
+        if self.need_weights:
+            outs += (weights,)
+        if isinstance(cache, MultiHeadAttention.Cache):
+            outs += (cache,)
+        return outs if len(outs) > 1 else out
+
+    def _attend_core(self, q, k, v, attn_mask):
+        """scores -> softmax -> values on split heads: (out, weights)."""
         weights = None
         if self.need_weights:
             # explicit-weights path (flash kernel never materializes them)
@@ -105,15 +122,7 @@ class MultiHeadAttention(Layer):
             out = attn_ops.flash_attention(
                 q, k, v, attn_mask=attn_mask, dropout_p=self.dropout,
                 training=self.training)
-        b, h, s, d = out.shape
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-        out = self.out_proj(out)
-        outs = (out,)
-        if self.need_weights:
-            outs += (weights,)
-        if isinstance(cache, MultiHeadAttention.Cache):
-            outs += (cache,)
-        return outs if len(outs) > 1 else out
+        return out, weights
 
     def gen_cache(self, key, value=None, type=None):
         if type == MultiHeadAttention.StaticCache:
@@ -125,6 +134,7 @@ class MultiHeadAttention(Layer):
         return MultiHeadAttention.Cache(k, k)
 
 
+@jax.named_scope(_xprof.REGION_LN)
 def _sublayer_epilogue(layer, out, residual, norm, dropout_layer):
     """src = norm(residual + dropout(out)) — the post-LN sublayer tail
     shared by encoder AND decoder layers.  On TPU this dispatches to the
@@ -156,6 +166,18 @@ def _sublayer_epilogue(layer, out, residual, norm, dropout_layer):
     return src
 
 
+@jax.named_scope(_xprof.REGION_LN)
+def _pre_norm(norm, x):
+    """The pre-LN sublayer head (``normalize_before``)."""
+    return norm(x)
+
+
+@jax.named_scope(_xprof.REGION_FFN)
+def _ffn(layer, x):
+    """Both products and the activation, encoder and decoder layers."""
+    return layer.linear2(layer.act_dropout(layer.activation(layer.linear1(x))))
+
+
 class TransformerEncoderLayer(Layer):
     """ref: transformer.py TransformerEncoderLayer (normalize_before toggles
     pre-/post-LN)."""
@@ -180,18 +202,19 @@ class TransformerEncoderLayer(Layer):
     def forward(self, src, src_mask=None, cache=None):
         residual = src
         if self.normalize_before:
-            src = self.norm1(src)
-        if cache is None:
-            out = self.self_attn(src, src, src, attn_mask=src_mask)
-        else:
-            out, cache = self.self_attn(src, src, src, attn_mask=src_mask,
-                                        cache=cache)
+            src = _pre_norm(self.norm1, src)
+        with jax.named_scope(_xprof.REGION_ATTN):
+            if cache is None:
+                out = self.self_attn(src, src, src, attn_mask=src_mask)
+            else:
+                out, cache = self.self_attn(src, src, src,
+                                            attn_mask=src_mask, cache=cache)
         src = _sublayer_epilogue(self, out, residual, self.norm1,
                                  self.dropout1)
         residual = src
         if self.normalize_before:
-            src = self.norm2(src)
-        src = self.linear2(self.act_dropout(self.activation(self.linear1(src))))
+            src = _pre_norm(self.norm2, src)
+        src = _ffn(self, src)
         src = _sublayer_epilogue(self, src, residual, self.norm2,
                                  self.dropout2)
         return src if cache is None else (src, cache)
@@ -273,27 +296,29 @@ class TransformerDecoderLayer(Layer):
     def forward(self, tgt, memory, tgt_mask=None, memory_mask=None, cache=None):
         residual = tgt
         if self.normalize_before:
-            tgt = self.norm1(tgt)
-        if cache is None:
-            out = self.self_attn(tgt, tgt, tgt, attn_mask=tgt_mask)
-        else:
-            out, sc = self.self_attn(tgt, tgt, tgt, attn_mask=tgt_mask,
-                                     cache=cache[0])
+            tgt = _pre_norm(self.norm1, tgt)
+        with jax.named_scope(_xprof.REGION_ATTN):
+            if cache is None:
+                out = self.self_attn(tgt, tgt, tgt, attn_mask=tgt_mask)
+            else:
+                out, sc = self.self_attn(tgt, tgt, tgt, attn_mask=tgt_mask,
+                                         cache=cache[0])
         tgt = _sublayer_epilogue(self, out, residual, self.norm1,
                                  self.dropout1)
         residual = tgt
         if self.normalize_before:
-            tgt = self.norm2(tgt)
-        out = self.cross_attn(tgt, memory, memory, attn_mask=memory_mask,
-                              cache=cache[1] if cache is not None and
-                              isinstance(cache[1], MultiHeadAttention.StaticCache)
-                              else None)
+            tgt = _pre_norm(self.norm2, tgt)
+        with jax.named_scope(_xprof.REGION_ATTN):
+            out = self.cross_attn(
+                tgt, memory, memory, attn_mask=memory_mask,
+                cache=cache[1] if cache is not None and isinstance(
+                    cache[1], MultiHeadAttention.StaticCache) else None)
         tgt = _sublayer_epilogue(self, out, residual, self.norm2,
                                  self.dropout2)
         residual = tgt
         if self.normalize_before:
-            tgt = self.norm3(tgt)
-        tgt = self.linear2(self.act_dropout(self.activation(self.linear1(tgt))))
+            tgt = _pre_norm(self.norm3, tgt)
+        tgt = _ffn(self, tgt)
         tgt = _sublayer_epilogue(self, tgt, residual, self.norm3,
                                  self.dropout3)
         return tgt if cache is None else (tgt, (sc, cache[1]))

@@ -37,6 +37,7 @@ from ..parallel.pipeline import (
     unmicrobatch,
 )
 from ..parallel.sharding import TRANSFORMER_RULES, infer_sharding
+from ..utils import xprof as _xprof
 from .ernie import ErnieConfig, ErnieEmbeddings, ErniePretrainingCriterion
 
 
@@ -56,8 +57,14 @@ class _MoEBlock(nn.Layer):
         self.dropout = nn.Dropout(cfg.hidden_dropout_prob)
 
     def forward(self, x):
-        x = self.norm1(x + self.dropout(self.self_attn(x)))
-        x = self.norm2(x + self.dropout(self.moe(x)))
+        with jax.named_scope(_xprof.REGION_ATTN):
+            out = self.self_attn(x)
+        with jax.named_scope(_xprof.REGION_LN):
+            x = self.norm1(x + self.dropout(out))
+        with jax.named_scope(_xprof.REGION_FFN):
+            out = self.moe(x)
+        with jax.named_scope(_xprof.REGION_LN):
+            x = self.norm2(x + self.dropout(out))
         return x
 
 
@@ -221,17 +228,23 @@ class HybridPretrainer:
         cfg = self.cfg
         # kernel dispatch shards over THIS trainer's mesh (mesh_scope)
         with _mesh.mesh_scope(self.mesh), _random.rng_scope(key):
-            h = functional_call(self.embeddings, params["embed"],
-                                (batch["input_ids"], batch["token_type_ids"]))
-            h = self._data_constraint(h)
-            h = self._encode(params["blocks"], h)
+            with jax.named_scope(_xprof.REGION_EMBED):
+                h = functional_call(
+                    self.embeddings, params["embed"],
+                    (batch["input_ids"], batch["token_type_ids"]))
+                h = self._data_constraint(h)
+            with jax.named_scope(_xprof.REGION_ENCODER):
+                h = self._encode(params["blocks"], h)
             head_params = dict(params["head"])
             head_params[self._TIED] = params["embed"][self._EMB]
-            logits, nsp = functional_call(
-                self.head, head_params, (h, batch.get("masked_positions")))
-        loss = self.criterion(logits.astype(jnp.float32),
-                              nsp.astype(jnp.float32),
-                              batch["mlm_labels"], batch["nsp_labels"])
+            with jax.named_scope(_xprof.REGION_HEAD):
+                logits, nsp = functional_call(
+                    self.head, head_params,
+                    (h, batch.get("masked_positions")))
+        with jax.named_scope(_xprof.REGION_LOSS):
+            loss = self.criterion(logits.astype(jnp.float32),
+                                  nsp.astype(jnp.float32),
+                                  batch["mlm_labels"], batch["nsp_labels"])
         # MoE load-balancing aux loss is not added here: the blocks run under
         # lax.scan (and the pp shard_map), so the per-block aux values are
         # trace-local.  Custom loops wanting it should call
@@ -255,18 +268,25 @@ class HybridPretrainer:
 
         def train_step(params, opt_state, batch, key):
             def _loss(p):
-                if compute_dtype != jnp.float32:
-                    p = jax.tree_util.tree_map(
-                        lambda x: x.astype(compute_dtype)
-                        if jnp.issubdtype(x.dtype, jnp.floating) else x, p)
-                return self.loss_fn(p, batch, key)
+                return self.loss_fn(_cast_floating(p, compute_dtype), batch,
+                                    key)
 
             loss, grads = jax.value_and_grad(_loss)(params)
-            new_params, new_state = optimizer.update(grads, opt_state, params)
-            new_state = self._zero_constrain(new_state)
+            new_params, new_state = self._update(optimizer, grads, opt_state,
+                                                 params)
             return new_params, new_state, loss
 
         return train_step
+
+    @jax.named_scope(_xprof.REGION_OPTIMIZER)
+    def _update(self, optimizer, grads, opt_state, params):
+        """The update over every leaf (gradients cast to the parameters'
+        dtype first), then the ZeRO constraint: both schedules' tail."""
+        grads = jax.tree_util.tree_map(
+            lambda g, q: g.astype(q.dtype), grads, params,
+            is_leaf=lambda x: not isinstance(x, dict))
+        new_params, new_state = optimizer.update(grads, opt_state, params)
+        return new_params, self._zero_constrain(new_state)
 
     def _zero_constrain(self, opt_state):
         """ZeRO-1 (fleet sharding strategy): constrain fp32 optimizer-state
@@ -302,14 +322,11 @@ class HybridPretrainer:
         from ..parallel.pipeline import pipeline_train_1f1b
 
         def train_step(params, opt_state, batch, key):
-            p = params
-            if compute_dtype != jnp.float32:
-                p = jax.tree_util.tree_map(
-                    lambda x: x.astype(compute_dtype)
-                    if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
+            p = _cast_floating(params, compute_dtype)
 
             block_fn = self._block_fn()
 
+            @jax.named_scope(_xprof.REGION_ENCODER)
             def stage_fn(blk, x, micro_idx):
                 with _random.rng_scope(
                         jax.random.fold_in(key, 2 * micro_idx + 2)):
@@ -323,13 +340,16 @@ class HybridPretrainer:
                 # odd salts for the head (even+2 are the stages'): per-micro
                 # head randomness advances like the GPipe stream would
                 with _random.rng_scope(
-                        jax.random.fold_in(key, 2 * micro_idx + 3)):
+                        jax.random.fold_in(key, 2 * micro_idx + 3)), \
+                        jax.named_scope(_xprof.REGION_HEAD):
                     logits, nsp = functional_call(
                         self.head, hp, (y, tgt.get("masked_positions")))
-                return self.criterion(
-                    logits.astype(jnp.float32), nsp.astype(jnp.float32),
-                    tgt["mlm_labels"], tgt["nsp_labels"])
+                with jax.named_scope(_xprof.REGION_LOSS):
+                    return self.criterion(
+                        logits.astype(jnp.float32), nsp.astype(jnp.float32),
+                        tgt["mlm_labels"], tgt["nsp_labels"])
 
+            @jax.named_scope(_xprof.REGION_EMBED)
             def embed_fn(ep):
                 with _random.rng_scope(jax.random.fold_in(key, 0)):
                     h = functional_call(
@@ -371,11 +391,8 @@ class HybridPretrainer:
             egrads[self._EMB] = egrads[self._EMB] + tied_g
             grads = {"embed": egrads, "blocks": dict(sgrads),
                      "head": hgrads}
-            grads = jax.tree_util.tree_map(
-                lambda g, q: g.astype(q.dtype), grads, params,
-                is_leaf=lambda x: not isinstance(x, dict))
-            new_params, new_state = optimizer.update(grads, opt_state, params)
-            new_state = self._zero_constrain(new_state)
+            new_params, new_state = self._update(optimizer, grads, opt_state,
+                                                 params)
             return new_params, new_state, loss
 
         @functools.wraps(train_step)
@@ -428,6 +445,17 @@ class _CloneList(nn.Layer):
             _reinit(c)
             clones.append(c)
         self.layers = nn.LayerList(clones)
+
+
+@jax.named_scope(_xprof.REGION_OPTIMIZER)
+def _cast_floating(params, compute_dtype):
+    """The weights in the compute dtype (differentiated through, the
+    gradients' cast back lands in the same region)."""
+    if compute_dtype == jnp.float32:
+        return params
+    return jax.tree_util.tree_map(
+        lambda x: x.astype(compute_dtype)
+        if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
 
 
 def _find_param(layer, name: str):

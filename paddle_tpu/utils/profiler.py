@@ -17,8 +17,12 @@ import json
 import time
 from typing import Optional
 
+import jax
+
 from ..core import native as _native
 from . import monitor as _monitor
+
+TRACE_ANNOTATION_PREFIX = "pdtpu."   # the product's spans in a jax capture
 
 _SORTED_KEYS = (None, "total", "calls", "max", "min", "ave")
 
@@ -36,16 +40,27 @@ class RecordEvent:
 
         with profiler.RecordEvent("data_load"):
             batch = next(loader)
+
+    The event also enters a ``jax.profiler.TraceAnnotation("pdtpu.<name>")``
+    (``trace.span`` comes through here too): while a ``start_device_trace``
+    capture runs, the product's host spans lie on the capture's ``/host:``
+    plane, on the device trace's clock; with no capture it costs a flag
+    test.
     """
 
     def __init__(self, name: str):
         self.name = str(name)
+        self._annotation = None
 
     def __enter__(self):
         _native.prof_push(self.name)
+        self._annotation = jax.profiler.TraceAnnotation(
+            TRACE_ANNOTATION_PREFIX + self.name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
         _native.prof_pop()
         return False
 
@@ -172,11 +187,13 @@ def summary(sorted_key: Optional[str] = None) -> str:
 # ---------------------------------------------------------------- devices --
 def start_device_trace(logdir: str) -> None:
     """Start an XLA device trace (TensorBoard format) — the TPU replacement
-    for the reference's CUPTI DeviceTracer (platform/device_tracer.h:19)."""
-    import jax
+    for the reference's CUPTI DeviceTracer (platform/device_tracer.h:19).
+    Read it in XProf/TensorBoard: the step's region scopes
+    (``utils/xprof.REGIONS``) are the name scopes of every op there, and
+    ``RecordEvent``s and ``trace.span``s show as ``pdtpu.<name>`` on the
+    host's plane, on the same clock."""
     jax.profiler.start_trace(logdir)
 
 
 def stop_device_trace() -> None:
-    import jax
     jax.profiler.stop_trace()

@@ -33,8 +33,11 @@ the *HLO metadata* layer instead of the driver layer.
   resident right now (the serving ``TenantManager`` layers peak-temp
   tracking across its live-executable LRU on top).
 
-``python -m tools.xprof`` renders table / JSON / chrome-trace views; the
-last built report is flight-recorded (top regions + MFU) on post-mortem
+``python -m tools.xprof`` renders table / JSON views of the *modelled*
+cost; measured device time is a ``jax.profiler`` capture
+(``profiler.start_device_trace``) read in XProf/TensorBoard, where the step's
+``REGIONS`` below show as the name scopes of every op.  The last built
+report is flight-recorded (top regions + MFU) on post-mortem
 dumps so a crash dump carries a perf snapshot.
 
 Model limitations (documented, reported, never silently wrong): loop
@@ -59,8 +62,8 @@ from . import trace as _trace
 __all__ = [
     "resolve_peaks", "parse_hlo", "attribute_hlo", "build_report",
     "profile_aot", "profile_jit", "memory_stats", "live_array_census",
-    "render_table", "to_chrome_trace", "summarize", "last_summary",
-    "OP_SCOPE_RE", "op_scope_name",
+    "render_table", "summarize", "last_summary",
+    "OP_SCOPE_RE", "op_scope_name", "REGIONS", "ATTN_CORE", "step_region",
 ]
 
 # -- telemetry (registered at import so metricsdump lists them) --------------
@@ -210,6 +213,29 @@ _ZERO_BYTES = frozenset((
 ))
 
 
+# Regions of a compiled training step: the ``jax.named_scope`` names that
+# text/pretrainer.py and nn/layer/transformer.py plant where the work is
+# built.  Always on (HLO metadata only), not behind ``xprof_scopes``.  The
+# innermost one in an instruction's ``metadata.op_name`` is its region;
+# backward work lands in the same region (``transpose(jvp(<scope>))``).
+# ``core`` counts only directly inside ``attn`` (the path reads
+# ``attn/.../core``); what is under ``encoder`` and in no finer region is
+# the scan's own bookkeeping (stacking and slicing residuals).  A Layer
+# attribute of the same name (``ErnieModel.encoder``) reads as that region.
+REGION_EMBED = "embed"          # lookups, position/type add, embedding LN
+REGION_ENCODER = "encoder"      # the whole block stack
+REGION_ATTN = "attn"            # q/k/v/out projections + the core
+REGION_ATTN_CORE = "core"       # scores -> softmax -> values, under attn
+REGION_FFN = "ffn"              # both products and the activation
+REGION_LN = "ln"                # residual add, dropout, LayerNorm
+REGION_HEAD = "head"            # MLM transform + tied logits + NSP
+REGION_LOSS = "loss"            # cross-entropies
+REGION_OPTIMIZER = "optimizer"  # the update over every leaf, grad casts
+ATTN_CORE = f"{REGION_ATTN}/{REGION_ATTN_CORE}"   # as reports name it
+REGIONS = (REGION_EMBED, REGION_ENCODER, REGION_ATTN, ATTN_CORE, REGION_FFN,
+           REGION_LN, REGION_HEAD, REGION_LOSS, REGION_OPTIMIZER)
+
+
 def op_scope_name(op_type: str, block_idx: int, op_idx: int) -> str:
     """The named-scope encoding the Executor plants per lowered op.  Dotted
     — XLA's scope sanitizer truncates ``@`` and ``:`` out of
@@ -255,16 +281,41 @@ def _shape_bytes(dtype: str, shape: Tuple[int, ...]) -> int:
     return _elems(shape) * _DTYPE_BYTES.get(dtype, 4)
 
 
+def _operand_text(rest: str) -> str:
+    """``rest`` starts right after ``opcode(``: the text up to the paren
+    that closes the operand list."""
+    depth = 1
+    for i, ch in enumerate(rest):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                return rest[:i]
+    return rest
+
+
+_OPERAND_RE = re.compile(
+    r"(?:([a-z0-9]+\[[0-9,\s]*\])(?:\{[^}]*\})?\)?\s+)?%([\w.\-]+)")
+
+
 def parse_hlo(text: str) -> Tuple[Dict[str, List[HloInstr]], List[str]]:
     """Parse HLO module text into {computation name: [instructions]} plus
-    the list of ENTRY computation names (one per module in the text)."""
+    the list of ENTRY computation names (one per module in the text).
+
+    An operand is ``<shape> %<name>`` in older text and a bare ``%<name>``
+    since jax 0.9: there its shape is the output shape of the instruction
+    (a parameter included) that defines the name earlier in the same
+    computation.  A tuple-shaped operand counts by its last element."""
     comps: Dict[str, List[HloInstr]] = {}
     entries: List[str] = []
     current: Optional[List[HloInstr]] = None
+    defined: Dict[str, List[Tuple[str, Tuple[int, ...]]]] = {}
     for line in text.splitlines():
         m = _COMP_RE.match(line)
         if m is not None:
             current = comps.setdefault(m.group(2), [])
+            defined = {}
             if m.group(1):
                 entries.append(m.group(2))
             continue
@@ -278,13 +329,16 @@ def parse_hlo(text: str) -> Tuple[Dict[str, List[HloInstr]], List[str]]:
             continue
         name, out_type, opcode, rest = mi.groups()
         op_name_m = _OPNAME_RE.search(rest)
-        # operand refs are always "<shape> %<name>"; attr shapes (layouts,
-        # literals) never precede a %-ref, so this scan is unambiguous
-        operands = _parse_shapes(
-            " ".join(re.findall(r"([a-z0-9]+\[[0-9,\s]*\](?:\{[^}]*\})?)\s+%",
-                                rest.split(", metadata=")[0])))
+        operands = []
+        for inline, ref in _OPERAND_RE.findall(_operand_text(rest)):
+            if inline:
+                operands += _parse_shapes(inline)
+            elif defined.get(ref):
+                operands.append(defined[ref][-1])
+        out_shapes = _parse_shapes(out_type)
+        defined[name] = out_shapes
         current.append(HloInstr(
-            name, opcode, _parse_shapes(out_type), operands,
+            name, opcode, out_shapes, operands,
             op_name_m.group(1) if op_name_m else "", rest))
     return comps, entries
 
@@ -387,15 +441,38 @@ def _unwrap(component: str) -> str:
         component = m.group(2)
 
 
+_JIT_RE = re.compile(r"\bp?jit\([^()]*\)")
+_WRAPPERS_RE = re.compile(r"[\w.\-]+\(|\)")
+
+
+def step_region(op_name: str) -> Tuple[Optional[str], str]:
+    """(region, pass) of one instruction's ``metadata.op_name``: the
+    innermost of ``REGIONS`` among the path's scopes (``attn/core`` where
+    ``core`` sits directly under ``attn``), None where the path names none;
+    pass is ``"bwd"`` where the path went through ``transpose(`` — the
+    backward of that scope, a ``jax.checkpoint``'s recomputed forward
+    included — else ``"fwd"``."""
+    which = "bwd" if "transpose(" in op_name else "fwd"
+    comps = _WRAPPERS_RE.sub("", _JIT_RE.sub("", op_name)).split("/")
+    for i in range(len(comps) - 1, -1, -1):
+        if comps[i] == REGION_ATTN_CORE and i and comps[i - 1] == REGION_ATTN:
+            return ATTN_CORE, which
+        if comps[i] in REGIONS:
+            return comps[i], which
+    return None, which
+
+
 def _region_of(op_name: str) -> Tuple[str, str, bool]:
     """(region key, op_type, attributed) for one instruction's op_name.
 
-    Attributed regions come from user named scopes: either the Executor's
+    Attributed regions come from user named scopes: the Executor's
     ``<op_type>.b<N>.i<M>`` encoding (innermost match wins — sub-block ops
-    nest inside their control-flow op's scope) or any named_scope path the
-    user planted (dygraph Layers push their layer names).  ``jit(...)``
-    components are jax function boundaries, not user scopes, and the final
-    component is the lowered primitive — both are stripped."""
+    nest inside their control-flow op's scope), else a training step's
+    ``REGIONS`` (``<region>.fwd`` / ``<region>.bwd``, see ``step_region``),
+    else any named_scope path the user planted (dygraph Layers push their
+    layer names).  ``jit(...)`` components are jax function boundaries, not
+    user scopes, and the final component is the lowered primitive — both
+    are stripped."""
     if not op_name or "/" not in op_name:
         return ("<unattributed>", op_name or "<none>", False)
     comps = op_name.split("/")
@@ -404,6 +481,9 @@ def _region_of(op_name: str) -> Tuple[str, str, bool]:
         m = OP_SCOPE_RE.match(core)
         if m is not None:
             return (core, m.group(1), True)
+    region, which = step_region(op_name)
+    if region is not None:
+        return (f"{region}.{which}", region, True)
     kept = []
     for comp in comps[:-1]:
         if comp.startswith(("jit(", "pjit(")):
@@ -732,29 +812,6 @@ def render_table(report: Dict[str, Any], top: int = 20) -> str:
             f"code {_human(m['code_bytes'])}B  "
             f"total {_human(m['total_bytes'])}B")
     return "\n".join(lines)
-
-
-def to_chrome_trace(report: Dict[str, Any]) -> Dict[str, Any]:
-    """Synthetic chrome://tracing timeline of the *modeled* step: regions
-    laid end to end by modeled time (the roofline's serial-execution view),
-    ranked track order, bound class in args."""
-    events: List[Dict[str, Any]] = [
-        {"name": "process_name", "ph": "M", "pid": 0,
-         "args": {"name": f"xprof model ({report['device']['kind']})"}},
-    ]
-    ts = 0.0
-    for row in report["regions"]:
-        dur = row["modeled_ms"] * 1000.0
-        events.append({
-            "name": row["region"], "ph": "X", "pid": 0, "tid": 0,
-            "ts": round(ts, 3), "dur": round(dur, 3),
-            "args": {"bound": row["bound"], "flops": row["flops"],
-                     "bytes": row["bytes"], "mfu": row["mfu"],
-                     "share": row["share"]},
-        })
-        ts += dur
-    return {"traceEvents": events,
-            "metadata": {"totals": report["totals"]}}
 
 
 def summarize(report: Dict[str, Any], top: int = 3) -> Dict[str, Any]:

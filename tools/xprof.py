@@ -12,7 +12,6 @@ Usage::
     python -m tools.xprof                        # toy fc model, table view
     python -m tools.xprof --model mlp --steps 8 --batch 64 --hidden 256
     python -m tools.xprof --format json --out report.json
-    python -m tools.xprof --format chrome --out trace.json   # chrome://tracing
     python -m tools.xprof --input report.json --top 5        # re-render a dump
     python -m tools.xprof --selfcheck            # CI assertion mode (tier-1)
 
@@ -26,8 +25,8 @@ a TPU run produces.
 ``--selfcheck`` asserts the acceptance contract: attribution coverage
 >= 90% of modeled flops on the toy model, every region carries a roofline
 class + MFU, the memory breakdown sums match ``memory_analysis()``, a
-synthetic compute-bound/memory-bound pair classifies correctly, and all
-three render formats produce output.  Exits non-zero on any violation.
+synthetic compute-bound/memory-bound pair classifies correctly, and both
+render formats produce output.  Exits non-zero on any violation.
 """
 from __future__ import annotations
 
@@ -94,8 +93,6 @@ def render(report: dict, fmt: str, top: int) -> str:
 
     if fmt == "json":
         return json.dumps(report, indent=2, sort_keys=True)
-    if fmt == "chrome":
-        return json.dumps(xprof.to_chrome_trace(report))
     return xprof.render_table(report, top=top)
 
 
@@ -165,11 +162,9 @@ def selfcheck() -> int:
           f"elementwise add classified {mb['regions'][0]['bound']}")
 
     # 5) every render format produces non-empty output
-    for fmt in ("table", "json", "chrome"):
+    for fmt in ("table", "json"):
         check(bool(render(report, fmt, top=5).strip()),
               f"{fmt} render came back empty")
-    chrome = xprof.to_chrome_trace(report)
-    check(len(chrome["traceEvents"]) > 1, "chrome trace has no events")
 
     if failures:
         for f in failures:
@@ -192,7 +187,7 @@ def main(argv=None) -> int:
                         help="measured Executor steps anchoring MFU")
     parser.add_argument("--batch", type=int, default=32)
     parser.add_argument("--hidden", type=int, default=128)
-    parser.add_argument("--format", choices=("table", "json", "chrome"),
+    parser.add_argument("--format", choices=("table", "json"),
                         default="table")
     parser.add_argument("--top", type=int, default=20,
                         help="regions shown in the table view")
