@@ -1,8 +1,12 @@
 """Activations (ref: python/paddle/nn/functional/activation.py; operators/
-activation_op.cc kernels).  All map 1:1 onto jax.nn / jnp primitives, which
-XLA fuses into adjacent matmuls — no fused-activation passes needed
-(ref ir/fuse_elewise_add_act pass is obsolete here)."""
+activation_op.cc kernels).  All but GELU map 1:1 onto jax.nn / jnp
+primitives, which XLA fuses into adjacent matmuls — no fused-activation
+passes needed (ref ir/fuse_elewise_add_act pass is obsolete here).  GELU is
+written out: value and derivative from one erf (or tanh) and one exp, in
+float32, rounded once (below)."""
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -47,46 +51,79 @@ def selu(x):
 # lax.scan over stacked blocks those are materialized per layer between the
 # forward and backward loops — at ERNIE-base b64 x s512 that was 4 x
 # bf16[12,64,512,3072] = 9 GB and the step did not fit a 16 GB chip
-# (CHANGES PR 21).  With the rule below the only residual is gelu'(x), one
-# array, computed in the same elementwise pass as the forward.  custom_jvp
-# (not custom_vjp): forward mode, vmap and jax.checkpoint still compose.
+# (CHANGES PR 21).  With the rules below the only residual is gelu'(x), one
+# array in the result's dtype.  custom_jvp (not custom_vjp): forward mode,
+# vmap, jax.checkpoint and second order still compose.
+#
+# Value and derivative share one erf and one exp per element, in float32:
+# with c = (1 + erf(x/sqrt2))/2 and p = exp(-x^2/2)/sqrt(2 pi), gelu = x c
+# and gelu' = c + x p; both are rounded to `dtype` once.  (jax.nn.gelu on
+# bf16 rounds x/sqrt2 and erfc on the way, erfc is three polynomial branches
+# all evaluated and selected, and a derivative beside it is a second erf and
+# exp: 150 vector operations an element in the FFN's first product's
+# epilogue, three times the product's own time on a v5e; PERF.md §6, PR 27.)
+# What XLA:TPU does with it in a scanned FFN (jax 0.9, libtpu 0.0.34):
+# - fed the product's bf16 result, the one-erf value is cheap enough to the
+#   fusion pass that it re-evaluates it in every consumer: three erf a
+#   block, one of them in the second product's operand (+7 ms a step).
+# - behind lax.optimization_barrier: one erf, but the stacked residuals'
+#   writes leave the product's epilogue and become copy passes (+7 ms each).
+# - fed the product's float32 sum (`dtype` says what to round to): one
+#   fusion, one erf, one exp, value and both stacked residuals written by
+#   the product's own epilogue.  nn/layer/transformer._ffn calls it so.
 _SQRT_HALF = 0.7071067811865476
 _INV_SQRT_2PI = 0.3989422804014327
 _SQRT_2_OVER_PI = 0.7978845608028654
 _TANH_C = 0.044715
 
 
-@jax.custom_jvp
-def _gelu_exact(x):
-    return jax.nn.gelu(x, approximate=False)
-
-
-@_gelu_exact.defjvp
-def _gelu_exact_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    xf = x.astype(jnp.float32)
+def _erf_parts(xf, derivative):
+    """c and, for the rule, x p (as above), in float32."""
     cdf = 0.5 * (1.0 + jax.lax.erf(xf * _SQRT_HALF))
-    pdf = jnp.exp(-0.5 * xf * xf) * _INV_SQRT_2PI
-    return _gelu_exact(x), t * (cdf + xf * pdf).astype(t.dtype)
+    if not derivative:
+        return cdf, None
+    return cdf, xf * (jnp.exp(-0.5 * xf * xf) * _INV_SQRT_2PI)
 
 
-@jax.custom_jvp
-def _gelu_tanh(x):
-    return jax.nn.gelu(x, approximate=True)
+def _tanh_parts(xf, derivative):
+    """The tanh form's c = (1 + tanh u)/2, u = sqrt(2/pi) (x + 0.044715 x^3),
+    and x c' from the same tanh."""
+    x2 = xf * xf
+    th = jnp.tanh(_SQRT_2_OVER_PI * xf * (1.0 + _TANH_C * x2))
+    cdf = 0.5 * (1.0 + th)
+    if not derivative:
+        return cdf, None
+    du = _SQRT_2_OVER_PI * (1.0 + 3.0 * _TANH_C * x2)
+    return cdf, xf * cdf * (1.0 - th) * du      # 1 - th^2 without cancelling
 
 
-@_gelu_tanh.defjvp
-def _gelu_tanh_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    xf = x.astype(jnp.float32)
-    th = jnp.tanh(_SQRT_2_OVER_PI * (xf + _TANH_C * xf ** 3))
-    du = _SQRT_2_OVER_PI * (1.0 + 3.0 * _TANH_C * xf * xf)
-    grad = 0.5 * (1.0 + th) + 0.5 * xf * (1.0 - th * th) * du
-    return _gelu_tanh(x), t * grad.astype(t.dtype)
+def _gelu_rule(parts):
+    """x c rounded to `dtype`, and the rule whose residual is c + x c'."""
+    @functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+    def f(x, dtype):
+        xf = x.astype(jnp.float32)
+        return (xf * parts(xf, False)[0]).astype(dtype)
+
+    @f.defjvp
+    def f_jvp(dtype, primals, tangents):
+        (x,), (t,) = primals, tangents
+        xf = x.astype(jnp.float32)
+        c, xdc = parts(xf, True)
+        return (xf * c).astype(dtype), t.astype(dtype) * (c + xdc).astype(dtype)
+
+    return f
 
 
-def gelu(x, approximate=False):
-    return _gelu_tanh(x) if approximate else _gelu_exact(x)
+_gelu_exact = _gelu_rule(_erf_parts)
+_gelu_tanh = _gelu_rule(_tanh_parts)
+
+
+def gelu(x, approximate=False, dtype=None):
+    """`dtype`: what the value (and the derivative the backward keeps) is
+    rounded to, default x.dtype; the arithmetic is float32 either way."""
+    x = jnp.asarray(x)
+    dtype = jnp.dtype(x.dtype if dtype is None else dtype)
+    return (_gelu_tanh if approximate else _gelu_exact)(x, dtype)
 
 
 def sigmoid(x):

@@ -40,8 +40,10 @@ def _linear_core_bwd(res, dy):
 _linear_core.defvjp(_linear_core_fwd, _linear_core_bwd)
 
 
-def linear(x, weight, bias=None):
+def linear(x, weight, bias=None, out_dtype=None):
     """ref: mul/matmul+elementwise_add fusion (fc op). weight: (in, out).
+    `out_dtype`: keep the product's sum + bias in that dtype (float32 for an
+    activation that rounds once itself) instead of the operands'.
 
     With PDTPU_LINEAR_DW=transpose, dW uses the explicit transpose+matmul
     schedule (_linear_core_bwd) instead of XLA's transposing-convolution
@@ -56,10 +58,10 @@ def linear(x, weight, bias=None):
     if os.environ.get("PDTPU_LINEAR_DW") == "transpose":
         out = _linear_core(x, weight)
     else:
-        out = jnp.matmul(x, weight)
+        out = jnp.matmul(x, weight, preferred_element_type=out_dtype)
     if bias is not None:
         out = out + bias
-    return out
+    return out if out_dtype is None else out.astype(out_dtype)
 
 
 def dropout(x, p=0.5, training=True, mode="upscale_in_train"):

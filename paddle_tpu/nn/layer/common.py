@@ -31,9 +31,10 @@ class Linear(Layer):
                                          _dtype_mod.get_default_dtype()),
                                   name=f"{name or 'linear'}.b", initializer=b_init)
 
-    def forward(self, x):
+    def forward(self, x, out_dtype=None):
         return F.linear(x, self.weight.value,
-                        None if self.bias is None else self.bias.value)
+                        None if self.bias is None else self.bias.value,
+                        out_dtype)
 
     def extra_repr(self):
         return f"in={self.in_features}, out={self.out_features}"

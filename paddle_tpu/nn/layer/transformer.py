@@ -174,8 +174,16 @@ def _pre_norm(norm, x):
 
 @jax.named_scope(_xprof.REGION_FFN)
 def _ffn(layer, x):
-    """Both products and the activation, encoder and decoder layers."""
-    return layer.linear2(layer.act_dropout(layer.activation(layer.linear1(x))))
+    """Both products and the activation, encoder and decoder layers.  GELU
+    takes the first product's float32 sum + bias and rounds once itself:
+    XLA then evaluates value and derivative once, in that product's own
+    epilogue (functional/activation.py, over the GELU rules)."""
+    lin = layer.linear1
+    if layer.activation is F.gelu and isinstance(lin, Linear):
+        h = F.gelu(lin(x, out_dtype=jnp.float32), dtype=x.dtype)
+    else:
+        h = layer.activation(lin(x))
+    return layer.linear2(layer.act_dropout(h))
 
 
 class TransformerEncoderLayer(Layer):
