@@ -85,7 +85,7 @@ from .layer.extras import (
     Unfold,
     ZeroPad2D,
 )
-from .layer.moe import MoEFFN
+from .layer.moe import DroplessMoE, MoEFFN, SwiGLU
 from .layer.rnn import (
     GRU,
     LSTM,

@@ -232,13 +232,45 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5):
 
 
 def rms_norm(x, weight=None, epsilon=1e-6):
-    """TPU-native addition (no reference equivalent): RMSNorm for modern LLMs."""
+    """TPU-native addition (no reference equivalent): RMSNorm for modern LLMs.
+    With a weight, the backward recomputes the float32 statistics from the
+    input as it came (the residuals are x and the weight, not float32
+    copies of x and of its normalised form: 8 bytes an element of a
+    scanned stack's memory at bf16)."""
+    if weight is None:
+        return _rms_normalised(x, epsilon)[0].astype(x.dtype)
+    return _rms_norm_weighted(x, weight, epsilon)
+
+
+def _rms_normalised(x, epsilon):
     xf = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    out = (xf / jnp.sqrt(ms + epsilon)).astype(x.dtype)
-    if weight is not None:
-        out = out * weight
-    return out
+    root = jnp.sqrt(ms + epsilon)
+    return xf / root, 1.0 / root
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rms_norm_weighted(x, weight, epsilon):
+    return _rms_normalised(x, epsilon)[0].astype(x.dtype) * weight
+
+
+def _rms_norm_fwd(x, weight, epsilon):
+    return _rms_norm_weighted(x, weight, epsilon), (x, weight)
+
+
+def _rms_norm_bwd(epsilon, res, g):
+    x, weight = res
+    normed, inv = _rms_normalised(x, epsilon)
+    gf = g.astype(jnp.float32)
+    d_normed = gf * weight.astype(jnp.float32)
+    d_weight = jnp.sum(gf * normed.astype(x.dtype).astype(jnp.float32),
+                       axis=tuple(range(g.ndim - weight.ndim)))
+    dx = inv * (d_normed - normed * jnp.mean(d_normed * normed, axis=-1,
+                                             keepdims=True))
+    return dx.astype(x.dtype), d_weight.astype(weight.dtype)
+
+
+_rms_norm_weighted.defvjp(_rms_norm_fwd, _rms_norm_bwd)
 
 
 def group_norm(x, num_groups, weight=None, bias=None, epsilon=1e-5):

@@ -7,18 +7,31 @@ combine tensors — static shapes, MXU-friendly, and when the expert dim of
 `wi`/`wo` is sharded over `ep` (set via Parameter.sharding_axes, consumed by
 parallel.sharding.infer_sharding) GSPMD lowers the dispatch einsums to
 all-to-all over ICI automatically; no hand-written token routing.
+
+`DroplessMoE` beside it is the expert layer as today's open models deploy
+it (DeepSeek-V3 and kin): sigmoid scores with a selection bias, k of E, no
+capacity and so no dropped token, gated experts, shared experts, and a layer
+that is told which experts it holds — one chip's share of an
+expert-parallel group computes its own experts' part of the result.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ...core import dtype as _dtype_mod
+from ...ops.pallas import config as _pcfg
+from ...ops.pallas import grouped_matmul as _gm
+from ...utils import xprof as _xprof
 from .. import functional as F
 from .. import initializer as init
 from ..layer.base import Layer, Parameter
 
-__all__ = ["MoEFFN", "switch_gating", "top2_gating"]
+__all__ = ["MoEFFN", "switch_gating", "top2_gating", "DroplessMoE", "SwiGLU",
+           "sigmoid_topk_routing"]
 
 
 def _one_hot(x, n, dtype=jnp.float32):
@@ -146,3 +159,257 @@ class MoEFFN(Layer):
                                        self.wi.value))
         expert_out = jnp.einsum("ebcf,efd->ebcd", h, self.wo.value)
         return jnp.einsum("bsec,ebcd->bsd", combine, expert_out), aux
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer
+# ---------------------------------------------------------------------------
+class SwiGLU(Layer):
+    """Gated FFN without biases: W_down(silu(x·W_gate) ⊙ x·W_up), the gate
+    and up projections held as one matrix [d_model, 2·d_ff] (gate first)."""
+
+    def __init__(self, d_model: int, d_ff: int, weight_attr=None):
+        super().__init__()
+        from .common import Linear
+        self.d_ff = d_ff
+        self.gate_up = Linear(d_model, 2 * d_ff, weight_attr, bias_attr=False)
+        self.down = Linear(d_ff, d_model, weight_attr, bias_attr=False)
+
+    def forward(self, x):
+        return _gated_down(self.gate_up(x), self.down.weight.value)
+
+
+@jax.checkpoint
+def _gated_down(gate_up, w_down):
+    """silu(gate) ⊙ up, then the down projection.  The backward multiplies
+    the halves again from the one product that is kept: the activation, its
+    derivative's factors and the down projection's input are four more
+    tensors of the FFN's width in every layer of a scanned stack."""
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return F.linear(F.silu(gate) * up, w_down)
+
+
+def sigmoid_topk_routing(x, router_weight, selection_bias, top_k: int,
+                         scaling: float = 1.0, normalize: bool = True):
+    """DeepSeek-V3's router without groups (`n_group` = `topk_group` = 1):
+    scores s = sigmoid(x·W_r) in float32; the `top_k` largest of s + b are
+    selected (b takes no gradient); the weights are the selected s (without
+    b), divided by their sum, times `scaling`.  x: [T, D] -> (expert ids
+    [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_weight.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + lax.stop_gradient(selection_bias.astype(jnp.float32))
+    _, ids = lax.top_k(lax.stop_gradient(biased), top_k)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    if normalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), weights * scaling
+
+
+# The pairs (token, slot) sorted by expert are a permutation `order` of
+# t·top_k + j with inverse `inverse`.  A grouped product writes the rows of
+# its groups and leaves the rest of its result as it found it, forward and
+# transposed alike, so rows that belong to no held pair are no result: the
+# two functions below never let one into a sum (a `select`, not a product
+# with 0), which costs nothing — the select fuses into the sum over the
+# slots — where zeroing the rows themselves is a pass over tokens × top_k
+# rows each time.
+def _slots(rows, inverse, top_k):
+    """The rows of every token's pair j, slot by slot: top_k arrays [T, D]
+    in float32.  (One gather reshaped to [T, top_k, D] would put top_k on
+    the tiled sublanes: a padded copy of tokens × top_k rows.)"""
+    inverse = inverse.reshape(-1, top_k)
+    return [rows[inverse[:, j]].astype(jnp.float32) for j in range(top_k)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(x, order, inverse, pair_held, top_k):
+    """Row r of the sorted pairs is token order[r] // top_k's activation.
+    The transpose of a gather with repeats is a scatter-add; pairs being a
+    permutation, it is a gather through the inverse and a sum over the
+    slots of the held pairs (`pair_held` [T, top_k])."""
+    return x[order // top_k]
+
+
+def _dispatch_fwd(x, order, inverse, pair_held, top_k):
+    return x[order // top_k], (inverse, pair_held)
+
+
+def _dispatch_bwd(top_k, res, g):
+    inverse, pair_held = res
+    dx = sum(jnp.where(pair_held[:, j, None], slot, 0)
+             for j, slot in enumerate(_slots(g, inverse, top_k)))
+    return dx.astype(g.dtype), None, None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(rows, pair_weight, order, inverse, top_k):
+    """Token t's result: Σ_j pair_weight[t, j] · (the row of its pair j),
+    over the pairs whose weight is not 0 (the held ones), summed in
+    float32."""
+    y = sum(jnp.where(pair_weight[:, j, None] != 0,
+                      slot * pair_weight[:, j, None], 0)
+            for j, slot in enumerate(_slots(rows, inverse, top_k)))
+    return y.astype(rows.dtype)
+
+
+def _combine_fwd(rows, pair_weight, order, inverse, top_k):
+    return (_combine(rows, pair_weight, order, inverse, top_k),
+            (rows, pair_weight, order, inverse))
+
+
+def _combine_bwd(top_k, res, g):
+    rows, pair_weight, order, inverse = res
+    row_weight = pair_weight.reshape(-1)[order]
+    d_rows = (g[order // top_k].astype(jnp.float32)
+              * row_weight[:, None]).astype(rows.dtype)
+    gf = g.astype(jnp.float32)
+    d_weight = jnp.stack([jnp.sum(slot * gf, axis=-1)
+                          for slot in _slots(rows, inverse, top_k)], axis=-1)
+    return d_rows, jnp.where(pair_weight != 0, d_weight, 0), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+class DroplessMoE(Layer):
+    """FFN(x) = Σ_selected∩held w_i·E_i(x) + Shared(x), no token dropped.
+
+    `held=(first, count)` says which routed experts this layer holds: the
+    router keeps its width `n_routed_experts` and its `top_k`, the weights
+    are normalised over all the selected, and the pairs whose expert lies
+    elsewhere are another chip's work: they are left out of the sort and
+    add nothing here (no exchange, no stand-in).  With
+    `count == n_routed_experts` it is the whole layer.  The shared experts
+    (one SwiGLU at n_shared_experts × d_expert) are computed by every
+    holder alike.
+
+    Token-expert pairs are sorted by expert (held first, in order), the
+    held experts run as one grouped product each way over
+    [held, d_model, 2·d_expert] and [held, d_expert, d_model] (the Pallas
+    kernel of `ops/pallas/grouped_matmul.py` on the TPU, `lax.ragged_dot`
+    elsewhere), and the weighted rows are summed back per token."""
+
+    def __init__(self, d_model: int, d_expert: int, n_routed_experts: int,
+                 top_k: int, *, held=None, n_shared_experts: int = 0,
+                 routed_scaling_factor: float = 1.0,
+                 norm_topk_prob: bool = True, weight_attr=None):
+        super().__init__()
+        first, count = held if held is not None else (0, n_routed_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= n_routed_experts):
+            raise ValueError(f"held={held!r} is not a range of the "
+                             f"{n_routed_experts} routed experts")
+        if top_k > n_routed_experts:
+            raise ValueError("top_k exceeds the number of routed experts")
+        self.d_model, self.d_expert = d_model, d_expert
+        self.n_routed_experts, self.top_k = n_routed_experts, top_k
+        self.held = (first, count)
+        self.routed_scaling_factor = routed_scaling_factor
+        self.norm_topk_prob = norm_topk_prob
+        dtype = _dtype_mod.get_default_dtype()
+        w_init = getattr(weight_attr, "initializer", None) or \
+            init.XavierUniform()
+        zeros = init.Constant(0.0)
+        self.router_weight = Parameter(
+            w_init((d_model, n_routed_experts), dtype), initializer=w_init)
+        # e_score_correction_bias: moves the selection, takes no gradient
+        self.router_bias = Parameter(zeros((n_routed_experts,), dtype),
+                                     initializer=zeros)
+        self.w_in = Parameter(w_init((count, d_model, 2 * d_expert), dtype),
+                              initializer=w_init)
+        self.w_out = Parameter(w_init((count, d_expert, d_model), dtype),
+                               initializer=w_init)
+        self.shared_mlp = SwiGLU(d_model, n_shared_experts * d_expert,
+                                 weight_attr) if n_shared_experts else None
+
+    def route(self, x):
+        """x: [T, D] -> (ids [T, k], weights [T, k] float32).  The
+        backward computes the scores again from x as it came: its float32
+        copy is not kept."""
+        return jax.checkpoint(functools.partial(
+            sigmoid_topk_routing, top_k=self.top_k,
+            scaling=self.routed_scaling_factor,
+            normalize=self.norm_topk_prob))(
+                x, self.router_weight.value, self.router_bias.value)
+
+    def _pair_held(self, ids):
+        first, count = self.held
+        return (ids >= first) & (ids < first + count)
+
+    def _plan(self, ids):
+        """The sort: (order [T·k] of the pairs by held expert, the rest
+        last; its inverse; group sizes [count])."""
+        first, count = self.held
+        local = jnp.where(self._pair_held(ids), ids - first,
+                          count).reshape(-1)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = jnp.sum(local[:, None] == jnp.arange(count)[None, :],
+                        axis=0, dtype=jnp.int32)
+        return order, inverse, sizes
+
+    def forward(self, x):
+        from ...parallel import mesh as _mesh
+
+        lead, d = x.shape[:-1], x.shape[-1]
+        tokens = x.reshape(-1, d)
+        with jax.named_scope(_xprof.SCOPE_ROUTER):
+            ids, weights = self.route(tokens)
+        with jax.named_scope(_xprof.SCOPE_EXPERTS):
+            # every data-parallel shard sorts and computes its own tokens'
+            # pairs against the held experts (replicated over dp)
+            rows = x.shape[0] if x.ndim == 3 else 1
+            batched = tuple(t.reshape(rows, -1, t.shape[-1])
+                            for t in (tokens, ids, weights))
+            kernel = _pcfg.backend_is_tpu() and _gm.supported(
+                tokens.shape[0] * self.top_k, d, self.d_expert)
+            n = _mesh.batch_shards(rows) if kernel else 0
+            if kernel and not n:
+                _pcfg.record_fallback("grouped_matmul", "partial_manual_mesh")
+            held = functools.partial(self._held_experts, bool(n))
+            weights_ = (self.w_in.value, self.w_out.value)
+            # the kernel runs once per data-parallel shard (a Mosaic call
+            # has no partitioning rule); XLA's own products are GSPMD's
+            y = (_mesh.per_batch_shard(held, n, batched, weights_) if n
+                 else held(*batched, *weights_)).reshape(-1, d)
+        if self.shared_mlp is not None:
+            with jax.named_scope(_xprof.SCOPE_SHARED):
+                y = y + self.shared_mlp(tokens)
+        return y.reshape(*lead, d)
+
+    @functools.partial(jax.checkpoint, static_argnums=(0, 1))
+    def _held_experts(self, kernel, tokens, ids, weights, w_in, w_out):
+        """These tokens' pairs sorted by held expert, through the held
+        experts and back, per token ([b, s, ·] in and out).  Its rows are
+        tokens × top_k of which the held are a share (an eighth on one chip
+        of eight), and all of them would wait as residuals of every layer:
+        the backward sorts, gathers and multiplies again instead (a third
+        more of the grouped products, the smallest of the layer)."""
+        shape = tokens.shape
+        tokens = tokens.reshape(-1, shape[-1])
+        ids, weights = (t.reshape(-1, self.top_k) for t in (ids, weights))
+        pair_held = self._pair_held(ids)
+        order, inverse, sizes = self._plan(ids)
+        dot = _gm.grouped_matmul if kernel else lax.ragged_dot
+        rows = _dispatch(tokens, order, inverse, pair_held, self.top_k)
+        gate, up = jnp.split(dot(rows, w_in, sizes), 2, axis=-1)
+        out = dot(F.silu(gate) * up, w_out, sizes)
+        return _combine(out, jnp.where(pair_held, weights, 0.0), order,
+                        inverse, self.top_k).reshape(shape)
+
+    def routing_stats(self, x):
+        """Counts of one call's routing, as int32/float32 scalars: pairs
+        routed (tokens × top_k), pairs whose expert is held here, the held
+        experts' largest load over their mean load, and pairs dropped (held
+        pairs that no group of the grouped product covers: 0 by
+        construction)."""
+        ids, _ = self.route(x.reshape(-1, x.shape[-1]))
+        sizes = self._plan(ids)[2]
+        held = jnp.sum(self._pair_held(ids), dtype=jnp.int32)
+        mean = jnp.maximum(jnp.mean(sizes.astype(jnp.float32)), 1e-9)
+        return {"pairs_routed": jnp.int32(ids.size), "pairs_held": held,
+                "held_load_max_over_mean": jnp.max(sizes) / mean,
+                "pairs_dropped": held - jnp.sum(sizes)}

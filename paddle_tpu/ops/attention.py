@@ -118,32 +118,42 @@ def flash_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
     allow; otherwise fall back to the jnp reference implementation.
 
     Kernel-eligible masks are k-position padding masks (shape (b,1,1,s));
-    arbitrary (b,h,sq,sk) masks fall back.  Dropout runs in-kernel with a
-    replayable position-keyed RNG."""
+    arbitrary (b,h,sq,sk) masks fall back.  ``v`` may differ from ``q`` and
+    ``k`` in its head size (latent attention: q·k at 192, v at 128).
+    Dropout runs in-kernel with a replayable position-keyed RNG.  A causal,
+    mask-free call that misses the enabled kernel is counted in
+    ``pallas.fallbacks`` with its reason, never silent: at long sequences
+    the fallback holds the whole float32 score square."""
     from ..parallel import mesh as _mesh
     from .pallas import flash_attention as fa
 
     b, h, s, d = q.shape
     rate = float(dropout_p) if training else 0.0
     bias = _as_padding_bias(attn_mask, b, s)
-    use_kernel = (
-        _pcfg.kernel_enabled("use_flash_attention")
-        and bias is not None
-        and q.shape == k.shape == v.shape
-        and fa.supported(s, d)
-    )
-    n = _mesh.batch_shards(b) if use_kernel else 0
-    if n:
-        seed = draw_dropout_seed(n, rate)
+    reason = None
+    if not _pcfg.kernel_enabled("use_flash_attention"):
+        pass        # no kernel on this backend, or switched off: nothing missed
+    elif bias is None:
+        reason = "mask"
+    elif not (q.shape == k.shape and v.shape[:-1] == k.shape[:-1]):
+        reason = "shapes"
+    elif not fa.supported(s, d, v.shape[-1]):
+        reason = "unsupported"
+    else:
+        n = _mesh.batch_shards(b)
+        if n:
+            seed = draw_dropout_seed(n, rate)
 
-        def kernel(q, k, v, bias, seed):
-            return fa.flash_attention(q, k, v, bias=bias, sm_scale=scale,
-                                      causal=is_causal, dropout_rate=rate,
-                                      seed=seed)
+            def kernel(q, k, v, bias, seed):
+                return fa.flash_attention(q, k, v, bias=bias, sm_scale=scale,
+                                          causal=is_causal, dropout_rate=rate,
+                                          seed=seed)
 
-        return _mesh.per_batch_shard(kernel, n, (q, k, v, bias, seed))
-    if use_kernel:
-        _pcfg.record_fallback("flash_attention", "partial_manual_mesh")
+            return _mesh.per_batch_shard(kernel, n, (q, k, v, bias, seed))
+        reason = "partial_manual_mesh"
+    if reason == "partial_manual_mesh" or (
+            reason and is_causal and attn_mask is None):
+        _pcfg.record_fallback("flash_attention", reason)
     return scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
                                         dropout_p=dropout_p, is_causal=is_causal,
                                         scale=scale, training=training)

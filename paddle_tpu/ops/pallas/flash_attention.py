@@ -17,6 +17,9 @@ TPU-native capability.  Design:
 * Backward: two kernels — dK/dV over a (batch*heads, k_blocks) grid and dQ
   over (batch*heads, q_blocks) — recomputing probabilities from the stored
   logsumexp (no S matrix ever materialized in HBM).
+* Head sizes: q and k share one (the contraction of the scores), v, o and
+  their cotangents another: latent attention trains at q·k 192 / v 128, and
+  padding v to 192 would add a third to the P·V, dV and dP work.
 * Padding mask: an additive k-position bias of shape (batch, seq_k) streams
   through both passes, which covers the BERT/ERNIE padding-mask case without
   falling back to the O(S^2) jnp path.
@@ -161,8 +164,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         return acc, m_new, l_new
 
-    d = q_ref.shape[-1]
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
     acc, m, l = jax.lax.fori_loop(0, num_kv_iter, body, (acc0, m0, l0))
@@ -173,8 +175,10 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
 def _flash_forward(q, k, v, bias, seed, sm_scale, causal, dropout_rate,
                    block_q, block_k):
-    """q,k,v: (bh, seq, d); bias: (b, seq); seed: int32 scalar array."""
+    """q,k: (bh, seq, d); v: (bh, seq, d_v); bias: (b, seq); seed: int32
+    scalar array."""
     bh, seq_len, d = q.shape
+    d_v = v.shape[-1]
     b = bias.shape[0]
     h = bh // b
     grid = (bh, seq_len // block_q)
@@ -189,15 +193,15 @@ def _flash_forward(q, k, v, bias, seed, sm_scale, causal, dropout_rate,
             pl.BlockSpec(memory_space=_smem()),
             pl.BlockSpec((1, block_q, d), lambda bh_i, i: (bh_i, i, 0)),
             pl.BlockSpec((1, seq_len, d), lambda bh_i, i: (bh_i, 0, 0)),
-            pl.BlockSpec((1, seq_len, d), lambda bh_i, i: (bh_i, 0, 0)),
+            pl.BlockSpec((1, seq_len, d_v), lambda bh_i, i: (bh_i, 0, 0)),
             pl.BlockSpec((1, 1, seq_len), lambda bh_i, i: (bh_i // h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh_i, i: (bh_i, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda bh_i, i: (bh_i, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh_i, i: (bh_i, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, seq_len, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32),
         ],
         interpret=_cfg.interpret(),
@@ -247,9 +251,9 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                                   preferred_element_type=jnp.float32)
         return dk_acc, dv_acc
 
-    d = k_ref.shape[-1]
-    zeros = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(qi_start, num_q, body, (zeros, zeros))
+    dk, dv = jax.lax.fori_loop(
+        qi_start, num_q, body,
+        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -298,6 +302,7 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
 def _flash_backward(q, k, v, bias, seed, o, lse, do, sm_scale, causal,
                     dropout_rate, block_q, block_k):
     bh, seq_len, d = q.shape
+    d_v = v.shape[-1]
     b = bias.shape[0]
     h = bh // b
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -306,7 +311,8 @@ def _flash_backward(q, k, v, bias, seed, o, lse, do, sm_scale, causal,
 
     common = dict(sm_scale=sm_scale, causal=causal, dropout_rate=dropout_rate,
                   block_q=block_q, block_k=block_k, seq_len=seq_len)
-    seq_spec = lambda: pl.BlockSpec((1, seq_len, d), lambda bh_i, i: (bh_i, 0, 0))
+    seq_spec = lambda d: pl.BlockSpec((1, seq_len, d), lambda bh_i, i: (bh_i, 0, 0))
+    blk_spec = lambda n, d: pl.BlockSpec((1, n, d), lambda bh_i, i: (bh_i, i, 0))
     row_spec = lambda: pl.BlockSpec((1, 1, seq_len), lambda bh_i, i: (bh_i, 0, 0))
 
     dk, dv = pl.pallas_call(
@@ -314,18 +320,15 @@ def _flash_backward(q, k, v, bias, seed, o, lse, do, sm_scale, causal,
         grid=(bh, seq_len // block_k),
         in_specs=[
             pl.BlockSpec(memory_space=_smem()),
-            seq_spec(),  # q
-            pl.BlockSpec((1, block_k, d), lambda bh_i, i: (bh_i, i, 0)),  # k
-            pl.BlockSpec((1, block_k, d), lambda bh_i, i: (bh_i, i, 0)),  # v
+            seq_spec(d),  # q
+            blk_spec(block_k, d),  # k
+            blk_spec(block_k, d_v),  # v
             pl.BlockSpec((1, 1, block_k), lambda bh_i, i: (bh_i // h, 0, i)),  # bias
-            seq_spec(),  # do
+            seq_spec(d_v),  # do
             row_spec(),  # lse
             row_spec(),  # delta
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh_i, i: (bh_i, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_i, i: (bh_i, i, 0)),
-        ],
+        out_specs=[blk_spec(block_k, d), blk_spec(block_k, d_v)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=_cfg.interpret(),
@@ -337,15 +340,15 @@ def _flash_backward(q, k, v, bias, seed, o, lse, do, sm_scale, causal,
         grid=(bh, seq_len // block_q),
         in_specs=[
             pl.BlockSpec(memory_space=_smem()),
-            pl.BlockSpec((1, block_q, d), lambda bh_i, i: (bh_i, i, 0)),  # q
-            seq_spec(),  # k
-            seq_spec(),  # v
+            blk_spec(block_q, d),  # q
+            seq_spec(d),  # k
+            seq_spec(d_v),  # v
             pl.BlockSpec((1, 1, seq_len), lambda bh_i, i: (bh_i // h, 0, 0)),  # bias
-            pl.BlockSpec((1, block_q, d), lambda bh_i, i: (bh_i, i, 0)),  # do
+            blk_spec(block_q, d_v),  # do
             pl.BlockSpec((1, 1, block_q), lambda bh_i, i: (bh_i, 0, i)),  # lse
             pl.BlockSpec((1, 1, block_q), lambda bh_i, i: (bh_i, 0, i)),  # delta
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh_i, i: (bh_i, i, 0)),
+        out_specs=blk_spec(block_q, d),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=_cfg.interpret(),
         name="flash_dq",
@@ -399,16 +402,20 @@ def _normalize_bias_seed(bias, seed, b, s):
     return bias, seed
 
 
-def supported(seq_len: int, head_dim: int) -> bool:
-    """Shapes the kernel handles: sublane-aligned head_dim (64 covers the
-    BERT/ERNIE family; Mosaic pads lanes), block-divisible seq."""
-    return head_dim % 64 == 0 and seq_len % 128 == 0 and seq_len >= 128
+def supported(seq_len: int, head_dim: int, v_head_dim: int = None) -> bool:
+    """Shapes the kernel handles: sublane-aligned head sizes (64 covers the
+    BERT/ERNIE family, 192/128 latent attention; Mosaic pads lanes),
+    block-divisible seq."""
+    v_head_dim = head_dim if v_head_dim is None else v_head_dim
+    return head_dim % 64 == 0 and v_head_dim % 64 == 0 \
+        and seq_len % 128 == 0 and seq_len >= 128
 
 
 def flash_attention(q, k, v, bias=None, sm_scale=None, causal=False,
                     dropout_rate=0.0, seed=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
-    """Flash attention over (batch, heads, seq, head_dim) inputs.
+    """Flash attention over (batch, heads, seq, head_dim) inputs; ``v`` may
+    have a head size of its own (the output's).
 
     ``bias`` is an optional additive k-position bias of shape (batch, seq_k)
     — the padding-mask case.  ``bias`` is treated as NON-DIFFERENTIABLE:
@@ -437,10 +444,10 @@ def flash_attention(q, k, v, bias=None, sm_scale=None, causal=False,
     # bias is non-differentiable (padding masks carry no trainable state;
     # the docstring carries the learned-bias warning)
     bias, seed = _normalize_bias_seed(bias, seed, b, s)
-    merged = lambda x: x.reshape(b * h, s, d)
+    merged = lambda x: x.reshape(b * h, s, x.shape[-1])
     _cfg.record_call("flash_attention")
     with jax.named_scope("pallas.flash_attention"):
         out = _flash_attention_bhsd(merged(q), merged(k), merged(v), bias,
                                     seed, sm_scale, causal,
                                     float(dropout_rate), bq, bk)
-    return out.reshape(b, h, s, d)
+    return out.reshape(b, h, s, v.shape[-1])

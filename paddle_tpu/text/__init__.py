@@ -1,5 +1,7 @@
 """paddle_tpu.text — NLP model zoo (ref: python/paddle/text/ + the
-PaddleNLP-era ERNIE family targeted by BASELINE.json)."""
+PaddleNLP-era ERNIE family targeted by BASELINE.json), a decoder-only
+expert family (`deepseek_v3`), and the trainer that takes either as a
+`PretrainModel` (`HybridPretrainer`)."""
 from .datasets import (Conll05st, Imdb, Imikolov, Movielens,
                        MovieReviews, UCIHousing, WMT14, WMT16)
 from .ernie import (
@@ -12,3 +14,5 @@ from .ernie import (
     ErnieModel,
     ErniePretrainingCriterion,
 )
+from .deepseek_v3 import DeepseekV3Config
+from .pretrainer import HybridPretrainer, PretrainModel, ernie_pretrain_model

@@ -1,4 +1,5 @@
-"""Hybrid-parallel ERNIE/BERT pretraining trainer.
+"""Hybrid-parallel pretraining trainer (ERNIE/BERT, and any model described
+as a `PretrainModel`: embedding, groups of uniform blocks, head, loss).
 
 This is the rebuild's answer to the reference's fleet hybrid stack — the
 composition of the PipelineOptimizer program splitter (fluid/optimizer.py:3661),
@@ -14,11 +15,18 @@ as ONE pjit'd train step over a dp×pp×ep×sp×tp mesh:
        partial-manual shard_map (axis_names={'pp'}): pp is manual, all other
        axes stay GSPMD-automatic inside the body
   ep — MoE expert dim sharding (nn.MoEFFN every `moe_every` blocks)
+
+The trainer builds no model of its own: it takes a `PretrainModel` — the
+model's pieces and which batch keys each reads — and stacks, shards, scans
+and differentiates them.  `ernie_pretrain_model` is the first such
+description (an `ErnieConfig` handed to the trainer is turned into it);
+`text/deepseek_v3.py` gives a decoder-only one with two groups of blocks.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,20 +76,109 @@ class _MoEBlock(nn.Layer):
         return x
 
 
-class HybridPretrainer:
-    """Assembles params + shardings + a pure train step for ERNIE pretraining
-    on the current hybrid mesh.
+@dataclasses.dataclass
+class PretrainModel:
+    """A model as `HybridPretrainer` trains it.
 
-    Pipeline note: the encoder stack must be uniform, so embeddings/pooler/
-    heads live outside the pipeline (replicated over pp) and the blocks'
-    parameters are stacked [L, ...] with the leading dim sharded over pp.
+    embeddings: Layer called with the batch's `embed_inputs` -> [B, S, H].
+    groups: {name: Layer with `.layers`, a list of uniform blocks x -> x};
+      each group's parameters are stacked [L, ...] under its name and
+      scanned (`blockwise_stage_fn`), the groups in order.  No name may be
+      "embed" or "head".
+    head: Layer called with (hidden, *batch's `head_inputs`, None where the
+      batch lacks one) -> whatever `criterion` takes first.
+    criterion: (head's outputs, batch) -> scalar loss.
+    token_keys / row_keys: the batch keys the step reads, [B, S] ones
+      (sharded over dp and sp) and [B, ...] ones (over dp).
+    tied: {head parameter: embedding parameter} kept as one leaf under
+      "embed" and bound into the head at call time.
+    """
+    embeddings: Any
+    groups: Dict[str, Any]
+    head: Any
+    criterion: Callable
+    embed_inputs: Tuple[str, ...]
+    head_inputs: Tuple[str, ...] = ()
+    token_keys: Tuple[str, ...] = ("input_ids",)
+    row_keys: Tuple[str, ...] = ()
+    tied: Dict[str, str] = dataclasses.field(default_factory=dict)
+    config: Any = None
+
+
+def ernie_pretrain_model(cfg: ErnieConfig,
+                         moe_experts: int = 0) -> PretrainModel:
+    """ERNIE/BERT pretraining (MLM + NSP) as the trainer's pieces.  The
+    encoder stack must be uniform, so embeddings/pooler/heads live outside
+    it; with `moe_experts` every block's FFN is an `nn.MoEFFN`."""
+    embeddings = ErnieEmbeddings(cfg)
+    if moe_experts:
+        block = _MoEBlock(cfg, moe_experts)
+    else:
+        block = nn.TransformerEncoderLayer(
+            cfg.hidden_size, cfg.num_attention_heads,
+            cfg.intermediate_size, dropout=cfg.hidden_dropout_prob,
+            activation=cfg.hidden_act,
+            attn_dropout=cfg.attention_probs_dropout_prob, act_dropout=0.0)
+    # fresh per-block init via the cloning LayerList (clones re-draw from
+    # each parameter's recorded initializer)
+    stack = nn.TransformerEncoder(block, cfg.num_hidden_layers) \
+        if not moe_experts else _CloneList(block, cfg.num_hidden_layers)
+    criterion = ErniePretrainingCriterion(cfg.vocab_size)
+
+    def loss(outputs, batch):
+        logits, nsp = outputs
+        return criterion(logits.astype(jnp.float32), nsp.astype(jnp.float32),
+                         batch["mlm_labels"], batch["nsp_labels"])
+
+    return PretrainModel(
+        embeddings=embeddings, groups={"blocks": stack},
+        head=_PretrainHead(cfg, embeddings.word_embeddings.weight),
+        criterion=loss, embed_inputs=("input_ids", "token_type_ids"),
+        head_inputs=("masked_positions",),
+        token_keys=("input_ids", "token_type_ids"),
+        # (b, n_mask) labels/indices and (b,) nsp labels: batch-sharded
+        # only.  n_mask is not a sequence dim (sp rarely divides it), and
+        # the masked-position indices address the full sequence, so none
+        # get seq-axis sharding.
+        row_keys=("mlm_labels", "nsp_labels", "masked_positions"),
+        # the MLM decoder weight is TIED to the embedding table: one pytree
+        # leaf (under "embed"), so its gradient accumulates from both uses
+        # and donation never sees the same buffer twice.
+        tied={"cls.predictions.decoder_weight": "word_embeddings.weight"},
+        config=cfg)
+
+
+class HybridPretrainer:
+    """Assembles params + shardings + a pure train step for a pretraining
+    model on the current hybrid mesh.
+
+    `config` is either the model's description (a `PretrainModel`) or an
+    `ErnieConfig` (default: ERNIE-1.0 base), which `ernie_pretrain_model`
+    turns into one (`moe_experts` is that description's option).
+
+    Parameters are {"embed": ..., <group>: stacked [L, ...] blocks, ...,
+    "head": ...}.  Pipeline note: a group of blocks must be uniform, so
+    embeddings and head live outside the pipeline (replicated over pp) and
+    the blocks' leading dim is sharded over pp; pp > 1 takes a model with
+    one group.
     """
 
-    def __init__(self, config: Optional[ErnieConfig] = None, *,
+    def __init__(self, config=None, *,
                  mesh=None, num_micro: int = 1, moe_experts: int = 0,
                  rules=TRANSFORMER_RULES, recompute: bool = False,
                  recompute_policy: Optional[str] = None, strategy=None):
-        self.cfg = config or ErnieConfig()
+        if isinstance(config, PretrainModel):
+            if moe_experts:
+                raise ValueError("moe_experts belongs to the ERNIE "
+                                 "description, not to a PretrainModel")
+            self.model = config
+        else:
+            self.model = ernie_pretrain_model(config or ErnieConfig(),
+                                              moe_experts)
+        if {"embed", "head"} & set(self.model.groups):
+            raise ValueError("a group of blocks may not be named 'embed' "
+                             "or 'head'")
+        self.cfg = self.model.config
         self.mesh = mesh or _mesh.current_mesh()
         self.num_micro = num_micro
         self.rules = rules
@@ -122,42 +219,25 @@ class HybridPretrainer:
         # (parallel/sharding.py zero_spec; ref proto sharding_configs).
         self.zero_sharding = bool(strategy is not None
                                   and getattr(strategy, "sharding", False))
-        cfg = self.cfg
-
-        self.embeddings = ErnieEmbeddings(cfg)
-        if moe_experts:
-            block = _MoEBlock(cfg, moe_experts)
-        else:
-            block = nn.TransformerEncoderLayer(
-                cfg.hidden_size, cfg.num_attention_heads,
-                cfg.intermediate_size, dropout=cfg.hidden_dropout_prob,
-                activation=cfg.hidden_act,
-                attn_dropout=cfg.attention_probs_dropout_prob, act_dropout=0.0)
-        # fresh per-block init via the cloning LayerList (clones re-draw from
-        # each parameter's recorded initializer)
-        self._stack = nn.TransformerEncoder(block, cfg.num_hidden_layers) \
-            if not moe_experts else _CloneList(block, cfg.num_hidden_layers)
-        self.block_template = self._stack.layers[0]
-        self.head = _PretrainHead(cfg, self.embeddings.word_embeddings.weight)
-        self.criterion = ErniePretrainingCriterion(cfg.vocab_size)
+        if len(self.model.groups) > 1 and \
+                _mesh.mesh_axis_size(_mesh.PP_AXIS, self.mesh) > 1:
+            raise ValueError("pp > 1 pipelines one group of uniform blocks; "
+                             f"this model has {list(self.model.groups)}")
+        self.embeddings = self.model.embeddings
+        self.head = self.model.head
+        self.block_templates = {name: stack.layers[0]
+                                for name, stack in self.model.groups.items()}
 
     # -- parameters ---------------------------------------------------------
-    _TIED = "cls.predictions.decoder_weight"
-    _EMB = "word_embeddings.weight"
-
     def init_params(self) -> Dict[str, Any]:
-        blocks = [parameters_dict(l) for l in self._stack.layers]
-        # the MLM decoder weight is TIED to the embedding table: keep one
-        # pytree leaf (under "embed") and bind it into the head at call time,
-        # so its gradient accumulates from both uses and donation never sees
-        # the same buffer twice.
-        head = {k: v for k, v in parameters_dict(self.head).items()
-                if k != self._TIED}
-        return {
-            "embed": parameters_dict(self.embeddings),
-            "blocks": stack_block_params(blocks),
-            "head": head,
-        }
+        tied = set(self.model.tied)
+        out = {"embed": parameters_dict(self.embeddings)}
+        for name, stack in self.model.groups.items():
+            out[name] = stack_block_params(
+                [parameters_dict(l) for l in stack.layers])
+        out["head"] = {k: v for k, v in parameters_dict(self.head).items()
+                       if k not in tied}
+        return out
 
     def param_shardings(self, params) -> Dict[str, Any]:
         m = self.mesh
@@ -165,18 +245,21 @@ class HybridPretrainer:
             "embed": infer_sharding(params["embed"], m, self.rules),
             "head": infer_sharding(params["head"], m, self.rules),
         }
-        blk = {}
-        for name, v in params["blocks"].items():
-            ann = None
-            p = _find_param(self.block_template, name)
-            if p is not None and getattr(p, "sharding_axes", None) is not None:
-                ann = tuple(p.sharding_axes)
-            if ann is None:
-                match = self.rules.match(name, v.ndim - 1)
-                ann = match if match is not None else (None,) * (v.ndim - 1)
-            spec = (_mesh.PP_AXIS,) + tuple(ann)
-            blk[name] = NamedSharding(m, _clean(spec, m, v.shape))
-        out["blocks"] = blk
+        for group, template in self.block_templates.items():
+            blk = {}
+            for name, v in params[group].items():
+                ann = None
+                p = _find_param(template, name)
+                if p is not None and \
+                        getattr(p, "sharding_axes", None) is not None:
+                    ann = tuple(p.sharding_axes)
+                if ann is None:
+                    match = self.rules.match(name, v.ndim - 1)
+                    ann = match if match is not None \
+                        else (None,) * (v.ndim - 1)
+                spec = (_mesh.PP_AXIS,) + tuple(ann)
+                blk[name] = NamedSharding(m, _clean(spec, m, v.shape))
+            out[group] = blk
         return out
 
     def place_params(self, params):
@@ -186,10 +269,10 @@ class HybridPretrainer:
             is_leaf=lambda x: not isinstance(x, dict))
 
     # -- forward ------------------------------------------------------------
-    def _block_fn(self):
-        """Single-block apply (+ optional recompute wrap) shared by the
-        GPipe and 1F1B paths."""
-        template = self.block_template
+    def _block_fn(self, group: str):
+        """Single-block apply of one group (+ optional recompute wrap)
+        shared by the GPipe and 1F1B paths."""
+        template = self.block_templates[group]
 
         def block_fn(blk, x):
             return functional_call(template, blk, (x,))
@@ -201,15 +284,17 @@ class HybridPretrainer:
                 block_fn, policy=checkpoint_policy(self.recompute_policy))
         return block_fn
 
-    def _encode(self, blocks, h):
-        """Run the encoder stack: pipelined over pp when the axis exists."""
+    def _encode(self, params, h):
+        """Run the groups of blocks in order, each a scan over its stacked
+        parameters; pipelined over pp when the axis exists (one group)."""
         pp = _mesh.mesh_axis_size(_mesh.PP_AXIS, self.mesh)
-        block_fn = self._block_fn()
-
         if pp == 1:
-            stage = blockwise_stage_fn(block_fn)
-            return stage(blocks, h)
+            for group in self.model.groups:
+                h = blockwise_stage_fn(self._block_fn(group))(params[group], h)
+            return h
 
+        (group,) = self.model.groups
+        blocks, block_fn = params[group], self._block_fn(group)
         xs = microbatch(h, self.num_micro)
 
         def run(blk, xs_):
@@ -224,27 +309,29 @@ class HybridPretrainer:
             axis_names={_mesh.PP_AXIS}, check_vma=False)
         return unmicrobatch(f(blocks, xs))
 
+    def _head_params(self, params):
+        head_params = dict(params["head"])
+        for head_name, embed_name in self.model.tied.items():
+            head_params[head_name] = params["embed"][embed_name]
+        return head_params
+
     def loss_fn(self, params, batch, key):
-        cfg = self.cfg
+        model = self.model
         # kernel dispatch shards over THIS trainer's mesh (mesh_scope)
         with _mesh.mesh_scope(self.mesh), _random.rng_scope(key):
             with jax.named_scope(_xprof.REGION_EMBED):
                 h = functional_call(
                     self.embeddings, params["embed"],
-                    (batch["input_ids"], batch["token_type_ids"]))
+                    tuple(batch[k] for k in model.embed_inputs))
                 h = self._data_constraint(h)
             with jax.named_scope(_xprof.REGION_ENCODER):
-                h = self._encode(params["blocks"], h)
-            head_params = dict(params["head"])
-            head_params[self._TIED] = params["embed"][self._EMB]
+                h = self._encode(params, h)
             with jax.named_scope(_xprof.REGION_HEAD):
-                logits, nsp = functional_call(
-                    self.head, head_params,
-                    (h, batch.get("masked_positions")))
+                outputs = functional_call(
+                    self.head, self._head_params(params),
+                    (h, *(batch.get(k) for k in model.head_inputs)))
         with jax.named_scope(_xprof.REGION_LOSS):
-            loss = self.criterion(logits.astype(jnp.float32),
-                                  nsp.astype(jnp.float32),
-                                  batch["mlm_labels"], batch["nsp_labels"])
+            loss = model.criterion(outputs, batch)
         # MoE load-balancing aux loss is not added here: the blocks run under
         # lax.scan (and the pp shard_map), so the per-block aux values are
         # trace-local.  Custom loops wanting it should call
@@ -324,7 +411,9 @@ class HybridPretrainer:
         def train_step(params, opt_state, batch, key):
             p = _cast_floating(params, compute_dtype)
 
-            block_fn = self._block_fn()
+            model = self.model
+            (group,) = model.groups
+            block_fn = self._block_fn(group)
 
             @jax.named_scope(_xprof.REGION_ENCODER)
             def stage_fn(blk, x, micro_idx):
@@ -342,32 +431,30 @@ class HybridPretrainer:
                 with _random.rng_scope(
                         jax.random.fold_in(key, 2 * micro_idx + 3)), \
                         jax.named_scope(_xprof.REGION_HEAD):
-                    logits, nsp = functional_call(
-                        self.head, hp, (y, tgt.get("masked_positions")))
+                    outputs = functional_call(
+                        self.head, hp,
+                        (y, *(tgt.get(k) for k in model.head_inputs)))
                 with jax.named_scope(_xprof.REGION_LOSS):
-                    return self.criterion(
-                        logits.astype(jnp.float32), nsp.astype(jnp.float32),
-                        tgt["mlm_labels"], tgt["nsp_labels"])
+                    return model.criterion(outputs, tgt)
 
             @jax.named_scope(_xprof.REGION_EMBED)
             def embed_fn(ep):
                 with _random.rng_scope(jax.random.fold_in(key, 0)):
                     h = functional_call(
                         self.embeddings, ep,
-                        (batch["input_ids"], batch["token_type_ids"]))
+                        tuple(batch[k] for k in model.embed_inputs))
                 return self._data_constraint(h)
 
-            head_params = dict(p["head"])
-            head_params[self._TIED] = p["embed"][self._EMB]
+            head_params = self._head_params(p)
 
             h, vjp_embed = jax.vjp(embed_fn, p["embed"])
             xs = microbatch(h, self.num_micro)
             targets = {k: microbatch(batch[k], self.num_micro)
-                       for k in ("masked_positions", "mlm_labels",
-                                 "nsp_labels") if k in batch}
+                       for k in model.token_keys + model.row_keys
+                       if k in batch and k not in model.embed_inputs}
 
             blk_specs = jax.tree_util.tree_map(
-                lambda _: PartitionSpec(_mesh.PP_AXIS), p["blocks"])
+                lambda _: PartitionSpec(_mesh.PP_AXIS), p[group])
 
             def run(blk, hp, xs_, ts_):
                 return pipeline_train_1f1b(
@@ -381,15 +468,15 @@ class HybridPretrainer:
                 out_specs=(PartitionSpec(), blk_specs, PartitionSpec(),
                            PartitionSpec()),
                 axis_names={_mesh.PP_AXIS}, check_vma=False)
-            loss, sgrads, hgrads, dxs = f(p["blocks"], head_params, xs,
+            loss, sgrads, hgrads, dxs = f(p[group], head_params, xs,
                                           targets)
             (egrads,) = vjp_embed(unmicrobatch(dxs))
 
-            hgrads = dict(hgrads)
-            tied_g = hgrads.pop(self._TIED)
-            egrads = dict(egrads)
-            egrads[self._EMB] = egrads[self._EMB] + tied_g
-            grads = {"embed": egrads, "blocks": dict(sgrads),
+            hgrads, egrads = dict(hgrads), dict(egrads)
+            for head_name, embed_name in model.tied.items():
+                egrads[embed_name] = egrads[embed_name] + \
+                    hgrads.pop(head_name)
+            grads = {"embed": egrads, group: dict(sgrads),
                      "head": hgrads}
             new_params, new_state = self._update(optimizer, grads, opt_state,
                                                  params)
@@ -404,17 +491,13 @@ class HybridPretrainer:
         return scoped
 
     def data_shardings(self, mesh=None):
+        """{batch key: sharding} for the keys the model's step reads."""
         m = mesh or self.mesh
         tok = _mesh.data_sharding(m, seq_axis=_mesh.SP_AXIS)
         dp_only = NamedSharding(m, PartitionSpec(
             _mesh.DP_AXIS if _mesh.DP_AXIS in m.axis_names else None))
-        return {"input_ids": tok, "token_type_ids": tok,
-                # (b, n_mask) labels/indices and (b,) nsp labels: batch-
-                # sharded only.  n_mask is not a sequence dim (sp rarely
-                # divides it), and the masked-position indices address the
-                # full sequence, so none get seq-axis sharding.
-                "mlm_labels": dp_only, "nsp_labels": dp_only,
-                "masked_positions": dp_only}
+        return {**{k: tok for k in self.model.token_keys},
+                **{k: dp_only for k in self.model.row_keys}}
 
 
 class _PretrainHead(nn.Layer):

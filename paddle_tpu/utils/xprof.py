@@ -232,6 +232,13 @@ REGION_HEAD = "head"            # MLM transform + tied logits + NSP
 REGION_LOSS = "loss"            # cross-entropies
 REGION_OPTIMIZER = "optimizer"  # the update over every leaf, grad casts
 ATTN_CORE = f"{REGION_ATTN}/{REGION_ATTN_CORE}"   # as reports name it
+# finer scopes inside a region (an expert layer's parts under `ffn`, latent
+# attention's compression under `attn`): a region reader ignores them, a
+# sub-scope reader finds them as `<region>/<sub>`
+SCOPE_ROUTER = "router"         # ffn: scores, selection, weights
+SCOPE_EXPERTS = "experts"       # ffn: sort, dispatch, grouped products, combine
+SCOPE_SHARED = "shared"         # ffn: the shared experts
+SCOPE_LATENT = "latent"         # attn: kv down-projection, its norm, up-projection
 REGIONS = (REGION_EMBED, REGION_ENCODER, REGION_ATTN, ATTN_CORE, REGION_FFN,
            REGION_LN, REGION_HEAD, REGION_LOSS, REGION_OPTIMIZER)
 

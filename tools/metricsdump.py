@@ -99,9 +99,14 @@ _KNOWN_NAMES = frozenset({
     # ops/pallas/config.py (kernel dispatch telemetry); kernel= label
     # values: flash_attention, flash_attention_packed, fused_layer_norm,
     # fused_rdln, conv2d_bn_act, bn_act_train, max_pool2d, avg_pool2d,
-    # int8_matmul, int8_conv2d, paged_attention
+    # int8_matmul, int8_conv2d, paged_attention, grouped_matmul
     "pallas.fallbacks",
     "pallas.kernel_calls",
+    # text/deepseek_v3.py routing_stats (nn.DroplessMoE; label layer)
+    "moe.held_load_max_over_mean",
+    "moe.pairs_dropped",
+    "moe.pairs_held",
+    "moe.pairs_routed",
     # static/passes.py (graph-rewrite pipeline)
     "passes.ops_fused",
     "passes.ops_removed",
