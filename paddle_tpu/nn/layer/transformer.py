@@ -55,8 +55,8 @@ class MultiHeadAttention(Layer):
         value = key if value is None else value
         if cache is None and not self.need_weights:
             # packed fast path: feed the projection outputs straight to the
-            # kernel in (b, s, h*d) layout — the split/merge transposes cost
-            # ~19 ms/step on the ERNIE flagship (pure layout copies)
+            # kernel in (b, s, h*d) layout — no split/merge transposes (a
+            # layout copy of q, k, v, o and of their gradients a layer)
             qp = self.q_proj(query)
             kp = self.k_proj(key)
             vp = self.v_proj(value)

@@ -2,14 +2,15 @@
 
 The standard kernel (flash_attention.py) consumes (batch*heads, seq, dim),
 which forces the model to materialize (b, s, h, d) -> (b, h, s, d)
-transposes around every attention call — measured ~19 ms/step of pure
-layout copies on the ERNIE flagship.  This variant reads the projection
-output LAYOUT DIRECTLY: blocks are (1, block_q, 2*dim) slices of the
-(b, s, h*d) array covering 128 lanes of heads (Mosaic requires
-128-divisible lane blocks): a PAIR of 64-wide heads (BERT/ERNIE family) or
-ONE 128-wide head (LLaMA-class models); each grid cell runs the
-online-softmax recursion for its heads back to back.  No transpose ever
-exists in the program.
+transposes around every attention call: a layout copy of q, k, v and the
+output forward, and of their gradients backward, in every layer.  This
+variant reads the projection output LAYOUT DIRECTLY: blocks are
+(1, block_q, 2*dim) slices of the (b, s, h*d) array covering 128 lanes of
+heads (Mosaic requires 128-divisible lane blocks): a PAIR of 64-wide heads
+(BERT/ERNIE family) or ONE 128-wide head (LLaMA-class models); each grid
+cell runs the online-softmax recursion for its heads back to back.  No
+transpose ever exists in the program, and the backward runs no arithmetic
+outside its two kernels (see _backward).
 
 Numerics, dropout (hardware-PRNG per-tile reseed keyed by the GLOBAL head
 index, replayable in both backward kernels), bias handling, and the matmul
@@ -148,9 +149,32 @@ def _bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         dv_ref[0, :, lo:lo + head_dim] = dv.astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, *, sm_scale, causal, dropout_rate,
-                   block_q, block_k, seq_len, head_dim):
+def _head_rowsums(a, b, head_dim):
+    """rowsum(a * b) over each head's lanes of a (rows, 128) tile, in float32:
+    (8, rows), row h for head h, rows lane-major as lse is stored.  The lane
+    reduction runs on the matrix unit (a 0/1 selector times the transposed
+    product): jnp.sum's cross-lane reduce on the vector unit costs the dq
+    kernel several times as much (PERF.md, PR 29).  The float32 product
+    goes in as bf16 pieces that hold it exactly — two for bf16 operands
+    (8 + 8 significant bits), three for float32 — summed in float32."""
+    prod = a.astype(jnp.float32) * b.astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    selector = jnp.where(lane // head_dim == head, 1.0, 0.0).astype(
+        jnp.bfloat16)
+    both_bf16 = a.dtype == jnp.bfloat16 and b.dtype == jnp.bfloat16
+    pieces = []
+    for _ in range(2 if both_bf16 else 3):
+        pieces.append(prod.astype(jnp.bfloat16))
+        prod = prod - pieces[-1].astype(jnp.float32)
+    return sum(jax.lax.dot_general(selector, piece, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+               for piece in pieces)
+
+
+def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
+                   lse_ref, dq_ref, delta_ref, *, sm_scale, causal,
+                   dropout_rate, block_q, block_k, seq_len, head_dim):
     pair = pl.program_id(0)
     qi = pl.program_id(1)
     num_kv = seq_len // block_k
@@ -159,15 +183,19 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         num_kv_iter = jnp.minimum(num_kv_iter, num_kv)
     else:
         num_kv_iter = num_kv
+    # delta = rowsum(do * o) of this q-block per head, in float32 from the
+    # blocks already in VMEM; written out for the dkdv kernel as fwd writes lse
+    deltas = _head_rowsums(do_ref[0], o_ref[0], head_dim)
+    delta_ref[0, 0] = deltas[:128 // head_dim]
 
     for head in range(128 // head_dim):
         lo = head * head_dim
         q = q_ref[0, :, lo:lo + head_dim]
         do = do_ref[0, :, lo:lo + head_dim]
-        # lse/delta ride full-seq blocks (shared spec with the dkdv kernel);
+        delta = deltas[head][:, None]
+        # lse rides a full-seq block (shared spec with the dkdv kernel);
         # this cell only needs its q-block slice
         lse = lse_ref[0, 0, head, pl.dslice(qi * block_q, block_q)]
-        delta = delta_ref[0, 0, head, pl.dslice(qi * block_q, block_q)]
         bh_global = pair * (128 // head_dim) + head
 
         def body(kv_idx, dq_acc, q=q, do=do, lse=lse, delta=delta,
@@ -191,7 +219,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 keep = _keep_mask(seed_ref[0], jnp.int32(bh_global), qi,
                                   kv_idx, q_pos, k_pos, dropout_rate)
                 dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-            ds = (p * (dp - delta[:, None]) * sm_scale).astype(k.dtype)
+            ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
             return dq_acc + jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
         dq = jax.lax.fori_loop(0, num_kv_iter, body,
@@ -208,6 +236,13 @@ def _specs(seq_len, pairs, block=None):
                             lambda p, i: (p // pairs, 0, p % pairs))
     return pl.BlockSpec((1, block, 128),
                         lambda p, i: (p // pairs, i, p % pairs))
+
+
+def _row_stat_spec(pairs, hpg, block_q):
+    """Output spec of a per-(row, head) float32 statistic (the forward's lse,
+    the backward's delta): (b, groups, heads_per_group, seq) by q-block."""
+    return pl.BlockSpec((1, 1, hpg, block_q),
+                        lambda p, i: (p // pairs, p % pairs, 0, i))
 
 
 def _forward(q, k, v, bias, seed, num_heads, sm_scale, causal, dropout_rate,
@@ -233,8 +268,7 @@ def _forward(q, k, v, bias, seed, num_heads, sm_scale, causal, dropout_rate,
         ],
         out_specs=[
             _specs(seq_len, pairs, block_q),
-            pl.BlockSpec((1, 1, hpg, block_q),
-                         lambda p, i: (p // pairs, p % pairs, 0, i)),
+            _row_stat_spec(pairs, hpg, block_q),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -247,15 +281,17 @@ def _forward(q, k, v, bias, seed, num_heads, sm_scale, causal, dropout_rate,
 
 def _backward(q, k, v, bias, seed, num_heads, o, lse, do, sm_scale, causal,
               dropout_rate, block_q, block_k):
+    """dq, dk, dv.  `o` and `do` go to the kernels as they come: the row sums
+    delta = rowsum(do * o) per head are made inside the dq kernel, in float32
+    from the bf16 blocks it holds in VMEM anyway, and written as
+    (b, groups, heads_per_group, seq) like the forward's lse for the dkdv
+    kernel to read.  Made here in jax.numpy they cost XLA two float32 copies
+    of (b, s, h*d) in a row-minor layout, a reduce and two re-tilings a
+    layer: ~0.5 GB of HBM traffic for one number per (row, head)."""
     b, seq_len, packed = q.shape
     hd = packed // num_heads
     pairs = packed // 128
     hpg = 128 // hd
-    # delta = rowsum(do * o) per head: (b, pairs, heads_per_group, seq)
-    do4 = do.reshape(b, seq_len, num_heads, hd).astype(jnp.float32)
-    o4 = o.reshape(b, seq_len, num_heads, hd).astype(jnp.float32)
-    delta = jnp.sum(do4 * o4, axis=-1)               # (b, seq, h)
-    delta = jnp.moveaxis(delta, 1, 2).reshape(b, pairs, hpg, seq_len)
     bias3 = bias.reshape(b, 1, seq_len)
 
     common = dict(sm_scale=sm_scale, causal=causal, dropout_rate=dropout_rate,
@@ -263,6 +299,29 @@ def _backward(q, k, v, bias, seed, num_heads, o, lse, do, sm_scale, causal,
                   head_dim=hd)
     lse_spec = pl.BlockSpec((1, 1, hpg, seq_len),
                             lambda p, i: (p // pairs, p % pairs, 0, 0))
+    dq, delta = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **common),
+        grid=(b * pairs, seq_len // block_q),
+        in_specs=[
+            pl.BlockSpec(memory_space=_smem()),
+            _specs(seq_len, pairs, block_q),                  # q
+            _specs(seq_len, pairs),   # k
+            _specs(seq_len, pairs),   # v
+            pl.BlockSpec((1, 1, seq_len), lambda p, i: (p // pairs, 0, 0)),
+            _specs(seq_len, pairs, block_q),                  # do
+            _specs(seq_len, pairs, block_q),                  # o
+            lse_spec,
+        ],
+        out_specs=[
+            _specs(seq_len, pairs, block_q),
+            _row_stat_spec(pairs, hpg, block_q),
+        ],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        interpret=_cfg.interpret(),
+        name="flash_packed_dq",
+    )(seed, q, k, v, bias3, do, o, lse)
+
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, **common),
         grid=(b * pairs, seq_len // block_k),
@@ -284,25 +343,6 @@ def _backward(q, k, v, bias, seed, num_heads, o, lse, do, sm_scale, causal,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=_cfg.interpret(),
         name="flash_packed_dkdv",
-    )(seed, q, k, v, bias3, do, lse, delta)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
-        grid=(b * pairs, seq_len // block_q),
-        in_specs=[
-            pl.BlockSpec(memory_space=_smem()),
-            _specs(seq_len, pairs, block_q),                  # q
-            _specs(seq_len, pairs),   # k
-            _specs(seq_len, pairs),   # v
-            pl.BlockSpec((1, 1, seq_len), lambda p, i: (p // pairs, 0, 0)),
-            _specs(seq_len, pairs, block_q),                  # do
-            lse_spec,
-            lse_spec,
-        ],
-        out_specs=_specs(seq_len, pairs, block_q),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_cfg.interpret(),
-        name="flash_packed_dq",
     )(seed, q, k, v, bias3, do, lse, delta)
     return dq, dk, dv
 

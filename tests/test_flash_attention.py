@@ -268,6 +268,112 @@ class TestPackedLayout:
                     seed=seed)
         assert np.array_equal(np.asarray(a1), np.asarray(a2))
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("h,d,s,block", [
+        (4, 64, 128, 128),      # one block, head pairs
+        (4, 64, 512, 128),      # four q-blocks
+        (2, 128, 128, 128),     # one block, single 128-wide heads
+        (2, 128, 512, 128),
+    ])
+    def test_packed_delta_is_float32_rowsum_of_do_times_o(self, monkeypatch,
+                                                          h, d, s, block,
+                                                          dtype):
+        """The row sums the dq kernel makes and hands the dkdv kernel are
+        rowsum(dO * O) per head, in float32 from the inputs as they come."""
+        from paddle_tpu.ops.pallas import flash_attention_packed as fp
+
+        b = 2
+        rng = np.random.default_rng(3)
+        q, k, v, do = (jnp.asarray(rng.normal(0, 1, (b, s, h * d)), dtype)
+                       for _ in range(4))
+        bias = jnp.zeros((b, s), jnp.float32)
+        seed = jnp.zeros((1,), jnp.int32)
+        args = (1.0 / np.sqrt(d), False, 0.0, block, block)
+        o, lse = fp._forward(q, k, v, bias, seed, h, *args)
+        seen = {}
+        orig = fp.pl.pallas_call
+
+        def spy(kernel, **kw):
+            call = orig(kernel, **kw)
+
+            def run(*operands):
+                out = call(*operands)
+                seen[kw["name"]] = (operands, out)
+                return out
+            return run
+
+        monkeypatch.setattr(fp.pl, "pallas_call", spy)
+        fp._backward(q, k, v, bias, seed, h, o, lse, do, *args)
+        want = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
+                        ).reshape(b, s, h, d), axis=-1)          # (b, s, h)
+        want = jnp.moveaxis(want, 1, 2).reshape(b, h * d // 128, 128 // d, s)
+        made = seen["flash_packed_dq"][1][1]
+        assert made.dtype == jnp.float32 and made.shape == want.shape
+        np.testing.assert_allclose(np.asarray(made), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        # and that array, not another, is what the dkdv kernel reads
+        assert seen["flash_packed_dkdv"][0][-1] is made
+
+    @pytest.mark.parametrize("h,d", [(4, 64), (2, 128)])
+    def test_packed_grad_runs_nothing_full_size_outside_its_kernels(self, h,
+                                                                    d):
+        """No equation of the gradient's jaxpr outside a pallas_call may touch
+        a (b, s, h*d)-sized operand, save reshape/broadcast: the row sums
+        cannot drift back out of the kernel unnoticed."""
+        from paddle_tpu.ops.pallas.flash_attention_packed import (
+            flash_attention_packed as packed,
+        )
+
+        b, s = 2, 256
+        x = jnp.zeros((b, s, h * d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.vjp(
+            lambda q, k, v: packed(q, k, v, h), x, x, x)[1])(x)
+        full = b * s * h * d
+        allowed = {"pallas_call", "reshape", "broadcast_in_dim"}
+        kernels, outside = [], []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                name = eqn.primitive.name
+                if name == "pallas_call":
+                    kernels.append(eqn.params["name"])
+                    continue        # what a kernel does inside is its own
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+                sizes = [getattr(a.aval, "size", 0)
+                         for a in list(eqn.invars) + list(eqn.outvars)]
+                if name not in allowed and max(sizes, default=0) >= full:
+                    outside.append(name)
+
+        walk(jaxpr.jaxpr)
+        assert not outside, outside
+        assert kernels == ["flash_packed_dq", "flash_packed_dkdv"]
+
+    @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+    @pytest.mark.parametrize("rate", [0.0, 0.1], ids=["nodrop", "drop0.1"])
+    @pytest.mark.parametrize("h,d", [(4, 64), (2, 128)])
+    def test_packed_grads_match_standard_kernel_multi_block(self, h, d, rate,
+                                                            causal):
+        """causal x dropout x more than one q-block: the packed backward (its
+        delta output's block spec included) against the standard kernel,
+        which replays the same per-head dropout streams."""
+        from paddle_tpu.ops.pallas.flash_attention import flash_attention as std
+        from paddle_tpu.ops.pallas.flash_attention_packed import (
+            flash_attention_packed as packed,
+        )
+
+        q4, k4, v4, bias, pack = self._data(b=1, h=h, s=512, d=d)
+        seed = jnp.asarray([11], jnp.int32)
+        kw = dict(bias=bias, causal=causal, dropout_rate=rate, seed=seed,
+                  block_q=128, block_k=128)
+        g_ref = jax.grad(lambda t: (std(*t, **kw) ** 2).sum())((q4, k4, v4))
+        g_pk = jax.grad(lambda t: (packed(*map(pack, t), h, **kw) ** 2
+                                   ).sum())((q4, k4, v4))
+        for name, a, r in zip("qkv", g_pk, g_ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=1e-5, atol=1e-5, err_msg=name)
+
     def test_mha_packed_dispatch(self, monkeypatch):
         """MultiHeadAttention takes the transpose-free path when the gate
         opens and matches the split-head fallback."""
