@@ -22,10 +22,10 @@ worker modes —
     ``jnp`` (or unpickles a jax array) computes on the host instead of
     trying to take the parent's chip.
 
-Measured on this image (64×(512,) int32 token batches, 4 spawn workers,
-steady state after startup): ~380 batches/s ≈ 12M tok/s through the
-shared-memory path — ~90× the flagship bench's ~4 steps/s consumption
-rate at b64×s512 (see
+Batches cross from the workers through shared memory, not pickled
+through a pipe; no cell of the benchmark runs this loader yet, so its rate
+beside a training step is not measured (PERF.md §7, cell 3; the workers'
+path is held by
 tests/test_io_hapi.py::test_multiprocess_dataloader_throughput).
 
 Spawn caveat: like torch's spawn mode, user scripts must guard entry with

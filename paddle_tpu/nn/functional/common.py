@@ -3,62 +3,17 @@ paddle/nn/functional/common.py; operators/dropout_op.cc, pad_op.cc,
 interpolate_v2)."""
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
 from ...core import random as _random
 
 
-@jax.custom_vjp
-def _linear_core(x, weight):
-    return jnp.matmul(x, weight)
-
-
-def _linear_core_fwd(x, weight):
-    return jnp.matmul(x, weight), (x, weight)
-
-
-def _linear_core_bwd(res, dy):
-    x, weight = res
-    # dW via an EXPLICIT transpose + plain matmul: XLA's default lowering
-    # of the dW contraction ((b,s,h),(b,s,k)->(h,k)) uses a transposing
-    # convolution emitter measured at ~40-47% of MXU peak on v5e (the
-    # largest single perf tax in the r03 step budget, ROADMAP S3); materializing x^T as a
-    # separate copy and feeding a standard matmul runs at ~56% — about
-    # 0.5 ms saved per FFN-sized dW at b64 x s512 (r04 microbench; a
-    # Pallas dW kernel measured at most 50%, so XLA's pair wins).
-    x2 = x.reshape(-1, x.shape[-1])
-    dy2 = dy.reshape(-1, dy.shape[-1])
-    dw = jnp.matmul(
-        x2.T, dy2, preferred_element_type=jnp.float32).astype(weight.dtype)
-    dx = jnp.matmul(dy, weight.T)
-    return dx.astype(x.dtype), dw
-
-
-_linear_core.defvjp(_linear_core_fwd, _linear_core_bwd)
-
-
 def linear(x, weight, bias=None, out_dtype=None):
     """ref: mul/matmul+elementwise_add fusion (fc op). weight: (in, out).
     `out_dtype`: keep the product's sum + bias in that dtype (float32 for an
-    activation that rounds once itself) instead of the operands'.
-
-    With PDTPU_LINEAR_DW=transpose, dW uses the explicit transpose+matmul
-    schedule (_linear_core_bwd) instead of XLA's transposing-convolution
-    emitter — wins in isolation (56% vs 40% of peak, r04 microbench) but
-    measured a NET LOSS end-to-end on the ERNIE flagship (168.5k vs
-    174.3k tok/s): in context XLA fuses the dW conv with the Adam update,
-    reading x/dy once, and the split schedule's extra HBM pass over the
-    activations outweighs the emitter win.  Recorded so it is not retried
-    blindly (ROADMAP "Recorded non-wins"; r04, earlier setup).  Note: the toggle path is a
-    custom_vjp, so forward-mode AD (jax.jvp/jacfwd) is unsupported under
-    it — reverse-mode only, fine for training."""
-    if os.environ.get("PDTPU_LINEAR_DW") == "transpose":
-        out = _linear_core(x, weight)
-    else:
-        out = jnp.matmul(x, weight, preferred_element_type=out_dtype)
+    activation that rounds once itself) instead of the operands'."""
+    out = jnp.matmul(x, weight, preferred_element_type=out_dtype)
     if bias is not None:
         out = out + bias
     return out if out_dtype is None else out.astype(out_dtype)

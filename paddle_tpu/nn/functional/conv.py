@@ -34,8 +34,7 @@ def conv2d(x, weight, bias=None, stride=1, padding=0, dilation=1, groups=1,
     — ref layouts.  NHWC is a NATIVE path (dimension_numbers carry the
     layout straight into XLA, no transposes): channels-last keeps C on the
     128-lane minor dimension the TPU vector units and MXU feeds want, so
-    the compiler stops materializing layout conversions around every conv
-    (the r05 ResNet ladder's first rung)."""
+    the compiler stops materializing layout conversions around every conv."""
     if data_format == "NHWC":
         out = lax.conv_general_dilated(
             x, weight,

@@ -23,7 +23,7 @@ def _bn_train(x, weight, bias, axes, epsilon):
     conv; the apply input-fuses into the consumer.  Backward: the
     classic two-pass schedule (one fused pass for dβ=Σdy and
     dγ=Σdy·x̂, one elementwise pass for dx) instead of leaving AD to
-    schedule the passes (r05 ResNet ladder, ROADMAP S1).
+    schedule the passes (ROADMAP S1).
 
     Returns (out, mean_f32, var_f32); weight/bias may be None.
     """
@@ -171,7 +171,7 @@ def batch_norm_act(x, running_mean, running_var, weight=None, bias=None,
 def bn_inference_scale_bias(mean, var, weight, bias, epsilon):
     """Fold inference-mode BN to per-channel ``a·x + b`` (fp32 a, b).
 
-    This is the r05 fold: the apply input-fuses into the producing conv's
+    The activation-space fold: the apply input-fuses into the producing conv's
     consumer.  Shared by F.batch_norm's inference path and the graph-level
     conv+BN+act fusion pass (static/passes.py) — the pass replaces the
     conv2d→batch_norm op pair with one ``fused_conv2d_bn_act`` op whose

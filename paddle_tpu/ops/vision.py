@@ -298,8 +298,8 @@ def yolo_loss(x, gt_box, gt_label, anchors: Sequence[int],
     any gt exceeds ignore_thresh.  All built as dense scatters — no ragged
     tensors (static-shape policy).
     """
-    # the loss contract is fp32 regardless of head dtype (bf16 heads
-    # measured throughput-NEUTRAL, r05 ladder — so exact parity wins);
+    # the loss contract is fp32 regardless of head dtype (bf16 heads were
+    # no faster on the earlier setup, record deleted — so exact parity wins);
     # casting at entry makes the invariant hold for EVERY term, including
     # the ignore-mask decode below
     x = jnp.asarray(x).astype(jnp.float32)
@@ -360,8 +360,8 @@ def yolo_loss(x, gt_box, gt_label, anchors: Sequence[int],
     t_w, t_h = scat(tw), scat(th)
     t_scale = scat(box_scale)
     # class targets scattered DIRECTLY in the head's (N, A, C, H, W)
-    # layout: the [..., C]-last form needed an 83 MB fp32 transpose of the
-    # prediction tensor per head per step (r05 YOLO ladder; ROADMAP "Recorded non-wins")
+    # layout: the [..., C]-last form needs an fp32 transpose of the whole
+    # prediction tensor per head per step
     cls_idx = jnp.clip(gt_label, 0, C - 1)
     t_cls = jnp.zeros((N, A, C, H, W), jnp.float32).at[
         (bidx, local_anchor, cls_idx, gj, gi)].set(1.0, mode="drop")

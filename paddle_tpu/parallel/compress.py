@@ -155,8 +155,8 @@ def wire_bytes(nelem: int, compress: Optional[str] = None,
     """Bytes moved over the interconnect by one ring allreduce of `nelem`
     elements across `n` members: 2*(n-1)/n * payload bytes, where the
     quantized payload carries 1 byte/element plus one fp32 scale per block.
-    This is the accounting collbench reports (cost_analysis on forced-host
-    CPU does not model inter-device traffic)."""
+    Counted from the sizes: cost_analysis on forced-host CPU does not
+    model inter-device traffic."""
     if n <= 1:
         return 0
     if compress in COMPRESS_KINDS:

@@ -70,7 +70,7 @@ _m_exchange_bytes = _monitor.histogram(
     "Per-device wire bytes one sharded-embedding lookup site moves per "
     "step (id all_to_all + forward row all_to_all + backward gradient-row "
     "all_to_all, quantization accounted) — observed at trace time from the "
-    "static shapes, the same accounting tools/recbench.py reports.")
+    "static shapes (`exchange_bytes`).")
 _m_unique_ratio = _monitor.gauge(
     "emb.unique_ratio",
     "unique ids / submitted ids of the most recent deduplicated lookup "

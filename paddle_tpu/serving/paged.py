@@ -596,8 +596,13 @@ class PagedDecoder:
             meta[3, i] = seq.block_ids[pos // bs]
             meta[4, i] = pos % bs
         width = self._live_width(len(seq.block_ids))
+        # copies, not the persistent mirrors: dispatch is asynchronous and
+        # may read a host buffer in place after it returns, and a chunk
+        # that does not end its prompt is not waited for — the next chunk
+        # would rewrite ``meta`` and ``_grow`` the table under it
         self.cache.k, self.cache.v, nxt = self._prefill_fn(
-            self.cache.k, self.cache.v, self._tables_np[slot, :width], meta)
+            self.cache.k, self.cache.v,
+            self._tables_np[slot, :width].copy(), meta.copy())
         seq.context_len = start + n
         KV_PREFILL_CHUNKS.inc()
         self._publish_full_blocks(seq)

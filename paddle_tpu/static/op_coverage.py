@@ -13,7 +13,7 @@ Rationale categories:
 - ``engine``: subgraph/fusion engines that XLA replaces wholesale.
 - ``service``: RPC/pslib/BoxPS control- or data-plane clients of services
   that live OUTSIDE jitted programs here (distributed/ps_server.py is the
-  capability re-scope; VERDICT r03/r04 accepted the descope).
+  capability re-scope).
 - ``host``: ops whose contract is inherently host-side/dynamic in a way
   the static TPU path re-scopes elsewhere (named alternative given).
 """
@@ -65,8 +65,7 @@ DESCOPED = {
     "conv2d_inception_fusion": "engine: same (cuDNN-specific)",
     "multihead_matmul": "engine: TRT-era fused attention; the Pallas "
                         "flash kernels are the TPU counterpart",
-    "fused_batch_norm_act": "engine: XLA fuses BN+act epilogues; the "
-                            "r05 vision ladder measures this fusion",
+    "fused_batch_norm_act": "engine: XLA fuses BN+act epilogues",
     "fused_elemwise_activation": "engine: generic elementwise fusion is "
                                  "XLA's bread and butter",
     "fused_embedding_eltwise_layernorm": "engine: TRT fused kernel; "

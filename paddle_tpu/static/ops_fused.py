@@ -12,7 +12,7 @@ types; their lowerings fold at trace time, so XLA sees one region:
   ops/pallas/conv_fused.py) the conv runs as a Pallas kernel with the
   per-channel BN transform ``a·x + b`` fused as an epilogue on its output
   tiles; otherwise BN is folded INTO THE FILTER (``w' = w * a`` per
-  output channel, ``b' = conv_bias * a + b`` — the r05 weight-space fold)
+  output channel, ``b' = conv_bias * a + b`` — the weight-space fold)
   and XLA runs one unfused conv.  Training mode (is_test=False) keeps
   XLA's conv and fuses the BN-stats reduction + scale/shift + activation
   via nn.functional.norm.batch_norm_act (Pallas when gated, jnp

@@ -515,7 +515,7 @@ class FuseConvBNAct(Pass):
     """conv2d → batch_norm [→ act] ⇒ ``fused_conv2d_bn_act``
     (ref conv_bn_fuse_pass.cc + conv_elementwise_add_act_fuse_pass.cc).
 
-    The generalized replacement for the r05 hand-fold: instead of every
+    The generalized replacement for the hand-written fold: instead of every
     inference batch_norm paying a per-activation a·x+b
     (nn/functional/norm.py), the pass folds the BN into the conv *filter*
     (see static/ops_fused.py).  Training batch_norms fuse too: the fused

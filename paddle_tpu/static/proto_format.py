@@ -516,7 +516,7 @@ def program_to_desc(program, feeds: Sequence[str],
 
     # mirror of the import-side guard (program_from_desc): a silently
     # truncated export would round-trip to a program missing its cond/while
-    # bodies — fail legibly instead (ADVICE round-5 finding)
+    # bodies — fail legibly instead
     if (len(program.blocks) > 1
             or any(a in op.attrs for op in program.global_block().ops
                    for a in SUB_BLOCK_ATTRS)):

@@ -780,7 +780,7 @@ def estimate_comm(program: Program, plan, mesh=None,
     """Static per-bucket allreduce wire-byte estimate for the plan's
     data-parallel gradient sync — same bucketing and wire math as
     ``compress.sync_gradients`` (bucket_assignment + wire_bytes), so on the
-    fleet/collbench path the estimate matches the traced
+    fleet path the estimate matches the traced
     ``comm.allreduce_bytes`` records — plus the per-site vocab-sharded
     embedding exchange bytes (mirroring the traced ``emb.exchange_bytes``)
     so recommender plans score their dominant collective honestly."""

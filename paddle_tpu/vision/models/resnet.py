@@ -4,8 +4,7 @@ BASELINE.json config-2 flagship, "PaddleClas ResNet-50").
 TPU notes: layout is selectable.  ``data_format="NCHW"`` matches the
 reference default; ``"NHWC"`` runs every conv/BN/pool channels-last —
 the TPU-native layout (C rides the 128-lane minor dim, XLA stops
-materializing layout conversions around each conv; the r05 vision-perf
-ladder measured this as the dominant single-chip win).  Parameters keep
+materializing layout conversions around each conv).  Parameters keep
 the reference OIHW layout either way, so checkpoints are
 layout-portable.  BasicBlock for 18/34, BottleneckBlock for 50/101/152.
 """

@@ -224,16 +224,20 @@ def test_bucketed_unquantized_matches_pmean():
 
 
 @needs_devices
-def test_quantized_allreduce_error_bound():
-    """int8 allreduce vs exact psum: relative L2 error stays small (each
-    element is off by at most a quantization step of its block, twice)."""
+@pytest.mark.parametrize("kind, bound", [("int8", 0.05), ("fp8", 0.2)])
+def test_quantized_allreduce_error_bound(kind, bound):
+    """Quantized allreduce vs exact psum: relative L2 error stays small
+    (each element is off by at most a quantization step of its block,
+    twice)."""
+    if kind == "fp8" and not hasattr(jnp, "float8_e4m3fn"):
+        pytest.skip("no fp8 dtype in this jaxlib")
     m = _mesh(8)
     xs = _per_shard(1)
 
     def both(x_local):
         x = x_local[0]
         exact = jax.lax.psum(x, DP_AXIS)
-        q = compress.all_reduce_compressed(x, DP_AXIS, compress="int8",
+        q = compress.all_reduce_compressed(x, DP_AXIS, compress=kind,
                                            block_size=256)
         return exact, q
 
@@ -241,7 +245,7 @@ def test_quantized_allreduce_error_bound():
         exact, q = _shard_map(both, m, (P(DP_AXIS),), (P(), P()))(xs)
     exact, q = np.asarray(exact), np.asarray(q)
     rel = np.linalg.norm(q - exact) / np.linalg.norm(exact)
-    assert rel <= 0.05, rel
+    assert rel <= bound, rel
 
 
 @needs_devices
@@ -604,25 +608,3 @@ def test_compile_cache_warm_start_under_comm_quantize(_flags_guard, tmp_path):
     _train(other, main, startup, loss, steps=1)
     _, m1, _ = _cc_counters(reg)
     assert m1 - m0 >= 1
-
-
-# ---------------------------------------------------------------------------
-# collbench selfcheck rides tier-1
-# ---------------------------------------------------------------------------
-
-@needs_devices
-def test_collbench_selfcheck():
-    repo = Path(__file__).resolve().parents[1]
-    # the selfcheck is a CPU host-topology smoke (8 forced host devices)
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PYTHONPATH=str(repo) + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
-    proc = subprocess.run(
-        [sys.executable, "-m", "tools.collbench", "--selfcheck"],
-        cwd=repo, capture_output=True, text=True, timeout=580, env=env)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["parity"]["unquantized_bitwise"] is True
-    int8 = [c for c in rec["configs"]
-            if c["compress"] == "int8" and c["schedule"] == "flat"]
-    assert int8 and int8[0]["wire_ratio"] <= 0.30

@@ -1,11 +1,8 @@
 """Steady-state step fast path: donation parity + safety guard, async
 dispatch, compile-cache stability, and the host→device prefetch stage
 (io/prefetch.py DeviceFeeder wired through DataLoader and Model.fit)."""
-import subprocess
-import sys
 import threading
 import time
-from pathlib import Path
 
 import jax
 import numpy as np
@@ -348,15 +345,3 @@ def test_lazy_logs_defer_materialization():
     assert calls == ["loss"]
     assert logs["loss"] == 1.25 and calls == ["loss"]  # forced once
     assert dict(logs.materialize()) == {"step": 3, "loss": 1.25}
-
-
-# ---------------------------------------------------------------------------
-# tools/stepbench rides tier-1 via --selfcheck
-# ---------------------------------------------------------------------------
-def test_stepbench_selfcheck():
-    repo = Path(__file__).resolve().parents[1]
-    proc = subprocess.run(
-        [sys.executable, "-m", "tools.stepbench", "--selfcheck"],
-        cwd=repo, capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "stepbench selfcheck: OK" in proc.stdout

@@ -254,9 +254,11 @@ class TestAutogradBridge:
         assert losses[-1] < losses[0] * 0.1, losses[::20]
 
 
-def test_linear_transpose_dw_schedule_matches_default(monkeypatch):
-    """PDTPU_LINEAR_DW=transpose (the recorded dW-schedule experiment,
-    ROADMAP "Recorded non-wins", r04) must be numerically identical to the default path."""
+def test_linear_supports_forward_mode_ad_whatever_the_environment(
+        monkeypatch):
+    """`F.linear` reads nothing of the environment: with the variable of the
+    deleted dW toggle set, forward-mode AD works through it (the toggle's
+    path was a `custom_vjp`) and the gradients are the unset run's, bitwise."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -274,7 +276,12 @@ def test_linear_transpose_dw_schedule_matches_default(monkeypatch):
     monkeypatch.delenv("PDTPU_LINEAR_DW", raising=False)
     ref = jax.grad(loss, argnums=(0, 1, 2))(x, w, b)
     monkeypatch.setenv("PDTPU_LINEAR_DW", "transpose")
-    alt = jax.grad(loss, argnums=(0, 1, 2))(x, w, b)
-    for r, a in zip(ref, alt):
-        np.testing.assert_allclose(np.asarray(r), np.asarray(a),
-                                   rtol=1e-5, atol=1e-5)
+    got = jax.grad(loss, argnums=(0, 1, 2))(x, w, b)
+    for r, g in zip(ref, got):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+    out, tangent = jax.jvp(lambda w_: F.linear(x, w_, b), (w,),
+                           (jnp.ones_like(w),))
+    np.testing.assert_allclose(
+        np.asarray(tangent),
+        np.broadcast_to(np.asarray(x).sum(-1, keepdims=True), out.shape),
+        rtol=1e-5, atol=1e-5)

@@ -21,11 +21,8 @@ Covers the subsystem end to end:
   * serving: ``add_embedding_tenant`` submit-side dedup returns rows in
     token order bitwise;
   * fleet strategy plumbing, the ShardedEmbedding class, PS host-table
-    interop, plan-fingerprint coverage, and the recbench selfcheck.
+    interop, plan-fingerprint coverage.
 """
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -542,15 +539,3 @@ def test_plan_fingerprint_carries_embedding_config():
                          embedding_capacity=1.2, embedding_quantize="int8")
     prints = {p.fingerprint() for p in (base, covered, tuned)}
     assert len(prints) == 3
-
-
-# ---------------------------------------------------------------------------
-# recbench rides tier-1 through its selfcheck
-# ---------------------------------------------------------------------------
-
-def test_recbench_selfcheck():
-    out = subprocess.run(
-        [sys.executable, "-m", "tools.recbench", "--selfcheck"],
-        capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr
-    assert "recbench selfcheck: OK" in out.stderr

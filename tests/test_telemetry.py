@@ -1,7 +1,6 @@
 """Live telemetry plane (utils/telemetry.py): HTTP exposition of metrics /
-health / flight ring / xprof / spans / calibration ledger, per-rank
-servers under `launch --telemetry_port`, and the tools/benchdiff
-regression gate.
+health / flight ring / xprof / spans / calibration ledger, and per-rank
+servers under `launch --telemetry_port`.
 
 The server smoke here is the tier-1 CI gate the ISSUE requires: start,
 scrape /metrics + /healthz, round-trip the exposition through
@@ -9,8 +8,6 @@ scrape /metrics + /healthz, round-trip the exposition through
 and run daemon threads, so pytest never hangs on shutdown."""
 import json
 import os
-import subprocess
-import sys
 import textwrap
 import time
 import urllib.error
@@ -309,108 +306,6 @@ def test_launch_two_ranks_serve_live_metrics_and_healthz(tmp_path):
         assert doc["peer"]["telemetry_port"] == float(base + (1 - rank))
         assert doc["peer"]["mark"] == float((1 - rank) + 1)
         assert doc["peer"]["healthz"]["rank"] == 1 - rank
-
-
-# ---------------------------------------------------------------------------
-# tools/benchdiff: the regression gate
-# ---------------------------------------------------------------------------
-
-def _bench(tmp_path, name, doc):
-    p = tmp_path / name
-    p.write_text(json.dumps(doc))
-    return str(p)
-
-
-def test_benchdiff_passes_identical_fails_seeded_regression(tmp_path):
-    from tools import benchdiff
-
-    base = {"parsed": {"metric": "pretrain_throughput", "value": 100.0,
-                       "unit": "tokens/sec/chip"},
-            "results": [{"metric": "serve_p99_ms", "value": 10.0,
-                         "unit": "ms"}]}
-    a = _bench(tmp_path, "a.json", base)
-    b = _bench(tmp_path, "b.json", base)
-    same = benchdiff.diff_metrics(benchdiff.extract_metrics(a),
-                                  benchdiff.extract_metrics(b))
-    assert same["verdict"] == "pass" and same["compared"] == 2
-
-    worse = {"parsed": dict(base["parsed"], value=80.0),   # -20% throughput
-             "results": [dict(base["results"][0], value=12.0)]}  # +20% p99
-    c = _bench(tmp_path, "c.json", worse)
-    bad = benchdiff.diff_metrics(benchdiff.extract_metrics(a),
-                                 benchdiff.extract_metrics(c))
-    assert bad["verdict"] == "fail"
-    assert {e["metric"] for e in bad["regressions"]} == {
-        "pretrain_throughput", "serve_p99_ms"}
-    # direction awareness: +20% throughput / -20% p99 are IMPROVEMENTS
-    better = {"parsed": dict(base["parsed"], value=120.0),
-              "results": [dict(base["results"][0], value=8.0)]}
-    d = _bench(tmp_path, "d.json", better)
-    good = benchdiff.diff_metrics(benchdiff.extract_metrics(a),
-                                  benchdiff.extract_metrics(d))
-    assert good["verdict"] == "pass"
-    assert len(good["improvements"]) == 2
-    # per-metric tolerance override widens just the noisy metric
-    ok = benchdiff.diff_metrics(benchdiff.extract_metrics(a),
-                                benchdiff.extract_metrics(c),
-                                overrides=[("p99", 0.5),
-                                           ("throughput", 0.5)])
-    assert ok["verdict"] == "pass"
-
-
-def test_benchdiff_reads_real_bench_ledger_and_record_schema(tmp_path):
-    from tools import benchdiff
-
-    # all three record schemas parse: the driver's {"parsed": {...}} line,
-    # a {"results": [...]} table (both built here with made-up values),
-    # and the repo's own nested BENCH_SERVE.json
-    driver = _bench(tmp_path, "driver.json", {
-        "n": 1, "cmd": "python bench.py", "rc": 0,
-        "parsed": {"metric": "ernie_base_pretrain_throughput",
-                   "value": 1000.0, "unit": "tokens/sec/chip",
-                   "platform": "tpu", "batch": 64, "seq_len": 512}})
-    table = _bench(tmp_path, "table.json", {"results": [
-        {"metric": "resnet50_train_throughput", "value": 10.0,
-         "unit": "imgs/sec/chip", "platform": "tpu", "mfu_est": 0.1},
-        {"metric": "conv_infer_throughput", "value": 20.0,
-         "unit": "imgs/sec/chip", "platform": "cpu", "mfu_est": None}]})
-    for f in (driver, table, os.path.join(REPO, "BENCH_SERVE.json")):
-        metrics = benchdiff.extract_metrics(f)
-        assert metrics, f
-    serve = benchdiff.extract_metrics(os.path.join(REPO, "BENCH_SERVE.json"))
-    assert "batched.qps" in serve            # nested record flattening
-    assert benchdiff.direction_of("batched.qps") == "higher"
-    assert benchdiff.direction_of("batched.p50_ms") == "lower"
-    assert benchdiff.direction_of("mystery_metric") == "both"
-    with pytest.raises(ValueError):
-        benchdiff.extract_metrics(
-            _bench(tmp_path, "empty.json", {"nothing": True}))
-
-
-def test_benchdiff_cli_selfcheck_and_verdict_line(tmp_path):
-    out = subprocess.run(
-        [sys.executable, "-m", "tools.benchdiff", "--selfcheck"],
-        cwd=REPO, capture_output=True, text=True, timeout=120)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert json.loads(out.stdout)["selfcheck"] == "pass"
-
-    base = {"parsed": {"metric": "tput", "value": 100.0,
-                       "unit": "rows/sec"}}
-    a = _bench(tmp_path, "a.json", base)
-    c = _bench(tmp_path, "c.json",
-               {"parsed": dict(base["parsed"], value=70.0)})
-    ok = subprocess.run([sys.executable, "-m", "tools.benchdiff", a, a],
-                        cwd=REPO, capture_output=True, text=True,
-                        timeout=120)
-    assert ok.returncode == 0
-    assert json.loads(ok.stdout)["verdict"] == "pass"
-    bad = subprocess.run([sys.executable, "-m", "tools.benchdiff", a, c],
-                         cwd=REPO, capture_output=True, text=True,
-                         timeout=120)
-    assert bad.returncode == 1               # the gate: nonzero on regression
-    verdict = json.loads(bad.stdout)
-    assert verdict["verdict"] == "fail"
-    assert verdict["regressions"][0]["metric"] == "tput"
 
 
 # ---------------------------------------------------------------------------

@@ -16,8 +16,8 @@ Demo models (``--model``):
                    sharded embedding)
   * ``resblock`` — a ResNet block: conv-bn-relu x2 + skip (hand plan:
                    pure dp; conv weights are 4-D so dp is the space)
-  * ``rec``      — recbench's wide&deep CTR model (hand plan: tp8
-                   vocab-sharded embeddings, recbench's own)
+  * ``rec``      — a wide&deep CTR model (hand plan: tp8 vocab-sharded
+                   embeddings)
 
 Usage::
 
@@ -162,12 +162,41 @@ def _build_resblock(batch: int, channels: int = 8, hw: int = 8):
     return main, startup, loss, feed, hand_plan
 
 
+def _build_ctr(vocab: int, dim: int, slots: int, lr: float):
+    """The wide&deep program: a wide ``(V, 1)`` linear table + a deep
+    ``(V, D)`` embedding -> MLP, sigmoid + log loss.  Returns (main,
+    startup, loss)."""
+    import paddle_tpu.static as static
+    from paddle_tpu.static import layers as L
+
+    main, startup = static.Program(), static.Program()
+    with static.program_guard(main, startup):
+        ids = L.data("ids", [slots], dtype="int64")
+        y = L.data("y", [1])
+        deep = L.embedding(ids, size=[vocab, dim], name="deep_emb")
+        wide = L.embedding(ids, size=[vocab, 1], name="wide_emb")
+        concat = L.reshape(deep, (-1, slots * dim))
+        hidden = L.fc(concat, max(16, dim), act="relu")
+        deep_logit = L.fc(hidden, 1)
+        wide_logit = L.fc(L.reshape(wide, (-1, slots)), 1)
+        prob = L.sigmoid(L.elementwise_add(wide_logit, deep_logit))
+        loss = L.mean(L.log_loss(prob, y))
+        static.optimizer.SGD(learning_rate=lr).minimize(loss)
+    return main, startup, loss
+
+
+def _zipf_ids(rng, vocab: int, shape, a: float = 1.3):
+    """Skewed id draw (popular items dominate — the CTR dedup payoff)."""
+    import numpy as np
+
+    z = rng.zipf(a, size=shape)
+    return ((z - 1) % vocab).astype(np.int64)
+
+
 def _build_rec(batch: int, vocab: int = 256, dim: int = 8, slots: int = 4):
     import numpy as np
-    from tools.recbench import _build_ctr, _zipf_ids
 
-    main, startup, loss, _emb_out, _wname = _build_ctr(vocab, dim, slots,
-                                                       lr=0.05)
+    main, startup, loss = _build_ctr(vocab, dim, slots, lr=0.05)
     rng = np.random.default_rng(0)
     feed = {"ids": _zipf_ids(rng, vocab, (batch, slots)),
             "y": (rng.random(size=(batch, 1)) < 0.3).astype(np.float32)}
@@ -176,7 +205,7 @@ def _build_rec(batch: int, vocab: int = 256, dim: int = 8, slots: int = 4):
         from jax.sharding import Mesh
         from paddle_tpu.parallel.sharding import ShardingPlan
 
-        # recbench's own: every device on tp, blanket vocab sharding
+        # every device on tp, blanket vocab sharding
         mesh = Mesh(np.asarray(devices).reshape(1, len(devices)),
                     ("dp", "tp"))
         return ShardingPlan(mesh=mesh, embedding_shard="tp")

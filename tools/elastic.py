@@ -37,8 +37,8 @@ import sys
 
 
 def _force_host_devices(n: int = 8) -> None:
-    """Before the first jax import: make XLA expose n host devices (the
-    stepbench pattern) so dp meshes exist on a CPU-only machine."""
+    """Before the first jax import: make XLA expose n host devices so dp
+    meshes exist on a CPU-only machine."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

@@ -25,13 +25,12 @@ into one job-level report:
   every rank is ONE job alert listing the affected ranks, not N pages.
   ``/history`` supplies per-rank ``slo.burn_rate`` series rendered as
   text-mode sparklines, and ``--gate`` makes the exit code non-zero
-  while any job-level alert is firing — CI/benchdiff-style jobs fail on
-  burning SLOs like on any other regression,
+  while any job-level alert is firing — a CI job fails on burning SLOs
+  like on any other regression,
 
 in ``--format text`` / ``--format json`` / ``--watch`` modes.  The JSON
-report carries a flat numeric ``record`` block, so it is directly
-consumable by ``tools/benchdiff`` (its ``"record"`` extractor) — fleet
-skew and calibration drift gate like any other benchmark number.  This
+report carries a flat numeric ``record`` block (fleet skew, calibration
+drift, alerts firing: one number each, for a gate to compare).  This
 is also the scrape client ROADMAP item 4's serving-fleet router reuses.
 
 Usage::
@@ -322,7 +321,7 @@ def merge(scrapes: List[Dict[str, Any]], straggler_factor: float = 2.0,
     report["alerts"] = _alerts_section(scrapes, ranks)
     report["burn_history"] = _burn_history(scrapes, ranks)
 
-    # -- flat numeric verdict for tools/benchdiff -------------------------
+    # -- flat numeric verdict --------------------------------------------
     record: Dict[str, Any] = {
         "fleet": {"nranks": len(scrapes), "healthy_ranks": healthy,
                   "stragglers": len(stragglers)},
@@ -642,8 +641,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                         "the merged report (CI smoke)")
     parser.add_argument("--gate", action="store_true",
                         help="exit non-zero (3) while any job-level SLO "
-                        "alert is firing — CI/benchdiff-style jobs fail "
-                        "on burning SLOs")
+                        "alert is firing — a CI job fails on burning "
+                        "SLOs")
     args = parser.parse_args(argv)
 
     if args.selfcheck:

@@ -1,6 +1,6 @@
 """Serving-frontend load generator: closed-loop and open-loop (qps ramp)
 benchmarks of ``paddle_tpu.serving`` plus the continuous-batching decode
-path, printing exactly ONE JSON line (BENCH_SERVE.json schema).
+path, printing exactly ONE JSON line.
 
 What it measures:
 
@@ -454,7 +454,7 @@ def _parser():
                     help="run the paged-KV serving blocks (capacity, "
                          "decode vs continuous, TTFT mix, prefix cache)")
     ap.add_argument("--out", default="",
-                    help="also write the BENCH_SERVE.json document here")
+                    help="also write the JSON document here")
     ap.add_argument("--selfcheck", action="store_true")
     return ap
 

@@ -15,7 +15,7 @@ Usage::
     python -m tools.xprof --input report.json --top 5        # re-render a dump
     python -m tools.xprof --selfcheck            # CI assertion mode (tier-1)
 
-The toy models are stepbench-shaped (fc regression / deeper mlp) and run a
+The toy models (fc regression / deeper mlp) run a
 few measured steps first, so the report's MFU and modeled-vs-measured drift
 are anchored by the real ``executor.step_time_ms`` median — on CPU CI the
 absolute MFU is meaningless (fallback peaks), but attribution coverage,
@@ -36,15 +36,15 @@ import sys
 
 
 def _ensure_cpu_devices() -> None:
-    """Default JAX to CPU when no flag is set, mirroring stepbench: the
-    tool must run on a build box without TPUs attached."""
+    """Default JAX to CPU when no flag is set: the tool must run on a
+    build box without TPUs attached."""
     import os
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def build_toy(model: str, batch: int, hidden: int):
-    """A stepbench-style toy training program: (main, startup, loss, feeds)."""
+    """A toy training program: (main, startup, loss, feeds)."""
     import numpy as np
 
     import paddle_tpu.static as static
