@@ -144,12 +144,15 @@ def flash_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
         if n:
             seed = draw_dropout_seed(n, rate)
 
-            def kernel(q, k, v, bias, seed):
+            def kernel(q, k, v, seed, bias=None):
                 return fa.flash_attention(q, k, v, bias=bias, sm_scale=scale,
                                           causal=is_causal, dropout_rate=rate,
                                           seed=seed)
 
-            return _mesh.per_batch_shard(kernel, n, (q, k, v, bias, seed))
+            # no mask is no bias operand: the kernels then neither stream
+            # nor add one (a zero bias costs a pass over every score tile)
+            operands = (q, k, v, seed) + (() if attn_mask is None else (bias,))
+            return _mesh.per_batch_shard(kernel, n, operands)
         reason = "partial_manual_mesh"
     if reason == "partial_manual_mesh" or (
             reason and is_causal and attn_mask is None):

@@ -93,6 +93,12 @@ _m_fallbacks = monitor.counter(
     "pallas.fallbacks",
     "Dispatches that fell back to the XLA lowering, labeled kernel/reason.",
     labelnames=("kernel", "reason"))
+_m_flash_tiles = monitor.gauge(
+    "pallas.flash.tiles",
+    "Score tiles a head in the flash_attention call traced last, by where "
+    "they lie: wholly under the causal diagonal, on it, above it (skipped, "
+    "never computed); each of the three kernels walks the same tiles.",
+    labelnames=("kind",))
 
 
 def record_call(kernel: str) -> None:
@@ -101,6 +107,15 @@ def record_call(kernel: str) -> None:
 
 def record_fallback(kernel: str, reason: str = "unsupported") -> None:
     _m_fallbacks.inc(kernel=kernel, reason=reason)
+
+
+def record_flash_tiles(under_diagonal: int, on_diagonal: int,
+                       skipped: int) -> None:
+    """A head's score tiles at the blocks a flash_attention call runs
+    (static shapes: known when the wrapper is traced)."""
+    for kind, n in (("under_diagonal", under_diagonal),
+                    ("on_diagonal", on_diagonal), ("skipped", skipped)):
+        _m_flash_tiles.set(n, kind=kind)
 
 
 def counted(kernel: str, supported: bool) -> bool:

@@ -291,19 +291,7 @@ class TestPackedLayout:
         seed = jnp.zeros((1,), jnp.int32)
         args = (1.0 / np.sqrt(d), False, 0.0, block, block)
         o, lse = fp._forward(q, k, v, bias, seed, h, *args)
-        seen = {}
-        orig = fp.pl.pallas_call
-
-        def spy(kernel, **kw):
-            call = orig(kernel, **kw)
-
-            def run(*operands):
-                out = call(*operands)
-                seen[kw["name"]] = (operands, out)
-                return out
-            return run
-
-        monkeypatch.setattr(fp.pl, "pallas_call", spy)
+        seen = _spy_pallas_calls(monkeypatch)   # fp.pl is fa.pl
         fp._backward(q, k, v, bias, seed, h, o, lse, do, *args)
         want = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
                         ).reshape(b, s, h, d), axis=-1)          # (b, s, h)
@@ -329,26 +317,9 @@ class TestPackedLayout:
         x = jnp.zeros((b, s, h * d), jnp.bfloat16)
         jaxpr = jax.make_jaxpr(jax.vjp(
             lambda q, k, v: packed(q, k, v, h), x, x, x)[1])(x)
-        full = b * s * h * d
-        allowed = {"pallas_call", "reshape", "broadcast_in_dim"}
-        kernels, outside = [], []
-
-        def walk(jp):
-            for eqn in jp.eqns:
-                name = eqn.primitive.name
-                if name == "pallas_call":
-                    kernels.append(eqn.params["name"])
-                    continue        # what a kernel does inside is its own
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    walk(sub)
-                sizes = [getattr(a.aval, "size", 0)
-                         for a in list(eqn.invars) + list(eqn.outvars)]
-                if name not in allowed and max(sizes, default=0) >= full:
-                    outside.append(name)
-
-        walk(jaxpr.jaxpr)
+        kernels, outside = _pallas_calls_and_outside(jaxpr, b * s * h * d)
         assert not outside, outside
-        assert kernels == ["flash_packed_dq", "flash_packed_dkdv"]
+        assert list(kernels) == ["flash_packed_dq", "flash_packed_dkdv"]
 
     @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
     @pytest.mark.parametrize("rate", [0.0, 0.1], ids=["nodrop", "drop0.1"])
@@ -402,3 +373,233 @@ class TestPackedLayout:
         assert calls == [True], "packed path did not engage"
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+
+# -- the specialised tile (PR 31) ---------------------------------------------
+
+def _plain_attention(q, k, v, bias, causal, keep, rate):
+    """float32 reference with the kernel's own dropout draw (`keep`)."""
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if bias is not None:
+        s = s + bias[:, None, None, :]
+    if causal:
+        n = s.shape[-1]
+        s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
+    p = jax.nn.softmax(s, -1)
+    if keep is not None:
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("rate", [0.0, 0.1], ids=["nodrop", "drop0.1"])
+@pytest.mark.parametrize("d,d_v", [(64, 64), (192, 128)],
+                         ids=["d64", "d192v128"])
+@pytest.mark.parametrize("block_q,block_k", [
+    (256, 256),     # one tile, on the diagonal
+    (64, 64),       # several: one masked tile a q-block, the rest unmasked
+    (128, 32),      # four tiles of a q-block straddle the diagonal
+    (32, 128),      # four q-blocks straddle a k-block's diagonal
+], ids=["one", "several", "wide_q", "wide_k"])
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "padbias"])
+def test_causal_tile_variants_match_reference(masked, block_q, block_k, d, d_v,
+                                              rate, dtype):
+    """Forward and dq/dk/dv of every variant of the causal tile (mask on
+    the diagonal tiles only, bias or none, dropout or none, q·k and v head
+    sizes apart) against the plain reference under the same dropout draw."""
+    b, h, s = 2, 2, 256
+    rng = np.random.default_rng(5)
+    q, k = (jnp.asarray(rng.normal(0, 1, (b, h, s, d)), dtype)
+            for _ in range(2))
+    v, do = (jnp.asarray(rng.normal(0, 1, (b, h, s, d_v)), dtype)
+             for _ in range(2))
+    bias = None
+    if masked:
+        bias = np.zeros((b, s), np.float32)
+        bias[0, 200:] = -1e4
+        bias = jnp.asarray(bias)
+    seed = jnp.asarray([31], jnp.int32)
+    keep = None
+    if rate:
+        qpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
+        keep = jnp.stack([
+            fa._dropout_keep(seed[0], jnp.int32(i), qpos, kpos, rate)
+            for i in range(b * h)]).reshape(b, h, s, s)
+
+    def kernel(q, k, v):
+        return fa.flash_attention(q, k, v, bias=bias, causal=True,
+                                  dropout_rate=rate, seed=seed,
+                                  block_q=block_q, block_k=block_k)
+
+    def plain(q, k, v):
+        return _plain_attention(q, k, v, bias, True, keep, rate)
+
+    out, vjp = jax.vjp(kernel, q, k, v)
+    ref, vjp_ref = jax.vjp(plain, q, k, v)
+    # bf16: the kernel rounds p, dS and the scaled q/k block to bf16 where
+    # the reference keeps float32
+    tol = 1e-5 if dtype == jnp.float32 else 2.5e-2
+    pairs = zip(("o", "dq", "dk", "dv"), (out,) + vjp(do),
+                (ref,) + vjp_ref(do.astype(jnp.float32)))
+    for name, a, r in pairs:
+        assert a.dtype == dtype, name
+        scale = max(float(jnp.abs(r).max()), 1.0)
+        np.testing.assert_allclose(
+            np.asarray(a.astype(jnp.float32)), np.asarray(r),
+            rtol=tol * 10, atol=tol * scale, err_msg=name)
+
+
+def test_no_mask_no_dropout_non_causal_tile(qkv):
+    """The leanest tile: no bias, no mask, no positions at all."""
+    q, k, v = qkv
+    out = fa.flash_attention(q, k, v, block_q=64, block_k=32)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(sdpa(q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    g = jax.grad(lambda t: (fa.flash_attention(*t, block_q=64, block_k=32)
+                            ** 2).sum())((q, k, v))
+    g_ref = jax.grad(lambda t: (sdpa(*t) ** 2).sum())((q, k, v))
+    for a, r in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def _spy_pallas_calls(monkeypatch):
+    """Every pallas_call of the standard kernel's module, by name: its
+    operands and what it returned."""
+    seen = {}
+    orig = fa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        call = orig(kernel, **kw)
+
+        def run(*operands):
+            out = call(*operands)
+            seen[kw["name"]] = (operands, out)
+            return out
+        return run
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    return seen
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("d,d_v,s,block", [
+    (64, 64, 128, 128),         # one q-block
+    (64, 64, 512, 128),         # four: the delta output's block spec
+    (192, 128, 256, 128),       # the latent-attention head sizes
+])
+def test_delta_is_float32_rowsum_of_do_times_o(monkeypatch, d, d_v, s, block,
+                                               dtype):
+    """The row sums `flash_dq` makes and hands `flash_dkdv` are
+    rowsum(dO * O), in float32 from the inputs as they come."""
+    bh = 4
+    rng = np.random.default_rng(3)
+    q, k = (jnp.asarray(rng.normal(0, 1, (bh, s, d)), dtype)
+            for _ in range(2))
+    v, do = (jnp.asarray(rng.normal(0, 1, (bh, s, d_v)), dtype)
+             for _ in range(2))
+    seed = jnp.zeros((1,), jnp.int32)
+    args = (1.0 / np.sqrt(d), True, 0.0, block, block)
+    o, res = fa._fwd(q, k, v, None, seed, *args)
+    seen = _spy_pallas_calls(monkeypatch)
+    fa._bwd(*args, res, do)
+    want = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    made = seen["flash_dq"][1][1]
+    assert made.dtype == jnp.float32 and made.shape == (bh, 1, s)
+    np.testing.assert_allclose(np.asarray(made[:, 0]), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    # and that array, not another, is what the dkdv kernel reads
+    assert seen["flash_dkdv"][0][-1] is made
+
+
+def _pallas_calls_and_outside(jaxpr, full):
+    """The jaxpr's pallas_calls (name -> operand shapes) and the names of
+    the equations outside them that touch an operand of `full` elements or
+    more, reshape/broadcast aside."""
+    allowed = {"pallas_call", "reshape", "broadcast_in_dim"}
+    kernels, outside = {}, []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                kernels[eqn.params["name"]] = [
+                    tuple(a.aval.shape) for a in eqn.invars]
+                continue            # what a kernel does inside is its own
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+            sizes = [getattr(a.aval, "size", 0)
+                     for a in list(eqn.invars) + list(eqn.outvars)]
+            if name not in allowed and max(sizes, default=0) >= full:
+                outside.append(name)
+
+    walk(jaxpr.jaxpr)
+    return kernels, outside
+
+
+def test_grad_runs_nothing_full_size_outside_its_kernels_and_no_zero_bias(
+        monkeypatch):
+    """Through the dispatch site with `attn_mask=None`: the three kernels
+    take no (b, s) bias operand, and no equation outside a pallas_call
+    touches a (b*h, s, .) operand but reshape/broadcast — the row sums
+    cannot drift back out of `flash_dq` unnoticed."""
+    monkeypatch.setattr(pcfg, "kernel_enabled",
+                        lambda name: bool(flags.get_flag(name)))
+    b, h, s, d, d_v = 2, 3, 256, 192, 128
+    q = jnp.zeros((b, h, s, d), jnp.bfloat16)
+    v = jnp.zeros((b, h, s, d_v), jnp.bfloat16)
+
+    def both(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: attn_ops.flash_attention(
+            q, k, v, is_causal=True), q, k, v)
+        return out, vjp(g)
+
+    kernels, outside = _pallas_calls_and_outside(
+        jax.make_jaxpr(both)(q, q, v, v), b * h * s * d_v)
+    assert not outside, outside
+    assert list(kernels) == ["flash_fwd", "flash_dq", "flash_dkdv"]
+    assert [len(ops) for ops in kernels.values()] == [4, 7, 7]
+    for name, shapes in kernels.items():
+        assert not any(sh[0] == b and sh[-1] == s for sh in shapes), (
+            name, shapes)
+    # a given padding mask still streams, through all three
+    mask = jnp.ones((b, 1, 1, s), bool)
+    kernels, _ = _pallas_calls_and_outside(jax.make_jaxpr(
+        lambda q, k, v: jax.grad(lambda q: attn_ops.flash_attention(
+            q, k, v, attn_mask=mask, is_causal=True).astype(
+                jnp.float32).sum())(q))(q, q, v), b * h * s * d_v)
+    assert all((b, 1, s) in shapes for shapes in kernels.values()), kernels
+
+
+@pytest.mark.parametrize("block_q,block_k,causal,want", [
+    (512, 512, True, (28, 8, 28)),      # cell 4 as it runs
+    (256, 512, True, (56, 16, 56)),
+    (512, 256, True, (56, 16, 56)),
+    (512, 512, False, (64, 0, 0)),
+])
+def test_flash_tiles_counter_at_cell_4s_shape(block_q, block_k, causal, want):
+    """`pallas.flash.tiles{kind}` says which of a head's tiles the call
+    computes and which it skips; without explicit blocks cell 4's call
+    runs 512 x 512."""
+    from paddle_tpu.utils import monitor
+
+    assert fa.tile_counts(4096, block_q, block_k, causal) == want
+    q = jax.ShapeDtypeStruct((2, 32, 4096, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, 32, 4096, 128), jnp.bfloat16)
+    blocks = {} if (block_q, block_k) == (512, 512) else dict(
+        block_q=block_q, block_k=block_k)
+    jax.eval_shape(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, **blocks), q, q, v)
+    gauge = monitor.default_registry().get("pallas.flash.tiles")
+    read = {labels["kind"]: n for labels, n in gauge.samples()}
+    assert tuple(read[kind] for kind in
+                 ("under_diagonal", "on_diagonal", "skipped")) == want
+    # the dkdv kernel walks q-blocks of a k-block: the same tiles
+    computed = sum(
+        4096 // block_q - (j * block_k // block_q if causal else 0)
+        for j in range(4096 // block_k))
+    assert computed == want[0] + want[1]

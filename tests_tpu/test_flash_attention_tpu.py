@@ -32,7 +32,9 @@ def _qkv():
                  for _ in range(3))
 
 
-def _dump_mask(seed, bq=512, bk=512):
+def _dump_mask(seed, bq=512, bk=512, bh=B * H, s=S):
+    """The keep mask the kernels draw, (bh, s_q, s_k): they hold a tile
+    with the keys along sublanes, `(block_k, block_q)`, and draw it so."""
     from jax.experimental import pallas as pl
 
     def kernel(seed_ref, out_ref):
@@ -41,19 +43,19 @@ def _dump_mask(seed, bq=512, bk=512):
 
         def body(kv, _):
             keep = fa._dropout_keep_hw(seed_ref[0], bh_idx, qi, kv,
-                                       (bq, bk), RATE)
-            out_ref[0, :, pl.dslice(kv * bk, bk)] = keep
+                                       (bk, bq), RATE)
+            out_ref[0, pl.dslice(kv * bk, bk), :] = keep
             return 0
 
-        jax.lax.fori_loop(0, S // bk, body, 0)
+        jax.lax.fori_loop(0, s // bk, body, 0)
 
     mask = pl.pallas_call(
-        kernel, grid=(B * H, S // bq),
+        kernel, grid=(bh, s // bq),
         in_specs=[pl.BlockSpec(memory_space=fa._smem())],
-        out_specs=pl.BlockSpec((1, bq, S), lambda bh_i, i: (bh_i, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, S), jnp.bool_),
+        out_specs=pl.BlockSpec((1, s, bq), lambda bh_i, i: (bh_i, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((bh, s, s), jnp.bool_),
     )(seed)
-    return np.asarray(mask).reshape(B, H, S, S)
+    return np.swapaxes(np.asarray(mask), 1, 2)
 
 
 def _ref_attn(q, k, v, mask):
@@ -69,14 +71,14 @@ def test_hw_dropout_deterministic_and_rate():
     o1 = fa.flash_attention(q, k, v, dropout_rate=RATE, seed=seed)
     o2 = fa.flash_attention(q, k, v, dropout_rate=RATE, seed=seed)
     assert np.array_equal(np.asarray(o1), np.asarray(o2))
-    mask = _dump_mask(seed)
+    mask = _dump_mask(seed).reshape(B, H, S, S)
     assert abs(mask.mean() - (1 - RATE)) < 0.01
 
 
 def test_hw_dropout_fwd_bwd_mask_consistency():
     q, k, v = _qkv()
     seed = jnp.asarray([1234], jnp.int32)
-    mask = _dump_mask(seed)
+    mask = _dump_mask(seed).reshape(B, H, S, S)
 
     out = fa.flash_attention(q, k, v, dropout_rate=RATE, seed=seed)
     ref = _ref_attn(q, k, v, mask)
@@ -90,3 +92,81 @@ def test_hw_dropout_fwd_bwd_mask_consistency():
         diff = float(jnp.abs(a - b).max())
         mag = float(jnp.abs(b).max())
         assert diff < 1e-2 * max(mag, 1.0), (name, diff, mag)
+
+
+# -- cell 4's own call: (2, 32, 4096, 192 / 128), bfloat16, causal, no mask ----
+
+CB, CH, CS, CD, CDV = 2, 32, 4096, 192, 128
+HEADS_A_CHUNK = 8       # the float32 reference holds 8 heads' score squares
+
+
+def _cell4_inputs():
+    rng = np.random.default_rng(4)
+    mk = lambda d: jnp.asarray(rng.normal(0, 1, (CB, CH, CS, d)),
+                               jnp.bfloat16)
+    return mk(CD), mk(CD), mk(CDV), mk(CDV)
+
+
+@jax.jit
+def _causal_reference_chunk(q, k, v, do, keep_scale):
+    """o, dq, dk, dv of a chunk of heads in float32 (`highest`);
+    `keep_scale` multiplies the probabilities (1 everywhere: no dropout)."""
+    def attend(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            s = jnp.einsum("hqd,hkd->hqk", q, k) / (CD ** 0.5)
+            s = jnp.where(jnp.tril(jnp.ones((CS, CS), bool)), s, -1e30)
+            p = jax.nn.softmax(s, axis=-1) * keep_scale
+            return jnp.einsum("hqk,hkd->hqd", p, v)
+
+    o, vjp = jax.vjp(attend, *(t.astype(jnp.float32) for t in (q, k, v)))
+    return (o,) + vjp(do.astype(jnp.float32))
+
+
+def _assert_matches_reference(got, inputs, keep):
+    """`got` (o, dq, dk, dv) of the kernels against the chunked float32
+    reference.  bfloat16 results: half an ulp of a value near 4 is 0.008,
+    so the file's 1e-2 is taken relative to the largest magnitude, and
+    twice (the kernels also round p, dS and the scaled block to bf16)."""
+    flat = [t.reshape(CB * CH, CS, -1) for t in inputs]
+    got = [t.reshape(CB * CH, CS, -1) for t in got]
+    for lo in range(0, CB * CH, HEADS_A_CHUNK):
+        sl = slice(lo, lo + HEADS_A_CHUNK)
+        keep_scale = (jnp.ones((1, 1, 1), jnp.float32) if keep is None
+                      else jnp.where(jnp.asarray(keep[sl]), 1 / (1 - RATE), 0))
+        want = _causal_reference_chunk(*(t[sl] for t in flat), keep_scale)
+        for name, a, r in zip(("o", "dq", "dk", "dv"), got, want):
+            diff = float(jnp.abs(a[sl].astype(jnp.float32) - r).max())
+            mag = float(jnp.abs(r).max())
+            assert diff < 2e-2 * max(mag, 1.0), (name, lo, diff, mag)
+
+
+def _kernel_o_and_grads(q, k, v, do, **kw):
+    out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, **kw), q, k, v)
+    return (out,) + vjp(do)
+
+
+def test_cell4_shape_matches_float32_reference_in_three_kernels():
+    inputs = _cell4_inputs()
+    run = jax.jit(_kernel_o_and_grads)
+    text = run.lower(*inputs).compile().as_text()
+    import re
+    calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    names = set(re.findall(r"/(flash_\w+)/pallas_call", text))
+    assert len(calls) == 3 and names == {
+        "flash_fwd", "flash_dkdv", "flash_dq"}, (len(calls), names)
+    _assert_matches_reference(run(*inputs), inputs, None)
+
+
+def test_cell4_shape_dropout_replays_one_mask_across_the_three_kernels():
+    """Dropout 0.1 at the blocks the call runs by itself (512 x 512): the
+    forward and both backward kernels against the reference under the mask
+    a dump kernel draws with the same seeding."""
+    inputs = _cell4_inputs()
+    seed = jnp.asarray([4321], jnp.int32)
+    keep = _dump_mask(seed, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K,
+                      bh=CB * CH, s=CS)
+    assert abs(keep[:, 1024:].mean() - (1 - RATE)) < 0.01
+    got = jax.jit(lambda *t: _kernel_o_and_grads(
+        *t, dropout_rate=RATE, seed=seed))(*inputs)
+    _assert_matches_reference(got, inputs, keep)

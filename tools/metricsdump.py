@@ -101,6 +101,7 @@ _KNOWN_NAMES = frozenset({
     # fused_rdln, conv2d_bn_act, bn_act_train, max_pool2d, avg_pool2d,
     # int8_matmul, int8_conv2d, paged_attention, grouped_matmul
     "pallas.fallbacks",
+    "pallas.flash.tiles",
     "pallas.kernel_calls",
     # text/deepseek_v3.py routing_stats (nn.DroplessMoE; label layer)
     "moe.held_load_max_over_mean",
