@@ -190,11 +190,13 @@ def _gated_down(gate_up, w_down):
 
 
 def sigmoid_topk_routing(x, router_weight, selection_bias, top_k: int,
-                         scaling: float = 1.0, normalize: bool = True):
+                         scaling: float = 1.0, normalize: bool = True,
+                         norm_eps: float = 1e-20):
     """DeepSeek-V3's router without groups (`n_group` = `topk_group` = 1):
     scores s = sigmoid(x·W_r) in float32; the `top_k` largest of s + b are
     selected (b takes no gradient); the weights are the selected s (without
-    b), divided by their sum, times `scaling`.  x: [T, D] -> (expert ids
+    b), divided by their sum + `norm_eps` (DeepSeek-V3's published code adds
+    1e-20, the LFM2 family 1e-6), times `scaling`.  x: [T, D] -> (expert ids
     [T, k] int32, weights [T, k] float32)."""
     logits = jnp.dot(x.astype(jnp.float32), router_weight.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
@@ -203,7 +205,8 @@ def sigmoid_topk_routing(x, router_weight, selection_bias, top_k: int,
     _, ids = lax.top_k(lax.stop_gradient(biased), top_k)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     if normalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + norm_eps)
     return ids.astype(jnp.int32), weights * scaling
 
 
@@ -284,7 +287,9 @@ class DroplessMoE(Layer):
     add nothing here (no exchange, no stand-in).  With
     `count == n_routed_experts` it is the whole layer.  The shared experts
     (one SwiGLU at n_shared_experts × d_expert) are computed by every
-    holder alike.
+    holder alike; with `n_shared_experts = 0` the layer has none, holds no
+    parameter for them and plants no `shared` scope.  `norm_eps` is what
+    the router adds to the selected scores' sum (`sigmoid_topk_routing`).
 
     Token-expert pairs are sorted by expert (held first, in order), the
     held experts run as one grouped product each way over
@@ -295,7 +300,8 @@ class DroplessMoE(Layer):
     def __init__(self, d_model: int, d_expert: int, n_routed_experts: int,
                  top_k: int, *, held=None, n_shared_experts: int = 0,
                  routed_scaling_factor: float = 1.0,
-                 norm_topk_prob: bool = True, weight_attr=None):
+                 norm_topk_prob: bool = True, norm_eps: float = 1e-20,
+                 weight_attr=None):
         super().__init__()
         first, count = held if held is not None else (0, n_routed_experts)
         if not (0 <= first and count >= 1
@@ -309,6 +315,7 @@ class DroplessMoE(Layer):
         self.held = (first, count)
         self.routed_scaling_factor = routed_scaling_factor
         self.norm_topk_prob = norm_topk_prob
+        self.norm_eps = norm_eps
         dtype = _dtype_mod.get_default_dtype()
         w_init = getattr(weight_attr, "initializer", None) or \
             init.XavierUniform()
@@ -332,7 +339,7 @@ class DroplessMoE(Layer):
         return jax.checkpoint(functools.partial(
             sigmoid_topk_routing, top_k=self.top_k,
             scaling=self.routed_scaling_factor,
-            normalize=self.norm_topk_prob))(
+            normalize=self.norm_topk_prob, norm_eps=self.norm_eps))(
                 x, self.router_weight.value, self.router_bias.value)
 
     def _pair_held(self, ids):

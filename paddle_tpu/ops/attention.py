@@ -2,7 +2,15 @@
 
 Reference parity: fused/multihead_matmul (inference-only fusion in the
 reference, SURVEY.md §5.7); here attention is a first-class training op.
-Inputs follow the (batch, num_heads, seq, head_dim) convention.
+Inputs follow the (batch, num_heads, seq, head_dim) convention.  What the
+entry points take:
+
+* `scaled_dot_product_attention`, `flash_attention`: q `(b, h, s, d)`,
+  k `(b, h_kv, s, d)`, v `(b, h_kv, s, d_v)` with `h % h_kv == 0` (query
+  head j attends key/value head `j // (h / h_kv)`; `h_kv == h` is plain
+  multi-head attention) and any `d_v` (the output's head size).
+* `flash_attention_packed`: q, k, v all `(b, s, h·d)`, one head count and
+  one head size.
 """
 from __future__ import annotations
 
@@ -16,12 +24,17 @@ from .pallas import config as _pcfg
 
 def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
                                  is_causal=False, scale=None, training=True):
-    """Reference attention: (b, h, s, d) -> (b, h, s, d).
+    """Reference attention: q (b, h, s, d), k (b, h_kv, s, d),
+    v (b, h_kv, s, d_v) -> (b, h, s, d_v); with fewer key/value heads than
+    query heads each is repeated for the query heads that share it.
 
     ``attn_mask`` is additive (float, broadcastable to (b, h, sq, sk)) or
     boolean (True = keep).
     """
     d = q.shape[-1]
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1)
+                for t in (k, v))
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if is_causal:
@@ -117,9 +130,13 @@ def flash_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
     """Dispatch to the Pallas flash-attention kernel when the backend/shape
     allow; otherwise fall back to the jnp reference implementation.
 
-    Kernel-eligible masks are k-position padding masks (shape (b,1,1,s));
-    arbitrary (b,h,sq,sk) masks fall back.  ``v`` may differ from ``q`` and
-    ``k`` in its head size (latent attention: q·k at 192, v at 128).
+    q is (b, h, s, d), k (b, h_kv, s, d), v (b, h_kv, s, d_v): ``v`` may
+    differ from ``q`` and ``k`` in its head size (latent attention: q·k at
+    192, v at 128), and ``k`` and ``v`` may have fewer heads than ``q``, a
+    divisor of its count (grouped-query attention: the kernels read a
+    key/value head once for the query heads that share it).  Anything else
+    is counted as ``shapes``.  Kernel-eligible masks are k-position padding
+    masks (shape (b,1,1,s)); arbitrary (b,h,sq,sk) masks fall back.
     Dropout runs in-kernel with a replayable position-keyed RNG.  A causal,
     mask-free call that misses the enabled kernel is counted in
     ``pallas.fallbacks`` with its reason, never silent: at long sequences
@@ -135,7 +152,8 @@ def flash_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
         pass        # no kernel on this backend, or switched off: nothing missed
     elif bias is None:
         reason = "mask"
-    elif not (q.shape == k.shape and v.shape[:-1] == k.shape[:-1]):
+    elif not (k.shape == (b, k.shape[1], s, d) and h % k.shape[1] == 0
+              and v.shape[:-1] == k.shape[:-1]):
         reason = "shapes"
     elif not fa.supported(s, d, v.shape[-1]):
         reason = "unsupported"

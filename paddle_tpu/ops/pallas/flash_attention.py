@@ -13,8 +13,23 @@ the two backward kernels:
   its q-block from the logsumexp, makes `delta = rowsum(dO ∘ O)` of the
   block from the dO and O it holds anyway, and writes it, lane-major like
   the logsumexp, as a second output.
-* `flash_dkdv`, grid (batch*heads, k_blocks), after `flash_dq`: reads those
-  row sums.
+* `flash_dkdv`, grid (batch*kv_heads, k_blocks, group), after `flash_dq`:
+  reads those row sums.
+
+**Grouped keys.**  k and v may have fewer heads than q: `(batch*kv_heads,
+seq, head)` with `heads = kv_heads × group`, query head j reading key/value
+head j // group (grouped-query attention; `group` 1 is the plain case, the
+same code).  Nothing is repeated in HBM: `flash_fwd` and `flash_dq` pick a
+query head's K/V rows by index map, and consecutive grid cells of one group
+name the same block, so the pipeline fetches it once a group.  `flash_dkdv`
+runs over the key/value heads with the group's query heads as its innermost
+grid axis: the k-block's dK, dV go from member to member through float32
+VMEM scratch and are stored at the last, so no dK of a repeated head is
+ever summed outside the kernel; a group of one never touches the scratch
+(chip, (2, 32, 4096, 192/128): 5.806 ms a call as before grouped keys;
+zeroing and reading it every cell cost 5.868).  Timed against a loop over
+the group's rows inside one grid cell at (1, 32/8, 8192, 64): 6.895 ms
+against 6.821, with Q/dO blocks a quarter the size (PERF.md §6, PR 32).
 
 **Every score tile is held transposed, `(block_k, block_q)`: keys along
 sublanes, queries along lanes.**  Sᵀ = K·Qᵀ and dPᵀ = V·dOᵀ contract the
@@ -301,36 +316,58 @@ def _smem():
     return pltpu.SMEM
 
 
-def _specs(q, bias):
-    """Block specs by grid `(bh, block index)` over `(bh, seq, d)` operands
-    and `(bh, 1, seq)` row statistics — whole rows of a head, or the grid's
-    block of them — and the bias as the calls take it: its `(b, 1, seq)`
-    operand and the same two specs, each a list that is empty without one."""
+def _vmem():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.VMEM
+
+
+def _specs(q, k, bias):
+    """Block specs over `(bh, seq, d)` operands and `(bh, 1, seq)` row
+    statistics — whole rows of a head, or the grid's block of them — and the
+    bias as the calls take it: its `(b, 1, seq)` operand and its specs, each
+    a list that is empty without one.
+
+    `q_*` specs are by `flash_fwd`'s and `flash_dq`'s grid `(query head,
+    q-block)`: `kv_rows` gives query head j the rows of key/value head
+    j // group.  `kv_*` specs are by `flash_dkdv`'s grid `(key/value head,
+    k-block, member of the group)`: `member_rows` gives the rows of query
+    head `kv head × group + member`."""
     bh, seq_len, _ = q.shape
+    group = bh // k.shape[0]
     if bias is None:
-        bias_operand, bias_rows, bias_block = [], [], lambda n: []
+        bias_operand, q_bias_rows, kv_bias_block = [], [], lambda n: []
     else:
         h = bh // bias.shape[0]
         bias_operand = [bias.reshape(-1, 1, seq_len)]
-        bias_rows = [pl.BlockSpec((1, 1, seq_len),
-                                  lambda b, i: (b // h, 0, 0))]
-        bias_block = lambda n: [pl.BlockSpec((1, 1, n),
-                                             lambda b, i: (b // h, 0, i))]
+        q_bias_rows = [pl.BlockSpec((1, 1, seq_len),
+                                    lambda b, i: (b // h, 0, 0))]
+        kv_bias_block = lambda n: [pl.BlockSpec(
+            (1, 1, n), lambda b, i, g: (b * group // h, 0, i))]
+    member = lambda b, i, g: (b * group + g, 0, 0)
     return dict(
-        rows=lambda d: pl.BlockSpec((1, seq_len, d), lambda b, i: (b, 0, 0)),
-        block=lambda n, d: pl.BlockSpec((1, n, d), lambda b, i: (b, i, 0)),
-        stat_rows=pl.BlockSpec((1, 1, seq_len), lambda b, i: (b, 0, 0)),
-        stat_block=lambda n: pl.BlockSpec((1, 1, n), lambda b, i: (b, 0, i)),
-        bias=bias_operand, bias_rows=bias_rows, bias_block=bias_block)
+        group=group,
+        q_block=lambda n, d: pl.BlockSpec((1, n, d), lambda b, i: (b, i, 0)),
+        q_stat_block=lambda n: pl.BlockSpec((1, 1, n),
+                                            lambda b, i: (b, 0, i)),
+        kv_rows=lambda d: pl.BlockSpec((1, seq_len, d),
+                                       lambda b, i: (b // group, 0, 0)),
+        kv_block=lambda n, d: pl.BlockSpec((1, n, d),
+                                           lambda b, i, g: (b, i, 0)),
+        member_rows=lambda d: pl.BlockSpec((1, seq_len, d), member),
+        member_stat_rows=pl.BlockSpec((1, 1, seq_len), member),
+        bias=bias_operand, q_bias_rows=q_bias_rows,
+        kv_bias_block=kv_bias_block)
 
 
 def _flash_forward(q, k, v, bias, seed, sm_scale, causal, dropout_rate,
                    block_q, block_k):
-    """q,k: (bh, seq, d); v: (bh, seq, d_v); bias: (b, seq) or None; seed:
-    int32 scalar array.  Returns o and the logsumexp (bh, 1, seq)."""
+    """q: (bh, seq, d); k: (b·kv_heads, seq, d); v: (b·kv_heads, seq, d_v);
+    bias: (b, seq) or None; seed: int32 scalar array.  Returns o and the
+    logsumexp (bh, 1, seq)."""
     bh, seq_len, d = q.shape
     d_v = v.shape[-1]
-    sp = _specs(q, bias)
+    sp = _specs(q, k, bias)
     return pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
@@ -338,9 +375,10 @@ def _flash_forward(q, k, v, bias, seed, sm_scale, causal, dropout_rate,
             seq_len=seq_len, has_bias=bias is not None),
         grid=(bh, seq_len // block_q),
         in_specs=[pl.BlockSpec(memory_space=_smem()),
-                  sp["block"](block_q, d), sp["rows"](d), sp["rows"](d_v)]
-        + sp["bias_rows"],
-        out_specs=[sp["block"](block_q, d_v), sp["stat_block"](block_q)],
+                  sp["q_block"](block_q, d), sp["kv_rows"](d),
+                  sp["kv_rows"](d_v)]
+        + sp["q_bias_rows"],
+        out_specs=[sp["q_block"](block_q, d_v), sp["q_stat_block"](block_q)],
         out_shape=[jax.ShapeDtypeStruct((bh, seq_len, d_v), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32)],
         interpret=_cfg.interpret(),
@@ -410,11 +448,13 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
 
 def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
                            causal, dropout_rate, block_q, block_k, seq_len,
-                           has_bias):
+                           has_bias, group):
     bias_ref = rest[0] if has_bias else None
-    do_ref, lse_ref, delta_ref, dk_ref, dv_ref = rest[-5:]
-    bh_idx = pl.program_id(0)
+    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_sum, dv_sum = rest[-7:]
     kv_idx = pl.program_id(1)
+    member = pl.program_id(2)           # which query head of this k/v head's
+    bh_idx = pl.program_id(0) * group + member    # the query head: q, do,
+    # lse and delta are its rows, and the dropout stream is keyed by it
     k = _scaled(k_ref[0], sm_scale)     # (block_k, d), the scores' scale on it
     v = v_ref[0]
     if has_bias:
@@ -446,12 +486,25 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
         dk_acc = dk_acc + _dot(dst.astype(q.dtype), q)
         return dk_acc, dv_acc
 
-    # q-blocks from the first that reaches this k-block's diagonal upwards
+    # q-blocks from the first that reaches this k-block's diagonal upwards;
+    # the sums go on from what the group's earlier members left, and only a
+    # member that has one before or after it touches the scratch (a group of
+    # one never does)
     lo = (kv_idx * block_k) // block_q if causal else 0
-    dk, dv = _for_tiles(tile, lo, seq_len // block_q, (
-        jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
-    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dk, dv = _for_tiles(tile, lo, seq_len // block_q, jax.lax.cond(
+        member > 0, lambda: (dk_sum[...], dv_sum[...]),
+        lambda: (jnp.zeros(k.shape, jnp.float32),
+                 jnp.zeros(v.shape, jnp.float32))))
+
+    @pl.when(member < group - 1)
+    def _():
+        dk_sum[...] = dk
+        dv_sum[...] = dv
+
+    @pl.when(member == group - 1)
+    def _():
+        dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _flash_dq(q, k, v, bias, seed, o, lse, do, sm_scale, causal, dropout_rate,
@@ -459,7 +512,7 @@ def _flash_dq(q, k, v, bias, seed, o, lse, do, sm_scale, causal, dropout_rate,
     """dq and delta = rowsum(do * o), float32 `(bh, 1, seq)` like lse."""
     bh, seq_len, d = q.shape
     d_v = v.shape[-1]
-    sp = _specs(q, bias)
+    sp = _specs(q, k, bias)
     return pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -467,11 +520,12 @@ def _flash_dq(q, k, v, bias, seed, o, lse, do, sm_scale, causal, dropout_rate,
             seq_len=seq_len, has_bias=bias is not None),
         grid=(bh, seq_len // block_q),
         in_specs=[pl.BlockSpec(memory_space=_smem()),
-                  sp["block"](block_q, d), sp["rows"](d), sp["rows"](d_v)]
-        + sp["bias_rows"]
-        + [sp["block"](block_q, d_v), sp["block"](block_q, d_v),  # do, o
-           sp["stat_block"](block_q)],                            # lse
-        out_specs=[sp["block"](block_q, d), sp["stat_block"](block_q)],
+                  sp["q_block"](block_q, d), sp["kv_rows"](d),
+                  sp["kv_rows"](d_v)]
+        + sp["q_bias_rows"]
+        + [sp["q_block"](block_q, d_v), sp["q_block"](block_q, d_v),  # do, o
+           sp["q_stat_block"](block_q)],                              # lse
+        out_specs=[sp["q_block"](block_q, d), sp["q_stat_block"](block_q)],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
         interpret=_cfg.interpret(),
@@ -483,21 +537,24 @@ def _flash_dkdv(q, k, v, bias, seed, lse, delta, do, sm_scale, causal,
                 dropout_rate, block_q, block_k):
     bh, seq_len, d = q.shape
     d_v = v.shape[-1]
-    sp = _specs(q, bias)
+    sp = _specs(q, k, bias)
     return pl.pallas_call(
         functools.partial(
             _flash_bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
             dropout_rate=dropout_rate, block_q=block_q, block_k=block_k,
-            seq_len=seq_len, has_bias=bias is not None),
-        grid=(bh, seq_len // block_k),
+            seq_len=seq_len, has_bias=bias is not None, group=sp["group"]),
+        grid=(k.shape[0], seq_len // block_k, sp["group"]),
         in_specs=[pl.BlockSpec(memory_space=_smem()),
-                  sp["rows"](d), sp["block"](block_k, d),
-                  sp["block"](block_k, d_v)]
-        + sp["bias_block"](block_k)
-        + [sp["rows"](d_v), sp["stat_rows"], sp["stat_rows"]],  # do lse delta
-        out_specs=[sp["block"](block_k, d), sp["block"](block_k, d_v)],
+                  sp["member_rows"](d), sp["kv_block"](block_k, d),
+                  sp["kv_block"](block_k, d_v)]
+        + sp["kv_bias_block"](block_k)
+        + [sp["member_rows"](d_v), sp["member_stat_rows"],      # do, lse
+           sp["member_stat_rows"]],                             # delta
+        out_specs=[sp["kv_block"](block_k, d), sp["kv_block"](block_k, d_v)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[_vmem()((block_k, d), jnp.float32),
+                        _vmem()((block_k, d_v), jnp.float32)],
         interpret=_cfg.interpret(),
         name="flash_dkdv",
     )(seed, q, k, v, *sp["bias"], do, lse, delta)
@@ -572,7 +629,9 @@ def flash_attention(q, k, v, bias=None, sm_scale=None, causal=False,
                     dropout_rate=0.0, seed=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
     """Flash attention over (batch, heads, seq, head_dim) inputs; ``v`` may
-    have a head size of its own (the output's).
+    have a head size of its own (the output's), and ``k`` and ``v`` fewer
+    heads than ``q``, a divisor of its count: query head j attends
+    key/value head j // (heads / kv_heads).
 
     ``bias`` is an optional additive k-position bias of shape (batch, seq_k)
     — the padding-mask case.  ``bias`` is treated as NON-DIFFERENTIABLE:
@@ -590,7 +649,10 @@ def flash_attention(q, k, v, bias=None, sm_scale=None, causal=False,
     # bias is non-differentiable (padding masks carry no trainable state;
     # the docstring carries the learned-bias warning)
     bias, seed = _normalize_bias_seed(bias, seed, b, s)
-    merged = lambda x: x.reshape(b * h, s, x.shape[-1])
+    if h % k.shape[1] or v.shape[1] != k.shape[1]:
+        raise ValueError(f"{h} query heads over {k.shape[1]} key and "
+                         f"{v.shape[1]} value heads")
+    merged = lambda x: x.reshape(-1, s, x.shape[-1])
     _cfg.record_call("flash_attention")
     with jax.named_scope("pallas.flash_attention"):
         out = _flash_attention_bhsd(merged(q), merged(k), merged(v),

@@ -27,6 +27,10 @@ import jax
 from paddle_tpu.ops.pallas import config as _cfg
 
 TILE_ROWS = 256
+# elements of a (k tile) x (n tile) block: the matrices' gradient keeps one
+# in float32 beside its double-buffered output, 2048 x 768 the most that
+# has run (16 MiB of scoped VMEM: 2048 x 1024 asks 19)
+TILE_ELEMENTS = 2048 * 768
 
 
 def _tile(n: int, cap: int) -> int:
@@ -42,8 +46,10 @@ def _tiling(m: int, k: int, n: int):
     sides.  The whole contraction in one tile where it is at most 2048 keeps
     a group's matrix in VMEM from row tile to row tile (chip, PR 28, a layer
     forward and backward at 14,336 held pairs: 4.94 ms against 5.94 with
-    256 x 1024 x 768/1024 for all three)."""
-    return TILE_ROWS, _tile(k, 2048), _tile(n, 1024)
+    256 x 1024 x 768/1024 for all three); the n tile as wide as
+    `TILE_ELEMENTS` leaves beside it, at most 1024."""
+    tile_k = _tile(k, 2048)
+    return TILE_ROWS, tile_k, _tile(n, min(1024, TILE_ELEMENTS // tile_k))
 
 
 def supported(m: int, k: int, n: int) -> bool:

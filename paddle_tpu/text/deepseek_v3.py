@@ -23,13 +23,13 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..autograd import functional_call
 from ..nn import functional as F
 from ..nn.layer.base import Layer
 from ..ops import attention as attn_ops
-from ..parallel.pipeline import blockwise_stage_fn
-from ..utils import monitor
 from ..utils import xprof as _xprof
+# the routing counters walk any model's groups; kept under this name too
+# for the callers that found them here (the benchmark's decoder entry)
+from .pretrainer import routing_stats  # noqa: F401
 
 
 class DeepseekV3Config:
@@ -164,18 +164,28 @@ class DeepseekV3Block(Layer):
     def forward(self, x, routing_stats: bool = False):
         """x -> y; with `routing_stats` (expert blocks), (y, the expert
         layer's `routing_stats` of this call)."""
-        with jax.named_scope(_xprof.REGION_LN):
-            normed = self.input_norm(x)
-        with jax.named_scope(_xprof.REGION_ATTN):
-            out = self.self_attn(normed)
-        with jax.named_scope(_xprof.REGION_LN):
-            x = x + out
-            normed = self.post_norm(x)
-        with jax.named_scope(_xprof.REGION_FFN):
-            out = self.mlp(normed)
-        with jax.named_scope(_xprof.REGION_LN):
-            y = x + out
-        return (y, self.mlp.routing_stats(normed)) if routing_stats else y
+        return residual_block(x, self.input_norm, self.self_attn,
+                              self.post_norm, self.mlp, routing_stats)
+
+
+def residual_block(x, input_norm, mixer, post_norm, ffn,
+                   routing_stats: bool = False):
+    """h = x + mixer(input_norm(x)); y = h + ffn(post_norm(h)), each part
+    under its region's scope (the token mixer's is `attn`, attention or
+    not).  With `routing_stats` (`ffn` an expert layer), (y, the layer's
+    `routing_stats` of this call)."""
+    with jax.named_scope(_xprof.REGION_LN):
+        normed = input_norm(x)
+    with jax.named_scope(_xprof.REGION_ATTN):
+        out = mixer(normed)
+    with jax.named_scope(_xprof.REGION_LN):
+        x = x + out
+        normed = post_norm(x)
+    with jax.named_scope(_xprof.REGION_FFN):
+        out = ffn(normed)
+    with jax.named_scope(_xprof.REGION_LN):
+        y = x + out
+    return (y, ffn.routing_stats(normed)) if routing_stats else y
 
 
 class DeepseekV3Embeddings(Layer):
@@ -246,46 +256,3 @@ def _host_device():
         return jax.default_device(jax.devices("cpu")[0])
     except RuntimeError:        # this process was told to see no CPU backend
         return contextlib.nullcontext()
-
-
-# ---------------------------------------------------------------------------
-# routing counters
-# ---------------------------------------------------------------------------
-_STATS = ("pairs_routed", "pairs_held", "held_load_max_over_mean",
-          "pairs_dropped")
-_gauges = {name: monitor.gauge(
-    f"moe.{name}", "DroplessMoE routing of the last `routing_stats` call, "
-    "per expert layer", labelnames=("layer",)) for name in _STATS}
-
-
-def routing_stats(trainer, params, batch, compute_dtype=jnp.bfloat16):
-    """One forward pass of `trainer`'s model over `batch` that reads every
-    expert layer's routing ({name: [layers] array}) and sets the gauges
-    `moe.pairs_routed`, `moe.pairs_held`, `moe.held_load_max_over_mean`,
-    `moe.pairs_dropped` (label `layer`).  Not part of a train step: run it
-    on a trained state when the counts are wanted."""
-    from .pretrainer import _cast_floating
-
-    model = trainer.model
-    template = trainer.block_templates["expert_blocks"]
-
-    @jax.jit
-    def stats(params, batch):
-        p = _cast_floating(params, compute_dtype)
-        h = functional_call(model.embeddings, p["embed"],
-                            tuple(batch[k] for k in model.embed_inputs))
-        if "dense_blocks" in model.groups:
-            h = blockwise_stage_fn(trainer._block_fn("dense_blocks"))(
-                p["dense_blocks"], h)
-
-        def body(x, blk):
-            return functional_call(template, blk, (x,),
-                                   {"routing_stats": True})
-
-        return jax.lax.scan(body, h, p["expert_blocks"])[1]
-
-    out = jax.device_get(stats(params, batch))
-    for name in _STATS:
-        for layer, value in enumerate(out[name]):
-            _gauges[name].set(float(value), layer=str(layer))
-    return out

@@ -20,7 +20,9 @@ The trainer builds no model of its own: it takes a `PretrainModel` — the
 model's pieces and which batch keys each reads — and stacks, shards, scans
 and differentiates them.  `ernie_pretrain_model` is the first such
 description (an `ErnieConfig` handed to the trainer is turned into it);
-`text/deepseek_v3.py` gives a decoder-only one with two groups of blocks.
+`text/deepseek_v3.py` gives a decoder-only one with two groups of blocks,
+`text/lfm2_moe.py` one whose groups follow a layer pattern (a group a run
+of one kind of block).
 """
 from __future__ import annotations
 
@@ -45,6 +47,7 @@ from ..parallel.pipeline import (
     unmicrobatch,
 )
 from ..parallel.sharding import TRANSFORMER_RULES, infer_sharding
+from ..utils import monitor
 from ..utils import xprof as _xprof
 from .ernie import ErnieConfig, ErnieEmbeddings, ErniePretrainingCriterion
 
@@ -539,6 +542,63 @@ def _cast_floating(params, compute_dtype):
     return jax.tree_util.tree_map(
         lambda x: x.astype(compute_dtype)
         if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
+
+
+# ---------------------------------------------------------------------------
+# routing counters
+# ---------------------------------------------------------------------------
+_ROUTING_STATS = ("pairs_routed", "pairs_held", "held_load_max_over_mean",
+                  "pairs_dropped")
+_routing_gauges = {name: monitor.gauge(
+    f"moe.{name}", "DroplessMoE routing of the last `routing_stats` call, "
+    "per expert layer", labelnames=("layer",)) for name in _ROUTING_STATS}
+
+
+def routing_stats(trainer: HybridPretrainer, params, batch,
+                  compute_dtype=jnp.bfloat16):
+    """One forward pass of `trainer`'s model over `batch` that walks its
+    groups in order and reads the routing of every block that has an expert
+    layer (`nn.DroplessMoE`; such a block's forward takes
+    `routing_stats=True` and returns (y, the layer's `routing_stats`)):
+    {name: [expert layers] array}, and the gauges `moe.pairs_routed`,
+    `moe.pairs_held`, `moe.held_load_max_over_mean`, `moe.pairs_dropped`
+    (label `layer`: the expert layer's place among the expert layers).  Not
+    part of a train step: run it on a trained state when the counts are
+    wanted."""
+    model = trainer.model
+    routed = {group for group, template in trainer.block_templates.items()
+              if any(isinstance(l, nn.DroplessMoE)
+                     for l in template.sublayers())}
+    if not routed:
+        return {}
+
+    @jax.jit
+    def stats(params, batch):
+        p = _cast_floating(params, compute_dtype)
+        h = functional_call(model.embeddings, p["embed"],
+                            tuple(batch[k] for k in model.embed_inputs))
+        out = []
+        for group in model.groups:
+            if group not in routed:
+                h = blockwise_stage_fn(trainer._block_fn(group))(p[group], h)
+                continue
+            template = trainer.block_templates[group]
+            h, read = lax.scan(
+                lambda x, blk: functional_call(
+                    template, blk, (x,), {"routing_stats": True}),
+                h, p[group])
+            out.append(read)
+        return {name: jnp.concatenate([read[name] for read in out])
+                for name in _ROUTING_STATS}
+
+    out = jax.device_get(stats(params, batch))
+    for name in _ROUTING_STATS:
+        gauge = _routing_gauges[name]
+        for labels, _ in gauge.samples():   # a deeper model's last call
+            gauge.remove(**labels)
+        for layer, value in enumerate(out[name]):
+            gauge.set(float(value), layer=str(layer))
+    return out
 
 
 def _find_param(layer, name: str):

@@ -224,7 +224,8 @@ _ZERO_BYTES = frozenset((
 # attribute of the same name (``ErnieModel.encoder``) reads as that region.
 REGION_EMBED = "embed"          # lookups, position/type add, embedding LN
 REGION_ENCODER = "encoder"      # the whole block stack
-REGION_ATTN = "attn"            # q/k/v/out projections + the core
+REGION_ATTN = "attn"            # the token mixer: q/k/v/out projections +
+                                # the core, or a convolution mixer (`conv`)
 REGION_ATTN_CORE = "core"       # scores -> softmax -> values, under attn
 REGION_FFN = "ffn"              # both products and the activation
 REGION_LN = "ln"                # residual add, dropout, LayerNorm
@@ -239,6 +240,7 @@ SCOPE_ROUTER = "router"         # ffn: scores, selection, weights
 SCOPE_EXPERTS = "experts"       # ffn: sort, dispatch, grouped products, combine
 SCOPE_SHARED = "shared"         # ffn: the shared experts
 SCOPE_LATENT = "latent"         # attn: kv down-projection, its norm, up-projection
+SCOPE_CONV = "conv"             # attn: a gated short-convolution mixer, whole
 REGIONS = (REGION_EMBED, REGION_ENCODER, REGION_ATTN, ATTN_CORE, REGION_FFN,
            REGION_LN, REGION_HEAD, REGION_LOSS, REGION_OPTIMIZER)
 

@@ -603,3 +603,94 @@ def test_flash_tiles_counter_at_cell_4s_shape(block_q, block_k, causal, want):
         4096 // block_q - (j * block_k // block_q if causal else 0)
         for j in range(4096 // block_k))
     assert computed == want[0] + want[1]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("kv_heads", [4, 1])
+@pytest.mark.parametrize("with_bias,rate", [(False, 0.0), (True, 0.25)])
+def test_grouped_keys_forward_and_three_gradients(kv_heads, causal,
+                                                  with_bias, rate):
+    """k and v with `kv_heads` heads under 4 query heads (`h_kv` in {h,
+    h/4}): query head j reads key/value head j // group, and dK, dV are
+    summed over the group inside `flash_dkdv`.  Forward and dq, dk, dv
+    against the plain path, which repeats the heads; with a padding bias
+    and dropout too (the stream is keyed by the query head)."""
+    b, h, s, d, d_v = 2, 4, 128, 64, 32
+    rng = np.random.default_rng(7)
+    mk = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, do = mk(b, h, s, d), mk(b, h, s, d_v)
+    k, v = mk(b, kv_heads, s, d), mk(b, kv_heads, s, d_v)
+    bias = None
+    if with_bias:
+        bias = jnp.zeros((b, s), jnp.float32).at[1, 96:].set(-1e4)
+    seed = jnp.asarray([11], jnp.int32)
+    keep = None
+    if rate:
+        qpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
+        keep = jnp.stack([
+            fa._dropout_keep(seed[0], jnp.int32(i), qpos, kpos, rate)
+            for i in range(b * h)]).reshape(b, h, s, s)
+
+    def kernel(q, k, v):
+        return fa.flash_attention(q, k, v, bias=bias, causal=causal,
+                                  dropout_rate=rate, seed=seed,
+                                  block_q=64, block_k=32)
+
+    def plain(q, k, v):
+        k, v = (jnp.repeat(t, h // kv_heads, axis=1) for t in (k, v))
+        return _plain_attention(q, k, v, bias, causal, keep, rate)
+
+    out, vjp = jax.vjp(kernel, q, k, v)
+    ref, vjp_ref = jax.vjp(plain, q, k, v)
+    for name, a, r in zip(("o", "dq", "dk", "dv"), (out,) + vjp(do),
+                          (ref,) + vjp_ref(do)):
+        assert a.shape == r.shape, name
+        scale = max(float(jnp.abs(r).max()), 1.0)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-4,
+                                   atol=1e-5 * scale, err_msg=name)
+
+
+def test_grouped_keys_through_the_dispatch(monkeypatch):
+    """`ops.attention.flash_attention` hands a 4/2 call to the three
+    kernels (no `shapes` fallback), k and v enter them with their own two
+    heads, and the plain path computes the same thing; a head count that
+    does not divide is still `shapes`."""
+    monkeypatch.setattr(pcfg, "kernel_enabled",
+                        lambda name: bool(flags.get_flag(name)))
+    b, h, h_kv, s, d = 1, 4, 2, 128, 64
+    rng = np.random.default_rng(3)
+    mk = lambda heads: jnp.asarray(rng.normal(size=(b, heads, s, d)),
+                                   jnp.float32)
+    q, k, v = mk(h), mk(h_kv), mk(h_kv)
+
+    def both(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: attn_ops.flash_attention(
+            q, k, v, is_causal=True), q, k, v)
+        return out, vjp(g)
+
+    kernels, outside = _pallas_calls_and_outside(
+        jax.make_jaxpr(both)(q, k, v, q), b * h * s * d)
+    assert not outside, outside
+    assert list(kernels) == ["flash_fwd", "flash_dq", "flash_dkdv"]
+    for name, shapes in kernels.items():
+        # q-sized and k-sized operands both: nothing was repeated
+        assert (b * h, s, d) in shapes and (b * h_kv, s, d) in shapes, name
+    out = attn_ops.flash_attention(q, k, v, is_causal=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(sdpa(q, k, v, is_causal=True)),
+        rtol=1e-5, atol=1e-5)
+    from paddle_tpu.utils import monitor
+
+    def shapes_fallbacks():
+        c = monitor.default_registry().get("pallas.fallbacks")
+        return sum(n for labels, n in c.samples()
+                   if labels == {"kernel": "flash_attention",
+                                 "reason": "shapes"})
+
+    before = shapes_fallbacks()
+    three = mk(3)
+    with pytest.raises((TypeError, ValueError)):
+        # 4 query heads over 3: no kernel, and the plain path cannot either
+        attn_ops.flash_attention(q, three, three, is_causal=True)
+    assert shapes_fallbacks() == before + 1

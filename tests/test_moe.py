@@ -101,3 +101,57 @@ def test_moe_ep_sharded_matches_single_device():
     shard_layer(layer, m)
     out = jax.jit(lambda inp: layer(inp))(x)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer's router: the normalisation's epsilon, no shared expert
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("norm_eps", [1e-20, 1e-6, 0.5])
+def test_sigmoid_router_adds_its_epsilon_to_the_selected_sum(norm_eps):
+    from paddle_tpu.nn.layer.moe import sigmoid_topk_routing
+    x = jax.random.normal(jax.random.PRNGKey(0), (9, 16))
+    w = jax.random.normal(jax.random.PRNGKey(1), (16, 8))
+    bias = jnp.zeros((8,)).at[5].set(10.0)
+    ids, weights = sigmoid_topk_routing(x, w, bias, 3, norm_eps=norm_eps)
+    scores = np.asarray(jax.nn.sigmoid(x @ w))
+    picked = np.take_along_axis(scores, np.asarray(ids), axis=1)
+    assert (np.asarray(ids) == 5).any(axis=1).all()   # the bias selects
+    np.testing.assert_allclose(                       # and does not weigh
+        weights, picked / (picked.sum(1, keepdims=True) + norm_eps),
+        rtol=1e-5)
+
+
+def test_sigmoid_router_default_epsilon_is_deepseeks():
+    import inspect
+    from paddle_tpu.nn.layer.moe import sigmoid_topk_routing
+    sig = inspect.signature(sigmoid_topk_routing)
+    assert sig.parameters["norm_eps"].default == 1e-20
+    assert inspect.signature(nn.DroplessMoE).parameters[
+        "norm_eps"].default == 1e-20
+
+
+def test_dropless_layer_hands_its_epsilon_to_the_router():
+    layer = nn.DroplessMoE(16, 8, 8, 3, norm_eps=0.5)
+    x = jax.random.normal(jax.random.PRNGKey(2), (12, 16))
+    _, weights = layer.route(x)
+    _, plain = nn.DroplessMoE(16, 8, 8, 3).route(x)
+    assert float(jnp.max(weights.sum(1))) < 0.9      # sum / (sum + 0.5)
+    np.testing.assert_allclose(plain.sum(1), 1.0, rtol=1e-5)
+
+
+def test_dropless_layer_without_shared_experts_plants_no_shared_scope():
+    """`n_shared_experts = 0`: no parameter, no `shared` scope in the
+    traced step, and the result is the held experts' sum alone."""
+    import re
+    layer = nn.DroplessMoE(16, 8, 8, 3, held=(0, 4), n_shared_experts=0)
+    assert layer.shared_mlp is None
+    assert sorted(n for n, _ in layer.named_parameters()) == [
+        "router_bias", "router_weight", "w_in", "w_out"]
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 6, 16))
+    text = jax.jit(layer).lower(x).as_text(debug_info=True)
+    scopes = set(re.findall(r"\b(router|experts|shared)\b", text))
+    assert scopes == {"router", "experts"}, scopes
+    with_shared = nn.DroplessMoE(16, 8, 8, 3, held=(0, 4),
+                                 n_shared_experts=1)
+    text = jax.jit(with_shared).lower(x).as_text(debug_info=True)
+    assert "shared" in text

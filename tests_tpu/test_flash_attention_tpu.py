@@ -170,3 +170,45 @@ def test_cell4_shape_dropout_replays_one_mask_across_the_three_kernels():
     got = jax.jit(lambda *t: _kernel_o_and_grads(
         *t, dropout_rate=RATE, seed=seed))(*inputs)
     _assert_matches_reference(got, inputs, keep)
+
+
+def test_grouped_keys_at_the_8k_cells_shape_match_float32():
+    """(1, 32/8, 8192, 64) causal, bf16 (`lfm2-24b-a2b.s8192`): four query
+    heads read one key/value head, dK and dV summed over them inside
+    `flash_dkdv`.  Against float32 attention, one key/value head's group at
+    a time (four 8192² score squares), its dk and dv summed over the
+    group's query heads by the reference's own vjp."""
+    h, h_kv, s, d = 32, 8, 8192, 64
+    group = h // h_kv
+    rng = np.random.default_rng(8)
+    mk = lambda heads: jnp.asarray(rng.normal(0, 1, (1, heads, s, d)),
+                                   jnp.bfloat16)
+    q, k, v, do = mk(h), mk(h_kv), mk(h_kv), mk(h)
+    run = jax.jit(_kernel_o_and_grads)
+    import re
+    names = set(re.findall(r"/(flash_\w+)/pallas_call",
+                           run.lower(q, k, v, do).compile().as_text()))
+    assert names == {"flash_fwd", "flash_dkdv", "flash_dq"}, names
+    o, dq, dk, dv = run(q, k, v, do)
+    assert dk.shape == k.shape and dv.shape == v.shape
+
+    @jax.jit
+    def reference(q, k, v, do):           # [group, s, d], [s, d] twice
+        def attend(q, k, v):
+            with jax.default_matmul_precision("highest"):
+                sc = jnp.einsum("gqd,kd->gqk", q, k) / (d ** 0.5)
+                sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -1e30)
+                return jnp.einsum("gqk,kd->gqd", jax.nn.softmax(sc, -1), v)
+
+        out, vjp = jax.vjp(attend, *(t.astype(jnp.float32)
+                                     for t in (q, k, v)))
+        return (out,) + vjp(do.astype(jnp.float32))
+
+    for j in range(h_kv):
+        mine = slice(j * group, (j + 1) * group)
+        want = reference(q[0, mine], k[0, j], v[0, j], do[0, mine])
+        got = (o[0, mine], dq[0, mine], dk[0, j], dv[0, j])
+        for name, a, r in zip(("o", "dq", "dk", "dv"), got, want):
+            diff = float(jnp.abs(a.astype(jnp.float32) - r).max())
+            mag = float(jnp.abs(r).max())
+            assert diff < 2e-2 * max(mag, 1.0), (name, j, diff, mag)

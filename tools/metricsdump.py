@@ -103,7 +103,7 @@ _KNOWN_NAMES = frozenset({
     "pallas.fallbacks",
     "pallas.flash.tiles",
     "pallas.kernel_calls",
-    # text/deepseek_v3.py routing_stats (nn.DroplessMoE; label layer)
+    # text/pretrainer.py routing_stats (nn.DroplessMoE; label layer)
     "moe.held_load_max_over_mean",
     "moe.pairs_dropped",
     "moe.pairs_held",
