@@ -211,23 +211,85 @@ def sigmoid_topk_routing(x, router_weight, selection_bias, top_k: int,
 
 
 # The pairs (token, slot) sorted by expert are a permutation `order` of
-# t·top_k + j with inverse `inverse`.  A grouped product writes the rows of
-# its groups and leaves the rest of its result as it found it, forward and
-# transposed alike, so rows that belong to no held pair are no result: the
-# two functions below never let one into a sum (a `select`, not a product
-# with 0), which costs nothing — the select fuses into the sum over the
-# slots — where zeroing the rows themselves is a pass over tokens × top_k
-# rows each time.
-def _slots(rows, inverse, top_k):
-    """The rows of every token's pair j, slot by slot: top_k arrays [T, D]
-    in float32.  (One gather reshaped to [T, top_k, D] would put top_k on
-    the tiled sublanes: a padded copy of tokens × top_k rows.)"""
-    inverse = inverse.reshape(-1, top_k)
-    return [rows[inverse[:, j]].astype(jnp.float32) for j in range(top_k)]
+# t·top_k + j with inverse `inverse`, the held pairs first.  A grouped
+# product writes the rows of its groups and leaves the rest of its result as
+# it found them, forward and transposed alike, so rows that belong to no
+# held pair are no result: nothing below lets one into a sum (a `select`,
+# not a product with 0), which costs nothing — the select fuses into the sum
+# over the slots — where zeroing the rows themselves is a pass over
+# tokens × top_k rows each time.
+#
+# The buffers keep tokens × top_k rows whatever the router does (no pair is
+# dropped, and the grouped products' row tiles stay where they are), but a
+# pass reads and writes the rows of held pairs only, as the grouped products
+# do: a pass over the sorted rows is a loop over chunks of `CHUNK_ROWS` rows
+# whose trip count is read from the call's own held pairs
+# (`_leading_rows`), and a gather per token reads the least of the buffer's
+# leading `NEAR_BYTES` that holds every held pair (`_over_slots`).
+CHUNK_ROWS = 2048
+# of a buffer's leading rows, what XLA:TPU holds in VMEM beside a pass's own
+# buffers, least first (a v5e's compiler places 112 MiB: a gather of 8,192
+# rows from 96 MiB there took 0.05 ms, from any buffer in HBM 0.29; beside
+# the layer's own [T, D] input it placed 64 MiB and not 96: chip, PR 33)
+NEAR_BYTES = (64 * 2 ** 20, 96 * 2 ** 20)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(x, order, inverse, pair_held, top_k):
+def _buffer_rows(held, rows: int):
+    """(chunks, rows a chunk) that cover the leading `held` of `rows`."""
+    chunk = min(CHUNK_ROWS, rows)
+    return (held + chunk - 1) // chunk, chunk
+
+
+def _leading_rows(fn, held, *operands):
+    """`fn` over the leading `held` rows of `operands` (arrays of one
+    length), a chunk at a time: chunks [c, ·] in, a chunk [c, ·] (or a
+    tuple of them) out, into buffers of the operands' length whose rows
+    from `held` (rounded up to a whole chunk) on are never written.  The
+    last chunk of a length that is no multiple of the chunk starts early
+    and writes some rows twice."""
+    rows = operands[0].shape[0]
+    chunks, chunk = _buffer_rows(held, rows)
+    out = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+        (chunk,) + a.shape[1:], a.dtype) for a in operands))
+
+    def body(i, buffers):
+        start = jnp.minimum(i * chunk, rows - chunk)
+        parts = fn(*(lax.dynamic_slice_in_dim(a, start, chunk)
+                     for a in operands))
+        return jax.tree_util.tree_map(
+            lambda b, p: lax.dynamic_update_slice_in_dim(b, p, start, 0),
+            buffers, parts)
+
+    return lax.fori_loop(0, chunks, body, jax.tree_util.tree_map(
+        lambda o: lax.empty((rows,) + o.shape[1:], o.dtype), out))
+
+
+def _over_slots(term, rows, inverse, held, top_k):
+    """Σ_j term(j, the rows of every token's pair j): [T, D] in float32,
+    the rows gathered slot by slot in float32.  (One gather reshaped to
+    [T, top_k, D] would put top_k on the tiled sublanes: a padded copy of
+    tokens × top_k rows.)  Only the `held` leading rows are any pair's
+    result: the gathers read the least of `NEAR_BYTES` of leading rows
+    that holds them all, a buffer that XLA:TPU keeps in VMEM, and the
+    whole buffer where none does."""
+    def over(rows, inverse):
+        inverse = inverse.reshape(-1, top_k)
+        return sum(term(j, rows[inverse[:, j]].astype(jnp.float32))
+                   for j in range(top_k))
+
+    row_bytes = rows.shape[1] * rows.dtype.itemsize
+    near = [n for n in (b // row_bytes for b in NEAR_BYTES)
+            if n < rows.shape[0]]
+    if not near:
+        return over(rows, inverse)
+    return lax.switch(
+        jnp.sum(held > jnp.asarray(near)),
+        [lambda n=n: over(rows[:n], jnp.minimum(inverse, n - 1))
+         for n in near] + [lambda: over(rows, inverse)])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _dispatch(x, order, inverse, pair_held, held, top_k):
     """Row r of the sorted pairs is token order[r] // top_k's activation.
     The transpose of a gather with repeats is a scatter-add; pairs being a
     permutation, it is a gather through the inverse and a sum over the
@@ -235,45 +297,76 @@ def _dispatch(x, order, inverse, pair_held, top_k):
     return x[order // top_k]
 
 
-def _dispatch_fwd(x, order, inverse, pair_held, top_k):
-    return x[order // top_k], (inverse, pair_held)
+def _dispatch_fwd(x, order, inverse, pair_held, held, top_k):
+    return x[order // top_k], (inverse, pair_held, held)
 
 
 def _dispatch_bwd(top_k, res, g):
-    inverse, pair_held = res
-    dx = sum(jnp.where(pair_held[:, j, None], slot, 0)
-             for j, slot in enumerate(_slots(g, inverse, top_k)))
-    return dx.astype(g.dtype), None, None, None
+    inverse, pair_held, held = res
+    dx = _over_slots(lambda j, slot: jnp.where(pair_held[:, j, None], slot, 0),
+                     g, inverse, held, top_k)
+    return dx.astype(g.dtype), None, None, None, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _combine(rows, pair_weight, order, inverse, top_k):
+def _swiglu(gate_up):
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return F.silu(gate) * up
+
+
+@jax.custom_vjp
+def _gated(gate_up, held):
+    """silu(gate) ⊙ up of the `held` leading rows of [rows, 2·d_expert]
+    (gate first)."""
+    return _leading_rows(_swiglu, held, gate_up)
+
+
+def _gated_fwd(gate_up, held):
+    return _gated(gate_up, held), (gate_up, held)
+
+
+def _gated_bwd(res, g):
+    gate_up, held = res
+    return _leading_rows(lambda gate_up, g: jax.vjp(_swiglu, gate_up)[1](g)[0],
+                         held, gate_up, g), None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine(rows, pair_weight, order, inverse, held, top_k):
     """Token t's result: Σ_j pair_weight[t, j] · (the row of its pair j),
     over the pairs whose weight is not 0 (the held ones), summed in
     float32."""
-    y = sum(jnp.where(pair_weight[:, j, None] != 0,
-                      slot * pair_weight[:, j, None], 0)
-            for j, slot in enumerate(_slots(rows, inverse, top_k)))
+    y = _over_slots(
+        lambda j, slot: jnp.where(pair_weight[:, j, None] != 0,
+                                  slot * pair_weight[:, j, None], 0),
+        rows, inverse, held, top_k)
     return y.astype(rows.dtype)
 
 
-def _combine_fwd(rows, pair_weight, order, inverse, top_k):
-    return (_combine(rows, pair_weight, order, inverse, top_k),
-            (rows, pair_weight, order, inverse))
+def _combine_fwd(rows, pair_weight, order, inverse, held, top_k):
+    return (_combine(rows, pair_weight, order, inverse, held, top_k),
+            (rows, pair_weight, order, inverse, held))
 
 
 def _combine_bwd(top_k, res, g):
-    rows, pair_weight, order, inverse = res
-    row_weight = pair_weight.reshape(-1)[order]
-    d_rows = (g[order // top_k].astype(jnp.float32)
-              * row_weight[:, None]).astype(rows.dtype)
-    gf = g.astype(jnp.float32)
-    d_weight = jnp.stack([jnp.sum(slot * gf, axis=-1)
-                          for slot in _slots(rows, inverse, top_k)], axis=-1)
-    return d_rows, jnp.where(pair_weight != 0, d_weight, 0), None, None
+    """Both gradients row by row of the sorted pairs: row r's is g's row of
+    its token times its pair's weight, its weight's the product of the two
+    rows summed in float32 (brought to [T, top_k] through the inverse)."""
+    rows, pair_weight, order, inverse, held = res
+
+    def back(order, rows):
+        g_rows = g[order // top_k].astype(jnp.float32)
+        weight = pair_weight.reshape(-1)[order][:, None]
+        return ((g_rows * weight).astype(rows.dtype),
+                jnp.sum(rows.astype(jnp.float32) * g_rows, axis=-1))
+
+    d_rows, d_weight = _leading_rows(back, held, order, rows)
+    return (d_rows, jnp.where(pair_weight != 0,
+                              d_weight[inverse].reshape(pair_weight.shape), 0),
+            None, None, None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_gated.defvjp(_gated_fwd, _gated_bwd)
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
@@ -394,29 +487,35 @@ class DroplessMoE(Layer):
         tokens × top_k of which the held are a share (an eighth on one chip
         of eight), and all of them would wait as residuals of every layer:
         the backward sorts, gathers and multiplies again instead (a third
-        more of the grouped products, the smallest of the layer)."""
+        more of the grouped products, the smallest of the layer).  Every
+        pass but the gather of the tokens' rows follows the held pairs
+        (that one reads [T, D] from VMEM at the buffer's write rate, and a
+        loop was no faster: chip, PR 33)."""
         shape = tokens.shape
         tokens = tokens.reshape(-1, shape[-1])
         ids, weights = (t.reshape(-1, self.top_k) for t in (ids, weights))
         pair_held = self._pair_held(ids)
         order, inverse, sizes = self._plan(ids)
+        held = jnp.sum(sizes)
         dot = _gm.grouped_matmul if kernel else lax.ragged_dot
-        rows = _dispatch(tokens, order, inverse, pair_held, self.top_k)
-        gate, up = jnp.split(dot(rows, w_in, sizes), 2, axis=-1)
-        out = dot(F.silu(gate) * up, w_out, sizes)
+        rows = _dispatch(tokens, order, inverse, pair_held, held, self.top_k)
+        out = dot(_gated(dot(rows, w_in, sizes), held), w_out, sizes)
         return _combine(out, jnp.where(pair_held, weights, 0.0), order,
-                        inverse, self.top_k).reshape(shape)
+                        inverse, held, self.top_k).reshape(shape)
 
     def routing_stats(self, x):
         """Counts of one call's routing, as int32/float32 scalars: pairs
         routed (tokens × top_k), pairs whose expert is held here, the held
-        experts' largest load over their mean load, and pairs dropped (held
+        experts' largest load over their mean load, pairs dropped (held
         pairs that no group of the grouped product covers: 0 by
-        construction)."""
+        construction), and the rows of the sorted buffers that a call which
+        sorts these tokens together passes over (`_buffer_rows`)."""
         ids, _ = self.route(x.reshape(-1, x.shape[-1]))
         sizes = self._plan(ids)[2]
         held = jnp.sum(self._pair_held(ids), dtype=jnp.int32)
         mean = jnp.maximum(jnp.mean(sizes.astype(jnp.float32)), 1e-9)
+        chunks, chunk = _buffer_rows(held, ids.size)
         return {"pairs_routed": jnp.int32(ids.size), "pairs_held": held,
                 "held_load_max_over_mean": jnp.max(sizes) / mean,
-                "pairs_dropped": held - jnp.sum(sizes)}
+                "pairs_dropped": held - jnp.sum(sizes),
+                "buffer_rows": jnp.minimum(chunks * chunk, ids.size)}

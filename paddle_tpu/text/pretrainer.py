@@ -548,7 +548,7 @@ def _cast_floating(params, compute_dtype):
 # routing counters
 # ---------------------------------------------------------------------------
 _ROUTING_STATS = ("pairs_routed", "pairs_held", "held_load_max_over_mean",
-                  "pairs_dropped")
+                  "pairs_dropped", "buffer_rows")
 _routing_gauges = {name: monitor.gauge(
     f"moe.{name}", "DroplessMoE routing of the last `routing_stats` call, "
     "per expert layer", labelnames=("layer",)) for name in _ROUTING_STATS}
@@ -561,10 +561,10 @@ def routing_stats(trainer: HybridPretrainer, params, batch,
     layer (`nn.DroplessMoE`; such a block's forward takes
     `routing_stats=True` and returns (y, the layer's `routing_stats`)):
     {name: [expert layers] array}, and the gauges `moe.pairs_routed`,
-    `moe.pairs_held`, `moe.held_load_max_over_mean`, `moe.pairs_dropped`
-    (label `layer`: the expert layer's place among the expert layers).  Not
-    part of a train step: run it on a trained state when the counts are
-    wanted."""
+    `moe.pairs_held`, `moe.held_load_max_over_mean`, `moe.pairs_dropped`,
+    `moe.buffer_rows` (label `layer`: the expert layer's place among the
+    expert layers).  Not part of a train step: run it on a trained state
+    when the counts are wanted."""
     model = trainer.model
     routed = {group for group, template in trainer.block_templates.items()
               if any(isinstance(l, nn.DroplessMoE)

@@ -431,8 +431,11 @@ def test_routing_stats_fill_the_moe_counters(tiny_lm):
     assert (0 < stats["pairs_held"]).all() and \
         (stats["pairs_held"] < 2 * 32 * 3).all()
     reg = monitor.default_registry()
+    # the rows the layer's passes run over: whole chunks that cover the held
+    assert (stats["buffer_rows"] >= stats["pairs_held"]).all() and \
+        (stats["buffer_rows"] <= stats["pairs_routed"]).all()
     for name in ("pairs_routed", "pairs_held", "held_load_max_over_mean",
-                 "pairs_dropped"):
+                 "pairs_dropped", "buffer_rows"):
         samples = dict((l["layer"], v)
                        for l, v in reg.get(f"moe.{name}").samples())
         assert samples.keys() == {"0", "1"}

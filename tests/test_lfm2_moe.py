@@ -306,8 +306,11 @@ def test_routing_stats_walk_every_group_with_an_expert_layer(tiny_lm):
         np.testing.assert_array_equal(stats[name],
                                       tiny_lm["via_deepseek"][name])
     reg = monitor.default_registry()
+    # the rows the layer's passes run over: whole chunks that cover the held
+    assert (stats["buffer_rows"] >= stats["pairs_held"]).all() and \
+        (stats["buffer_rows"] <= stats["pairs_routed"]).all()
     for name in ("pairs_routed", "pairs_held", "held_load_max_over_mean",
-                 "pairs_dropped"):
+                 "pairs_dropped", "buffer_rows"):
         samples = dict((l["layer"], v)
                        for l, v in reg.get(f"moe.{name}").samples())
         assert samples.keys() == {"0", "1", "2"}

@@ -104,6 +104,7 @@ _KNOWN_NAMES = frozenset({
     "pallas.flash.tiles",
     "pallas.kernel_calls",
     # text/pretrainer.py routing_stats (nn.DroplessMoE; label layer)
+    "moe.buffer_rows",
     "moe.held_load_max_over_mean",
     "moe.pairs_dropped",
     "moe.pairs_held",
