@@ -169,22 +169,29 @@ class DeepseekV3Block(Layer):
 
 
 def residual_block(x, input_norm, mixer, post_norm, ffn,
-                   routing_stats: bool = False):
+                   routing_stats: bool = False,
+                   residual_multiplier: Optional[float] = None):
     """h = x + mixer(input_norm(x)); y = h + ffn(post_norm(h)), each part
     under its region's scope (the token mixer's is `attn`, attention or
-    not).  With `routing_stats` (`ffn` an expert layer), (y, the layer's
+    not).  With `residual_multiplier` both branches are scaled by it before
+    they are added (h = x + m · mixer(…)); without, no multiply is emitted.
+    With `routing_stats` (`ffn` an expert layer), (y, the layer's
     `routing_stats` of this call)."""
+    def add(x, out):
+        return x + (out if residual_multiplier is None
+                    else residual_multiplier * out)
+
     with jax.named_scope(_xprof.REGION_LN):
         normed = input_norm(x)
     with jax.named_scope(_xprof.REGION_ATTN):
         out = mixer(normed)
     with jax.named_scope(_xprof.REGION_LN):
-        x = x + out
+        x = add(x, out)
         normed = post_norm(x)
     with jax.named_scope(_xprof.REGION_FFN):
         out = ffn(normed)
     with jax.named_scope(_xprof.REGION_LN):
-        y = x + out
+        y = add(x, out)
     return (y, ffn.routing_stats(normed)) if routing_stats else y
 
 

@@ -35,7 +35,7 @@ from ..nn.layer.base import Layer, Parameter
 from ..ops import attention as attn_ops
 from ..utils import xprof as _xprof
 from .deepseek_v3 import _host_device, next_token_loss, residual_block
-from .pretrainer import PretrainModel
+from .pretrainer import PretrainModel, run_groups, runs_of_one_kind
 
 CONV, ATTENTION = "conv", "full_attention"
 ROUTER_NORM_EPS = 1e-6      # the published code's, no key of the config
@@ -98,23 +98,27 @@ class Lfm2MoeConfig:
             0.0, self.initializer_range)})()
 
 
+def block_kinds(cfg: Lfm2MoeConfig) -> List[Tuple[str, bool]]:
+    """Every layer's kind of block, in order: (mixer, expert FFN?)."""
+    return [(mixer, i >= cfg.num_dense_layers)
+            for i, mixer in enumerate(cfg.layer_types)]
+
+
 def block_runs(cfg: Lfm2MoeConfig) -> List[Tuple[str, bool, int]]:
     """The layers in order as maximal runs of one kind of block:
     [(mixer, expert FFN?, layers)]."""
-    runs = []
-    for i, mixer in enumerate(cfg.layer_types):
-        kind = (mixer, i >= cfg.num_dense_layers)
-        if runs and runs[-1][:2] == kind:
-            runs[-1] = kind + (runs[-1][2] + 1,)
-        else:
-            runs.append(kind + (1,))
-    return runs
+    return [kind + (n,) for kind, n in runs_of_one_kind(block_kinds(cfg))]
+
+
+def kind_label(kind: Tuple[str, bool]) -> str:
+    mixer, expert = kind
+    return (f"{'conv' if mixer == CONV else 'attention'}"
+            f"_{'expert' if expert else 'dense'}")
 
 
 def run_name(index: int, mixer: str, expert: bool) -> str:
     """A run's group name: its place, its mixer, its FFN."""
-    return (f"run{index:02d}_{'conv' if mixer == CONV else 'attention'}"
-            f"_{'expert' if expert else 'dense'}")
+    return f"run{index:02d}_{kind_label((mixer, expert))}"
 
 
 def rotary_halves(x, theta: float):
@@ -268,19 +272,13 @@ class Lfm2MoeLMHead(Layer):
 def pretrain_model(cfg: Lfm2MoeConfig):
     """The model as `HybridPretrainer` takes it: one group a run of
     `block_runs`, in order."""
-    def stack(mixer, expert, n):
-        holder = Layer()
-        holder.layers = nn.LayerList(
-            [Lfm2MoeBlock(cfg, mixer, expert) for _ in range(n)])
-        return holder
-
     # drawn on the host, as `deepseek_v3.pretrain_model` says why
     with _host_device():
         embeddings = Lfm2MoeEmbeddings(cfg)
         return PretrainModel(
             embeddings=embeddings,
-            groups={run_name(i, mixer, expert): stack(mixer, expert, n)
-                    for i, (mixer, expert, n) in enumerate(block_runs(cfg))},
+            groups=run_groups(block_kinds(cfg), kind_label,
+                              lambda kind: Lfm2MoeBlock(cfg, *kind)),
             head=Lfm2MoeLMHead(cfg, embeddings.word_embeddings.weight),
             criterion=next_token_loss, embed_inputs=("input_ids",),
             token_keys=("input_ids",),

@@ -21,14 +21,17 @@ model's pieces and which batch keys each reads — and stacks, shards, scans
 and differentiates them.  `ernie_pretrain_model` is the first such
 description (an `ErnieConfig` handed to the trainer is turned into it);
 `text/deepseek_v3.py` gives a decoder-only one with two groups of blocks,
-`text/lfm2_moe.py` one whose groups follow a layer pattern (a group a run
-of one kind of block).
+`text/lfm2_moe.py` and `text/granite_hybrid.py` ones whose groups follow a
+layer pattern (a group a run of one kind of block: `runs_of_one_kind`,
+`run_groups`).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+import itertools
+from typing import Any, Callable, Dict, Hashable, List, Optional, Sequence, \
+    Tuple
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +109,28 @@ class PretrainModel:
     row_keys: Tuple[str, ...] = ()
     tied: Dict[str, str] = dataclasses.field(default_factory=dict)
     config: Any = None
+
+
+def runs_of_one_kind(kinds: Sequence[Hashable]) -> List[Tuple[Any, int]]:
+    """A layer pattern as the groups a trainer can scan: the layers' kinds
+    in order -> [(kind, layers)], the maximal runs of one kind.  Nothing
+    here knows a period, so a pattern that ends mid-period needs no special
+    case."""
+    return [(kind, len(list(run))) for kind, run in itertools.groupby(kinds)]
+
+
+def run_groups(kinds: Sequence[Hashable], label: Callable[[Any], str],
+               make_block: Callable[[Any], Any]) -> Dict[str, Any]:
+    """`PretrainModel.groups` of a layer pattern: one group a run of
+    `runs_of_one_kind`, named `run<index>_<label(kind)>` (its place, so the
+    names sort in the model's order) and holding `make_block(kind)` once a
+    layer."""
+    groups = {}
+    for index, (kind, n) in enumerate(runs_of_one_kind(kinds)):
+        holder = nn.Layer()
+        holder.layers = nn.LayerList([make_block(kind) for _ in range(n)])
+        groups[f"run{index:02d}_{label(kind)}"] = holder
+    return groups
 
 
 def ernie_pretrain_model(cfg: ErnieConfig,

@@ -225,7 +225,8 @@ _ZERO_BYTES = frozenset((
 REGION_EMBED = "embed"          # lookups, position/type add, embedding LN
 REGION_ENCODER = "encoder"      # the whole block stack
 REGION_ATTN = "attn"            # the token mixer: q/k/v/out projections +
-                                # the core, or a convolution mixer (`conv`)
+                                # the core, a convolution mixer (`conv`) or
+                                # a state-space mixer (`ssm`)
 REGION_ATTN_CORE = "core"       # scores -> softmax -> values, under attn
 REGION_FFN = "ffn"              # both products and the activation
 REGION_LN = "ln"                # residual add, dropout, LayerNorm
@@ -241,6 +242,11 @@ SCOPE_EXPERTS = "experts"       # ffn: sort, dispatch, grouped products, combine
 SCOPE_SHARED = "shared"         # ffn: the shared experts
 SCOPE_LATENT = "latent"         # attn: kv down-projection, its norm, up-projection
 SCOPE_CONV = "conv"             # attn: a gated short-convolution mixer, whole
+SCOPE_SSM = "ssm"               # attn: a Mamba-2 mixer, whole (in-projection,
+                                # convolution, scan, gated norm, out-projection)
+SCOPE_SSD = "ssd"               # attn/ssm: the state-space scan alone
+                                # (`ops/ssd.py`; `scan` is the block stack's
+                                # bookkeeping, `core` is attention's)
 REGIONS = (REGION_EMBED, REGION_ENCODER, REGION_ATTN, ATTN_CORE, REGION_FFN,
            REGION_LN, REGION_HEAD, REGION_LOSS, REGION_OPTIMIZER)
 

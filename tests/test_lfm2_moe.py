@@ -347,7 +347,8 @@ def test_no_layer_attribute_is_named_like_a_scope():
     model = lm.pretrain_model(lm.Lfm2MoeConfig(**TINY))
     taken = {r.split("/")[-1] for r in xprof.REGIONS} | {
         xprof.SCOPE_ROUTER, xprof.SCOPE_EXPERTS, xprof.SCOPE_SHARED,
-        xprof.SCOPE_LATENT, xprof.SCOPE_CONV, "attn"}
+        xprof.SCOPE_LATENT, xprof.SCOPE_CONV, xprof.SCOPE_SSM,
+        xprof.SCOPE_SSD, "attn"}
     assert xprof.SCOPE_CONV == "conv"
     layers = [model.embeddings, model.head] + [
         s.layers[0] for s in model.groups.values()]

@@ -103,6 +103,8 @@ _KNOWN_NAMES = frozenset({
     "pallas.fallbacks",
     "pallas.flash.tiles",
     "pallas.kernel_calls",
+    # ops/ssd.py (the state-space scan's dispatch; labels impl, chunk)
+    "ssm.scan_calls",
     # text/pretrainer.py routing_stats (nn.DroplessMoE; label layer)
     "moe.buffer_rows",
     "moe.held_load_max_over_mean",
