@@ -158,8 +158,8 @@ def _keep_from_hw_bits(seed_words, shape, rate):
 
 def _dropout_keep_hw(seed, bh, qi, kv_idx, shape, rate):
     """Hardware-PRNG keep-mask for the tile (q_block qi, k_block kv_idx), drawn
-    at `shape` (the packed kernels hold it `(block_q, block_k)`, the
-    standard ones `(block_k, block_q)`).
+    at `shape`: every kernel, standard and packed, holds the tile
+    `(block_k, block_q)` and draws it so through `_keep_scale`.
 
     The generator is RE-SEEDED per (seed, bh, q_block, k_block) tile, so the
     stream drawn for a tile depends only on its coordinates — the forward,

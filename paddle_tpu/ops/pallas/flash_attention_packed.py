@@ -5,18 +5,45 @@ which forces the model to materialize (b, s, h, d) -> (b, h, s, d)
 transposes around every attention call: a layout copy of q, k, v and the
 output forward, and of their gradients backward, in every layer.  This
 variant reads the projection output LAYOUT DIRECTLY: blocks are
-(1, block_q, 2*dim) slices of the (b, s, h*d) array covering 128 lanes of
+(1, block, 128) slices of the (b, s, h*d) array covering 128 lanes of
 heads (Mosaic requires 128-divisible lane blocks): a PAIR of 64-wide heads
-(BERT/ERNIE family) or ONE 128-wide head (LLaMA-class models); each grid
-cell runs the online-softmax recursion for its heads back to back.  No
-transpose ever exists in the program, and the backward runs no arithmetic
-outside its two kernels (see _backward).
+(BERT/ERNIE family) or ONE 128-wide head (LLaMA-class models).  No
+transpose of the operands ever exists in the program, and the backward
+runs no arithmetic outside its two kernels (see _backward).
 
-Numerics, dropout (hardware-PRNG per-tile reseed keyed by the GLOBAL head
-index, replayable in both backward kernels), bias handling, and the matmul
-dtype policy are identical to flash_attention.py; causal masking is
-supported the same way.  Non-pair-divisible head counts fall back to the
-standard kernel at the dispatch layer (ops/attention.py).
+**The tile.**  All three kernels hold a score tile as the standard ones
+do, `(block_k, block_q)`: keys along sublanes, queries along lanes
+(Sᵀ = K·Qᵀ, dPᵀ = V·dOᵀ by `_dot_nt`, no operand transposed for them).
+What a query row owns — running max and sum, logsumexp, delta — is a
+`(1, block_q)` row as it is stored, `(b, groups, heads_per_group, seq)`,
+and broadcasts down sublanes as it lies; a reduction over the keys is an
+elementwise pass over the tile's sublane groups.  The accumulators follow
+the tile: `flash_packed_fwd` sums Oᵀ `(d, block_q)` += Vᵀ·Pᵀ and
+`flash_packed_dq` dQᵀ += Kᵀ·dSᵀ, with the `(block_k, 128)` V or K block
+turned once a tile for every head of the group (a head is then a slice of
+sublanes) and the group's heads joined along sublanes, turned once a grid
+cell and stored as one unmasked 128-lane block; `flash_packed_dkdv`'s
+dV += Pᵀ·dO and dK += dSᵀ·Q consume the tile as it is.  The scores' scale
+lives on the resident block (`_scaled`), the factor of dS on the
+accumulator at its store.
+
+**The heads of a group share one loop body** (`_walk`): a tile of every
+head in one basic block, so that one head's exponentials run beside the
+other's products and a grid cell pays one loop's fill and drain, or none
+— a trip count known at trace time (a non-causal call) that fits one
+iteration of `_for_tiles` is unrolled.  On one v5e at (64, 12, 512, 64)
+with a padding bias (cell 1's call; PERF.md §6, PR 35) forward / dkdv / dq
+took 1.166 / 1.407 / 0.996 ms a call with queries along sublanes and a
+loop a head, 0.783 / 1.163 / 0.899 as they stand.
+
+Numerics, bias handling and the matmul dtype policy are those of
+flash_attention.py, whose helpers the kernels share; causal masking is
+supported the same way.  Dropout draws tile (qi, kv_idx) of GLOBAL head
+`group * heads_per_group + head` through `_keep_scale`, at the one shape
+all three kernels hold it, so forward, dkdv and dq replay one mask
+(tests_tpu/test_packed_attention_tpu.py holds them to it on the chip).
+Non-pair-divisible head counts fall back to the standard kernel at the
+dispatch layer (ops/attention.py).
 """
 from __future__ import annotations
 
@@ -33,120 +60,141 @@ from .flash_attention import (
     DEFAULT_BLOCK_K,
     DEFAULT_BLOCK_Q,
     NEG_INF,
-    _keep_mask,
+    _TILES_PER_ITERATION,
+    _dot,
+    _dot_nt,
+    _for_tiles,
+    _keep_scale,
+    _kv_end,
     _normalize_bias_seed,
+    _scaled,
     _smem,
+    _under_diagonal,
 )
+
+
+def _rows(idx, block):
+    """Rows `[idx * block, (idx + 1) * block)` of a ref: a traced index is
+    declared aligned, a Python int (an unrolled walk) is a static slice."""
+    if isinstance(idx, int):
+        return pl.dslice(idx * block, block)
+    return pl.dslice(pl.multiple_of(idx * block, block), block)
+
+
+def _walk(tile, start, stop, carry):
+    """`carry = tile(i, carry)` for i in [start, stop).  Bounds known at
+    trace time (a non-causal call) that fit one iteration of `_for_tiles`
+    run with no loop at all — the ERNIE cells have ONE tile a grid cell, and
+    at two the unrolled walk took a forward call 0.347 -> 0.302 ms
+    ((8, 8, 1024, 128), PERF.md §6, PR 35); everything else is `_for_tiles`."""
+    static = isinstance(start, int) and isinstance(stop, int)
+    if static and stop - start <= _TILES_PER_ITERATION:
+        for i in range(start, stop):
+            carry = tile(i, carry)
+        return carry
+    return _for_tiles(tile, start, stop, carry)
+
+
+def _head_lanes(head_dim):
+    """The lane slice of each head of a 128-lane group."""
+    return [slice(lo, lo + head_dim) for lo in range(0, 128, head_dim)]
 
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                 sm_scale, causal, dropout_rate, block_q, block_k, seq_len,
                 head_dim):
-    pair = pl.program_id(0)
+    """One q-block of one 128-lane group: per head the online softmax over
+    `(block_k, block_q)` tiles, Oᵀ `(head_dim, block_q)` accumulated; every
+    head's tile of a k-block in one body."""
+    group = pl.program_id(0)
     qi = pl.program_id(1)
-    q2 = q_ref[0]                       # (block_q, 2*head_dim)
+    heads = _head_lanes(head_dim)
+    q = _scaled(q_ref[0], sm_scale)     # (block_q, 128), the scores' scale on it
 
-    num_kv = seq_len // block_k
-    if causal:
-        num_kv_iter = (qi * block_q) // block_k + pl.cdiv(block_q, block_k)
-        num_kv_iter = jnp.minimum(num_kv_iter, num_kv)
-    else:
-        num_kv_iter = num_kv
-
-    for head in range(128 // head_dim):
-        lo = head * head_dim
-        q = q2[:, lo:lo + head_dim]
-        bh_global = pair * (128 // head_dim) + head  # dropout stream key
-
-        def body(kv_idx, carry, q=q, bh_global=bh_global, lo=lo):
-            acc, m_prev, l_prev = carry
-            k = k_ref[0, pl.dslice(kv_idx * block_k, block_k),
-                      lo:lo + head_dim]
-            v = v_ref[0, pl.dslice(kv_idx * block_k, block_k),
-                      lo:lo + head_dim]
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-            bias = bias_ref[0, 0, pl.dslice(kv_idx * block_k, block_k)]
-            s = s + bias.astype(jnp.float32)[None, :]
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
+    def tile(kv_idx, carry):
+        rows = _rows(kv_idx, block_k)
+        k = k_ref[0, rows, :]                             # (block_k, 128)
+        vt = v_ref[0, rows, :].T        # (128, block_k): a head is sublanes
+        bias = bias_ref[0, 0, rows][:, None]
+        if causal:
+            under = _under_diagonal(qi, kv_idx, block_q, block_k)
+        out = []
+        for head, (lanes, (acc, m_prev, l_prev)) in enumerate(zip(heads,
+                                                                  carry)):
+            st = _dot_nt(k[:, lanes], q[:, lanes]) + bias  # (block_k, block_q)
             if causal:
-                s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-            m_cur = jnp.max(s, axis=-1)
-            m_new = jnp.maximum(m_prev, m_cur)
+                st = jnp.where(under, st, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, None])
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+            pt = jnp.exp(st - m_new)
+            l_new = l_prev * alpha + jnp.sum(pt, axis=0, keepdims=True)
             if dropout_rate > 0.0:
-                keep = _keep_mask(seed_ref[0], jnp.int32(bh_global), qi,
-                                  kv_idx, q_pos, k_pos, dropout_rate)
-                p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-            acc = acc * alpha[:, None] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            return acc, m_new, l_new
+                pt = pt * _keep_scale(seed_ref[0], group * len(heads) + head,
+                                      qi, kv_idx, block_q, block_k,
+                                      dropout_rate)
+            acc = acc * alpha + _dot(vt[lanes], pt.astype(vt.dtype))
+            out.append((acc, m_new, l_new))
+        return tuple(out)
 
-        acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
-        m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((block_q,), jnp.float32)
-        acc, m, l = jax.lax.fori_loop(0, num_kv_iter, body, (acc0, m0, l0))
-        l_safe = jnp.maximum(l, 1e-30)
-        o_ref[0, :, lo:lo + head_dim] = (
-            acc / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, head] = m + jnp.log(l_safe)
+    state = _walk(tile, 0, _kv_end(qi, block_q, block_k, seq_len, causal), (
+        (jnp.zeros((head_dim, block_q), jnp.float32),
+         jnp.full((1, block_q), NEG_INF, jnp.float32),
+         jnp.zeros((1, block_q), jnp.float32)),) * len(heads))
+    l_safe = [jnp.maximum(l, 1e-30) for _, _, l in state]
+    # the heads' Oᵀ joined along sublanes, turned once: one 128-lane store
+    o_ref[0] = jnp.concatenate(
+        [acc / l for (acc, _, _), l in zip(state, l_safe)],
+        axis=0).T.astype(o_ref.dtype)
+    lse_ref[0, 0] = jnp.concatenate(
+        [m + jnp.log(l) for (_, m, _), l in zip(state, l_safe)], axis=0)
 
 
 def _bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                      delta_ref, dk_ref, dv_ref, *, sm_scale, causal,
                      dropout_rate, block_q, block_k, seq_len, head_dim):
-    pair = pl.program_id(0)
+    group = pl.program_id(0)
     kv_idx = pl.program_id(1)
-    bias = bias_ref[0, 0].astype(jnp.float32)   # (block_k,)
-    num_q = seq_len // block_q
-    qi_start = (kv_idx * block_k) // block_q if causal else 0
+    heads = _head_lanes(head_dim)
+    k = _scaled(k_ref[0], sm_scale)     # (block_k, 128), the scores' scale on it
+    v = v_ref[0]
+    bias = bias_ref[0, 0][:, None]      # this k-block's, turned once a cell
 
-    for head in range(128 // head_dim):
-        lo = head * head_dim
-        k = k_ref[0, :, lo:lo + head_dim]       # (block_k, d)
-        v = v_ref[0, :, lo:lo + head_dim]
-        bh_global = pair * (128 // head_dim) + head
-
-        def body(qi, carry, k=k, v=v, bh_global=bh_global, lo=lo, head=head):
-            dk_acc, dv_acc = carry
-            q = q_ref[0, pl.dslice(qi * block_q, block_q), lo:lo + head_dim]
-            do = do_ref[0, pl.dslice(qi * block_q, block_q), lo:lo + head_dim]
-            lse = lse_ref[0, 0, head, pl.dslice(qi * block_q, block_q)]
-            delta = delta_ref[0, 0, head, pl.dslice(qi * block_q, block_q)]
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-            s = s + bias[None, :]
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            p = jnp.exp(s - lse[:, None])
+    def tile(qi, carry):
+        rows = _rows(qi, block_q)
+        q = q_ref[0, rows, :]                             # (block_q, 128)
+        do = do_ref[0, rows, :]
+        if causal:
+            under = _under_diagonal(qi, kv_idx, block_q, block_k)
+        out = []
+        for head, (lanes, (dk_acc, dv_acc)) in enumerate(zip(heads, carry)):
+            lse = lse_ref[0, 0, pl.dslice(head, 1), rows]     # (1, block_q)
+            delta = delta_ref[0, 0, pl.dslice(head, 1), rows]
+            st = _dot_nt(k[:, lanes], q[:, lanes]) + bias  # (block_k, block_q)
+            pt = jnp.exp(st - lse)      # true softmax probabilities
             if causal:
-                p = jnp.where(q_pos >= k_pos, p, 0.0)
+                pt = jnp.where(under, pt, 0.0)
+            dpt = _dot_nt(v[:, lanes], do[:, lanes])
             if dropout_rate > 0.0:
-                keep = _keep_mask(seed_ref[0], jnp.int32(bh_global), qi,
-                                  kv_idx, q_pos, k_pos, dropout_rate)
-                inv = 1.0 / (1.0 - dropout_rate)
-                p_d = jnp.where(keep, p * inv, 0.0)
+                keep = _keep_scale(seed_ref[0], group * len(heads) + head, qi,
+                                   kv_idx, block_q, block_k, dropout_rate)
+                dv_acc = dv_acc + _dot((pt * keep).astype(do.dtype),
+                                       do[:, lanes])
+                dpt = dpt * keep
             else:
-                p_d = p
-            dv_acc = dv_acc + jnp.dot(p_d.astype(do.dtype).T, do,
-                                      preferred_element_type=jnp.float32)
-            dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-            if dropout_rate > 0.0:
-                dp = jnp.where(keep, dp * inv, 0.0)
-            ds = p * (dp - delta[:, None]) * sm_scale
-            dk_acc = dk_acc + jnp.dot(ds.astype(q.dtype).T, q,
-                                      preferred_element_type=jnp.float32)
-            return dk_acc, dv_acc
+                dv_acc = dv_acc + _dot(pt.astype(do.dtype), do[:, lanes])
+            dst = pt * (dpt - delta)
+            dk_acc = dk_acc + _dot(dst.astype(q.dtype), q[:, lanes])
+            out.append((dk_acc, dv_acc))
+        return tuple(out)
 
-        zeros = jnp.zeros((block_k, head_dim), jnp.float32)
-        dk, dv = jax.lax.fori_loop(qi_start, num_q, body, (zeros, zeros))
-        dk_ref[0, :, lo:lo + head_dim] = dk.astype(dk_ref.dtype)
-        dv_ref[0, :, lo:lo + head_dim] = dv.astype(dv_ref.dtype)
+    # q-blocks from the first that reaches this k-block's diagonal upwards
+    zeros = jnp.zeros((block_k, head_dim), jnp.float32)
+    lo = (kv_idx * block_k) // block_q if causal else 0
+    state = _walk(tile, lo, seq_len // block_q,
+                  ((zeros, zeros),) * len(heads))
+    for lanes, (dk, dv) in zip(heads, state):
+        dk_ref[0, :, lanes] = (dk * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0, :, lanes] = dv.astype(dv_ref.dtype)
 
 
 def _head_rowsums(a, b, head_dim):
@@ -167,65 +215,52 @@ def _head_rowsums(a, b, head_dim):
     for _ in range(2 if both_bf16 else 3):
         pieces.append(prod.astype(jnp.bfloat16))
         prod = prod - pieces[-1].astype(jnp.float32)
-    return sum(jax.lax.dot_general(selector, piece, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-               for piece in pieces)
+    return sum(_dot_nt(selector, piece) for piece in pieces)
 
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
                    lse_ref, dq_ref, delta_ref, *, sm_scale, causal,
                    dropout_rate, block_q, block_k, seq_len, head_dim):
-    pair = pl.program_id(0)
+    group = pl.program_id(0)
     qi = pl.program_id(1)
-    num_kv = seq_len // block_k
-    if causal:
-        num_kv_iter = (qi * block_q) // block_k + pl.cdiv(block_q, block_k)
-        num_kv_iter = jnp.minimum(num_kv_iter, num_kv)
-    else:
-        num_kv_iter = num_kv
+    heads = _head_lanes(head_dim)
+    q = _scaled(q_ref[0], sm_scale)     # (block_q, 128), the scores' scale on it
+    do = do_ref[0]
     # delta = rowsum(do * o) of this q-block per head, in float32 from the
     # blocks already in VMEM; written out for the dkdv kernel as fwd writes lse
-    deltas = _head_rowsums(do_ref[0], o_ref[0], head_dim)
-    delta_ref[0, 0] = deltas[:128 // head_dim]
+    deltas = _head_rowsums(do, o_ref[0], head_dim)
+    delta_ref[0, 0] = deltas[:len(heads)]
+    # lse rides a full-seq block (shared spec with the dkdv kernel); this
+    # cell only needs its q-block slice
+    lses = lse_ref[0, 0, :, pl.dslice(qi * block_q, block_q)]
 
-    for head in range(128 // head_dim):
-        lo = head * head_dim
-        q = q_ref[0, :, lo:lo + head_dim]
-        do = do_ref[0, :, lo:lo + head_dim]
-        delta = deltas[head][:, None]
-        # lse rides a full-seq block (shared spec with the dkdv kernel);
-        # this cell only needs its q-block slice
-        lse = lse_ref[0, 0, head, pl.dslice(qi * block_q, block_q)]
-        bh_global = pair * (128 // head_dim) + head
-
-        def body(kv_idx, dq_acc, q=q, do=do, lse=lse, delta=delta,
-                 bh_global=bh_global, lo=lo):
-            k = k_ref[0, pl.dslice(kv_idx * block_k, block_k),
-                      lo:lo + head_dim]
-            v = v_ref[0, pl.dslice(kv_idx * block_k, block_k),
-                      lo:lo + head_dim]
-            bias = bias_ref[0, 0, pl.dslice(kv_idx * block_k, block_k)]
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-            s = s + bias.astype(jnp.float32)[None, :]
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            p = jnp.exp(s - lse[:, None])
+    def tile(kv_idx, carry):            # carry: a head's dqᵀ, (d, block_q)
+        rows = _rows(kv_idx, block_k)
+        k = k_ref[0, rows, :]                             # (block_k, 128)
+        v = v_ref[0, rows, :]
+        kt = k.T                        # (128, block_k): a head is sublanes
+        bias = bias_ref[0, 0, rows][:, None]
+        if causal:
+            under = _under_diagonal(qi, kv_idx, block_q, block_k)
+        out = []
+        for head, (lanes, dq_acc) in enumerate(zip(heads, carry)):
+            st = _dot_nt(k[:, lanes], q[:, lanes]) + bias  # (block_k, block_q)
+            pt = jnp.exp(st - lses[head:head + 1])
             if causal:
-                p = jnp.where(q_pos >= k_pos, p, 0.0)
-            dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+                pt = jnp.where(under, pt, 0.0)
+            dpt = _dot_nt(v[:, lanes], do[:, lanes])
             if dropout_rate > 0.0:
-                keep = _keep_mask(seed_ref[0], jnp.int32(bh_global), qi,
-                                  kv_idx, q_pos, k_pos, dropout_rate)
-                dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-            ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-            return dq_acc + jnp.dot(ds, k, preferred_element_type=jnp.float32)
+                dpt = dpt * _keep_scale(seed_ref[0],
+                                        group * len(heads) + head, qi, kv_idx,
+                                        block_q, block_k, dropout_rate)
+            dst = (pt * (dpt - deltas[head:head + 1])).astype(k.dtype)
+            out.append(dq_acc + _dot(kt[lanes], dst))
+        return tuple(out)
 
-        dq = jax.lax.fori_loop(0, num_kv_iter, body,
-                               jnp.zeros((q_ref.shape[1], head_dim),
-                                         jnp.float32))
-        dq_ref[0, :, lo:lo + head_dim] = dq.astype(dq_ref.dtype)
+    dqs = _walk(tile, 0, _kv_end(qi, block_q, block_k, seq_len, causal),
+                (jnp.zeros((head_dim, block_q), jnp.float32),) * len(heads))
+    dq_ref[0] = (jnp.concatenate(dqs, axis=0) * sm_scale).T.astype(
+        dq_ref.dtype)
 
 
 def _specs(seq_len, pairs, block=None):

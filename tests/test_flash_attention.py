@@ -38,6 +38,15 @@ def _mask4d(bias):
     return bias[:, None, None, :]
 
 
+def _hash_keep(seed, b, h, s, rate):
+    """The keep mask the kernels draw off the chip, `(b, h, s_q, s_k)`: the
+    position hash of every global head."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
+    return jnp.stack([fa._dropout_keep(seed[0], jnp.int32(i), qpos, kpos, rate)
+                      for i in range(b * h)]).reshape(b, h, s, s)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_forward_matches_reference(qkv, pad_bias, causal):
     q, k, v = qkv
@@ -97,11 +106,7 @@ def test_dropout_grads_match_same_mask_reference(qkv, pad_bias):
     q, k, v = qkv
     seed = jnp.array([77], jnp.int32)
     rate = 0.3
-    qpos = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
-    keeps = jnp.stack([
-        fa._dropout_keep(seed[0], jnp.int32(i), qpos, kpos, rate)
-        for i in range(B * H)]).reshape(B, H, S, S)
+    keeps = _hash_keep(seed, B, H, S, rate)
 
     def ref(q, k, v):
         sm = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
@@ -275,6 +280,7 @@ class TestPackedLayout:
         (4, 64, 512, 128),      # four q-blocks
         (2, 128, 128, 128),     # one block, single 128-wide heads
         (2, 128, 512, 128),
+        (6, 64, 256, 128),      # an odd count of lane groups
     ])
     def test_packed_delta_is_float32_rowsum_of_do_times_o(self, monkeypatch,
                                                           h, d, s, block,
@@ -303,7 +309,7 @@ class TestPackedLayout:
         # and that array, not another, is what the dkdv kernel reads
         assert seen["flash_packed_dkdv"][0][-1] is made
 
-    @pytest.mark.parametrize("h,d", [(4, 64), (2, 128)])
+    @pytest.mark.parametrize("h,d", [(4, 64), (2, 128), (6, 64)])
     def test_packed_grad_runs_nothing_full_size_outside_its_kernels(self, h,
                                                                     d):
         """No equation of the gradient's jaxpr outside a pallas_call may touch
@@ -344,6 +350,68 @@ class TestPackedLayout:
         for name, a, r in zip("qkv", g_pk, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                        rtol=1e-5, atol=1e-5, err_msg=name)
+
+    @pytest.mark.parametrize("rate", [0.0, 0.1], ids=["nodrop", "drop0.1"])
+    @pytest.mark.parametrize("h,d,s,block_q,block_k,causal", [
+        (4, 64, 512, 128, 128, False),  # four key blocks, each its own bias
+        (4, 64, 512, 256, 128, True),   # a tile wider along the queries
+        (4, 64, 512, 128, 256, True),   # ... and along the keys
+        (2, 128, 512, 256, 128, True),  # one head a lane group
+        (3, 128, 256, 128, 256, False),
+        (6, 64, 256, 128, 128, False),  # an odd count of lane groups
+        (6, 64, 256, 64, 128, True),
+    ], ids=["keyblocks", "wide_q", "wide_k", "d128", "d128_full", "groups3",
+            "groups3_causal"])
+    def test_packed_forward_lse_and_gradients_match_plain_reference(
+            self, h, d, s, block_q, block_k, causal, rate):
+        """What a score tile held keys-along-sublanes can get wrong: out, the
+        stored logsumexp `(b, groups, heads_per_group, seq)` and dq, dk, dv
+        against plain jax.numpy under the kernel's own dropout draw, with a
+        bias that differs at every key."""
+        from paddle_tpu.ops.pallas import flash_attention_packed as fp
+
+        b = 2
+        rng = np.random.default_rng(7)
+        q4, k4, v4, do4 = (jnp.asarray(rng.normal(0, 1, (b, h, s, d)),
+                                       jnp.float32) for _ in range(4))
+        bias = jnp.asarray(rng.normal(0, 2, (b, s)), jnp.float32)
+        pack = lambda t: jnp.moveaxis(t, 1, 2).reshape(b, s, h * d)
+        unpack = lambda t: jnp.moveaxis(t.reshape(b, s, h, d), 2, 1)
+        seed = jnp.asarray([13], jnp.int32)
+        keep = None
+        if rate:
+            keep = _hash_keep(seed, b, h, s, rate)
+
+        def kernel(q, k, v):
+            return unpack(fp.flash_attention_packed(
+                pack(q), pack(k), pack(v), h, bias=bias, causal=causal,
+                dropout_rate=rate, seed=seed, block_q=block_q,
+                block_k=block_k))
+
+        def plain(q, k, v):
+            return _plain_attention(q, k, v, bias, causal, keep, rate)
+
+        out, vjp = jax.vjp(kernel, q4, k4, v4)
+        ref, vjp_ref = jax.vjp(plain, q4, k4, v4)
+        pairs = zip(("o", "dq", "dk", "dv"), (out,) + vjp(do4),
+                    (ref,) + vjp_ref(do4))
+        for name, a, r in pairs:
+            scale = max(float(jnp.abs(r).max()), 1.0)
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=1e-4, atol=1e-5 * scale,
+                                       err_msg=name)
+        _, lse = fp._forward(pack(q4), pack(k4), pack(v4), bias, seed, h,
+                             1.0 / np.sqrt(d), causal, rate, block_q, block_k)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q4, k4) / np.sqrt(d) \
+            + bias[:, None, None, :]
+        if causal:
+            scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores,
+                               -jnp.inf)
+        want = jax.nn.logsumexp(scores, -1).reshape(b, h * d // 128,
+                                                    128 // d, s)
+        assert lse.dtype == jnp.float32 and lse.shape == want.shape
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
 
     def test_mha_packed_dispatch(self, monkeypatch):
         """MultiHeadAttention takes the transpose-free path when the gate
@@ -423,11 +491,7 @@ def test_causal_tile_variants_match_reference(masked, block_q, block_k, d, d_v,
     seed = jnp.asarray([31], jnp.int32)
     keep = None
     if rate:
-        qpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-        kpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-        keep = jnp.stack([
-            fa._dropout_keep(seed[0], jnp.int32(i), qpos, kpos, rate)
-            for i in range(b * h)]).reshape(b, h, s, s)
+        keep = _hash_keep(seed, b, h, s, rate)
 
     def kernel(q, k, v):
         return fa.flash_attention(q, k, v, bias=bias, causal=True,
@@ -626,11 +690,7 @@ def test_grouped_keys_forward_and_three_gradients(kv_heads, causal,
     seed = jnp.asarray([11], jnp.int32)
     keep = None
     if rate:
-        qpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-        kpos = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-        keep = jnp.stack([
-            fa._dropout_keep(seed[0], jnp.int32(i), qpos, kpos, rate)
-            for i in range(b * h)]).reshape(b, h, s, s)
+        keep = _hash_keep(seed, b, h, s, rate)
 
     def kernel(q, k, v):
         return fa.flash_attention(q, k, v, bias=bias, causal=causal,

@@ -74,7 +74,12 @@ def test_functional_dispatch_respects_flag(monkeypatch):
     it off again."""
     import paddle_tpu.nn.functional.norm as norm_mod
     from paddle_tpu.core import flags
+    from paddle_tpu.parallel import mesh as mesh_mod
 
+    # no mesh: one that an earlier test file of this worker left behind
+    # (`current_mesh()` makes an all-dp one on first use) splits the 256 rows
+    # eight ways and closes the shape gate
+    monkeypatch.setattr(mesh_mod, "_global_mesh", None)
     calls = []
     orig = fln.fused_layer_norm
 
