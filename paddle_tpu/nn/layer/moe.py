@@ -498,10 +498,20 @@ class DroplessMoE(Layer):
         order, inverse, sizes = self._plan(ids)
         held = jnp.sum(sizes)
         dot = _gm.grouped_matmul if kernel else lax.ragged_dot
-        rows = _dispatch(tokens, order, inverse, pair_held, held, self.top_k)
-        out = dot(_gated(dot(rows, w_in, sizes), held), w_out, sizes)
-        return _combine(out, jnp.where(pair_held, weights, 0.0), order,
-                        inverse, held, self.top_k).reshape(shape)
+        # each pass under its own scope, planted round the call: a
+        # `custom_vjp`'s backward rule is traced under its call's scopes
+        with jax.named_scope(_xprof.SCOPE_DISPATCH):
+            rows = _dispatch(tokens, order, inverse, pair_held, held,
+                             self.top_k)
+        with jax.named_scope(_xprof.SCOPE_PRODUCTS):
+            rows = dot(rows, w_in, sizes)
+        with jax.named_scope(_xprof.SCOPE_GATED):
+            rows = _gated(rows, held)
+        with jax.named_scope(_xprof.SCOPE_PRODUCTS):
+            rows = dot(rows, w_out, sizes)
+        with jax.named_scope(_xprof.SCOPE_COMBINE):
+            return _combine(rows, jnp.where(pair_held, weights, 0.0), order,
+                            inverse, held, self.top_k).reshape(shape)
 
     def routing_stats(self, x):
         """Counts of one call's routing, as int32/float32 scalars: pairs

@@ -57,38 +57,52 @@ class MultiHeadAttention(Layer):
             # packed fast path: feed the projection outputs straight to the
             # kernel in (b, s, h*d) layout — no split/merge transposes (a
             # layout copy of q, k, v, o and of their gradients a layer)
-            qp = self.q_proj(query)
-            kp = self.k_proj(key)
-            vp = self.v_proj(value)
+            with jax.named_scope(_xprof.SCOPE_PROJ):
+                qp = self.q_proj(query)
+                kp = self.k_proj(key)
+                vp = self.v_proj(value)
             with jax.named_scope(_xprof.ATTN_CORE):
                 out = attn_ops.flash_attention_packed(
                     qp, kp, vp, self.num_heads, attn_mask=attn_mask,
                     dropout_p=self.dropout, training=self.training)
             if out is not None:
-                return self.out_proj(out)
-            q = self._split_heads(qp)
-            k = self._split_heads(kp)
-            v = self._split_heads(vp)
+                with jax.named_scope(_xprof.SCOPE_PROJ):
+                    return self.out_proj(out)
+            with jax.named_scope(_xprof.SCOPE_PREP):
+                q = self._split_heads(qp)
+                k = self._split_heads(kp)
+                v = self._split_heads(vp)
             return self._attend(q, k, v, attn_mask, None)
-        q = self._split_heads(self.q_proj(query))
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            q = self.q_proj(query)
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            q = self._split_heads(q)
         if isinstance(cache, MultiHeadAttention.StaticCache):
             k, v = cache.k, cache.v
         else:
-            k = self._split_heads(self.k_proj(key))
-            v = self._split_heads(self.v_proj(value))
-            if isinstance(cache, MultiHeadAttention.Cache):
-                k = jnp.concatenate([cache.k, k], axis=2)
-                v = jnp.concatenate([cache.v, v], axis=2)
-                cache = MultiHeadAttention.Cache(k, v)
+            with jax.named_scope(_xprof.SCOPE_PROJ):
+                k = self.k_proj(key)
+            with jax.named_scope(_xprof.SCOPE_PREP):
+                k = self._split_heads(k)
+            with jax.named_scope(_xprof.SCOPE_PROJ):
+                v = self.v_proj(value)
+            with jax.named_scope(_xprof.SCOPE_PREP):
+                v = self._split_heads(v)
+                if isinstance(cache, MultiHeadAttention.Cache):
+                    k = jnp.concatenate([cache.k, k], axis=2)
+                    v = jnp.concatenate([cache.v, v], axis=2)
+                    cache = MultiHeadAttention.Cache(k, v)
 
         return self._attend(q, k, v, attn_mask, cache)
 
     def _attend(self, q, k, v, attn_mask, cache):
         with jax.named_scope(_xprof.ATTN_CORE):
             out, weights = self._attend_core(q, k, v, attn_mask)
-        b, h, s, d = out.shape
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-        out = self.out_proj(out)
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            b, h, s, d = out.shape
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            out = self.out_proj(out)
         outs = (out,)
         if self.need_weights:
             outs += (weights,)

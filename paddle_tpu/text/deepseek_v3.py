@@ -121,23 +121,33 @@ class LatentAttention(Layer):
         h, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim)
         heads = lambda t: t.reshape(b, s, h, -1).transpose(0, 2, 1, 3)  # noqa: E731
-        q = heads(self.q_proj(x))                       # [b, h, s, 192]
-        with jax.named_scope(_xprof.SCOPE_LATENT):
-            latent, k_rope = jnp.split(self.kv_a_proj(x),
-                                       [cfg.kv_lora_rank], axis=-1)
-            kv = heads(self.kv_b_proj(self.kv_a_norm(latent)))
-        k_nope, v = jnp.split(kv, [nope], axis=-1)
-        q_rope = rotary_interleaved(q[..., nope:], cfg.rope_theta)
-        k_rope = rotary_interleaved(k_rope[:, None], cfg.rope_theta)
-        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope, (b, h, s, rope))], axis=-1)
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            q = self.q_proj(x)
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            q = heads(q)                                # [b, h, s, 192]
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            latent = self.kv_a_proj(x)
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            latent, k_rope = jnp.split(latent, [cfg.kv_lora_rank], axis=-1)
+            latent = self.kv_a_norm(latent)
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            kv = self.kv_b_proj(latent)
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            k_nope, v = jnp.split(heads(kv), [nope], axis=-1)
+            q_rope = rotary_interleaved(q[..., nope:], cfg.rope_theta)
+            k_rope = rotary_interleaved(k_rope[:, None], cfg.rope_theta)
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (b, h, s, rope))], axis=-1)
         with jax.named_scope(_xprof.ATTN_CORE):
             out = attn_ops.flash_attention(
                 q, k, v, is_causal=True,
                 scale=1.0 / math.sqrt(cfg.qk_head_dim),
                 training=self.training)
-        return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, s, -1))
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            return self.o_proj(out)
 
 
 class DeepseekV3Block(Layer):

@@ -163,19 +163,24 @@ class Mamba2Mixer(Layer):
         cfg, (b, s, _) = self.cfg, x.shape
         inner, state, f32 = cfg.mamba_d_inner, cfg.mamba_d_state, jnp.float32
         with jax.named_scope(_xprof.SCOPE_SSM):
-            z, xbc, dt = jnp.split(self.in_proj(x),
-                                   [inner, 2 * inner + 2 * state], axis=-1)
-            xbc = F.silu(causal_depthwise_conv(
-                xbc, self.taps.value, self.taps_bias.value))
-            u, B, C = jnp.split(xbc, [inner, inner + state], axis=-1)
-            dt = jax.nn.softplus(dt.astype(f32)
-                                 + self.dt_bias.value.astype(f32))
-            y = state_space_scan(
-                u.reshape(b, s, cfg.mamba_n_heads, cfg.mamba_d_head), dt,
-                -jnp.exp(self.a_log.value.astype(f32)), B, C,
-                self.d_skip.value, cfg.mamba_chunk_size)
-            y = self.gate_norm(y.reshape(b, s, inner) * F.silu(z))
-            return self.out_proj(y)
+            with jax.named_scope(_xprof.SCOPE_PROJ):
+                zxbcdt = self.in_proj(x)
+            with jax.named_scope(_xprof.SCOPE_POINTWISE):
+                z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * state],
+                                       axis=-1)
+                xbc = F.silu(causal_depthwise_conv(
+                    xbc, self.taps.value, self.taps_bias.value))
+                u, B, C = jnp.split(xbc, [inner, inner + state], axis=-1)
+                dt = jax.nn.softplus(dt.astype(f32)
+                                     + self.dt_bias.value.astype(f32))
+                u = u.reshape(b, s, cfg.mamba_n_heads, cfg.mamba_d_head)
+                a = -jnp.exp(self.a_log.value.astype(f32))
+            y = state_space_scan(u, dt, a, B, C, self.d_skip.value,
+                                 cfg.mamba_chunk_size)
+            with jax.named_scope(_xprof.SCOPE_POINTWISE):
+                y = self.gate_norm(y.reshape(b, s, inner) * F.silu(z))
+            with jax.named_scope(_xprof.SCOPE_PROJ):
+                return self.out_proj(y)
 
 
 class NopeAttention(Layer):
@@ -200,12 +205,18 @@ class NopeAttention(Layer):
         h, kv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                     cfg.head_dim)
         heads = lambda t: t.reshape(b, s, -1, d).transpose(0, 2, 1, 3)  # noqa: E731
-        q, k, v = jnp.split(self.qkv_proj(x), [h * d, (h + kv) * d], axis=-1)
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            qkv = self.qkv_proj(x)
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            q, k, v = jnp.split(qkv, [h * d, (h + kv) * d], axis=-1)
         with jax.named_scope(_xprof.ATTN_CORE):
             out = attn_ops.flash_attention(
                 heads(q), heads(k), heads(v), is_causal=True,
                 scale=cfg.attention_multiplier, training=self.training)
-        return self.out_proj(out.transpose(0, 2, 1, 3).reshape(b, s, -1))
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            return self.out_proj(out)
 
 
 class GraniteHybridBlock(Layer):

@@ -149,10 +149,12 @@ def _gated_conv_out(bcu, taps, w_out):
     again from the projection: z, c and the out-projection's input are three
     more tensors of the model's width in every layer of a scanned stack."""
     s, n = bcu.shape[-2], taps.shape[0]
-    gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
-    z = jnp.pad(gate_b * u, ((0, 0), (n - 1, 0), (0, 0)))
-    c = sum(taps[j] * z[:, j:j + s] for j in range(n))
-    return F.linear(gate_c * c, w_out)
+    with jax.named_scope(_xprof.SCOPE_POINTWISE):
+        gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
+        z = jnp.pad(gate_b * u, ((0, 0), (n - 1, 0), (0, 0)))
+        c = gate_c * sum(taps[j] * z[:, j:j + s] for j in range(n))
+    with jax.named_scope(_xprof.SCOPE_PROJ):
+        return F.linear(c, w_out)
 
 
 class ShortConv(Layer):
@@ -175,7 +177,9 @@ class ShortConv(Layer):
 
     def forward(self, x):
         with jax.named_scope(_xprof.SCOPE_CONV):
-            return _gated_conv_out(self.in_proj(x), self.taps.value,
+            with jax.named_scope(_xprof.SCOPE_PROJ):
+                bcu = self.in_proj(x)
+            return _gated_conv_out(bcu, self.taps.value,
                                    self.out_proj.weight.value)
 
 
@@ -204,16 +208,22 @@ class GroupedQueryAttention(Layer):
         h, kv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                     cfg.head_dim)
         heads = lambda t: t.reshape(b, s, -1, d).transpose(0, 2, 1, 3)  # noqa: E731
-        q, k, v = jnp.split(self.qkv_proj(x), [h * d, (h + kv) * d], axis=-1)
-        q = rotary_halves(heads(self.q_norm(q.reshape(b, s, h, d))),
-                          cfg.rope_theta)
-        k = rotary_halves(heads(self.k_norm(k.reshape(b, s, kv, d))),
-                          cfg.rope_theta)
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            qkv = self.qkv_proj(x)
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            q, k, v = jnp.split(qkv, [h * d, (h + kv) * d], axis=-1)
+            q = rotary_halves(heads(self.q_norm(q.reshape(b, s, h, d))),
+                              cfg.rope_theta)
+            k = rotary_halves(heads(self.k_norm(k.reshape(b, s, kv, d))),
+                              cfg.rope_theta)
         with jax.named_scope(_xprof.ATTN_CORE):
             out = attn_ops.flash_attention(
                 q, k, heads(v), is_causal=True, scale=1.0 / math.sqrt(d),
                 training=self.training)
-        return self.out_proj(out.transpose(0, 2, 1, 3).reshape(b, s, -1))
+        with jax.named_scope(_xprof.SCOPE_PREP):
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        with jax.named_scope(_xprof.SCOPE_PROJ):
+            return self.out_proj(out)
 
 
 class Lfm2MoeBlock(Layer):

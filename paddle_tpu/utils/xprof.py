@@ -63,7 +63,8 @@ __all__ = [
     "resolve_peaks", "parse_hlo", "attribute_hlo", "build_report",
     "profile_aot", "profile_jit", "memory_stats", "live_array_census",
     "render_table", "summarize", "last_summary",
-    "OP_SCOPE_RE", "op_scope_name", "REGIONS", "ATTN_CORE", "step_region",
+    "OP_SCOPE_RE", "op_scope_name", "REGIONS", "ATTN_CORE", "SUBSCOPES",
+    "PASSES", "step_region",
 ]
 
 # -- telemetry (registered at import so metricsdump lists them) --------------
@@ -234,21 +235,48 @@ REGION_HEAD = "head"            # MLM transform + tied logits + NSP
 REGION_LOSS = "loss"            # cross-entropies
 REGION_OPTIMIZER = "optimizer"  # the update over every leaf, grad casts
 ATTN_CORE = f"{REGION_ATTN}/{REGION_ATTN_CORE}"   # as reports name it
-# finer scopes inside a region (an expert layer's parts under `ffn`, latent
-# attention's compression under `attn`): a region reader ignores them, a
+# finer scopes inside a region (an expert layer's parts under `ffn`, a
+# mixer that is no attention under `attn`): a region reader ignores them, a
 # sub-scope reader finds them as `<region>/<sub>`
 SCOPE_ROUTER = "router"         # ffn: scores, selection, weights
 SCOPE_EXPERTS = "experts"       # ffn: sort, dispatch, grouped products, combine
 SCOPE_SHARED = "shared"         # ffn: the shared experts
-SCOPE_LATENT = "latent"         # attn: kv down-projection, its norm, up-projection
 SCOPE_CONV = "conv"             # attn: a gated short-convolution mixer, whole
 SCOPE_SSM = "ssm"               # attn: a Mamba-2 mixer, whole (in-projection,
                                 # convolution, scan, gated norm, out-projection)
 SCOPE_SSD = "ssd"               # attn/ssm: the state-space scan alone
                                 # (`ops/ssd.py`; `scan` is the block stack's
                                 # bookkeeping, `core` is attention's)
+# the second level: what a mixer or the expert layer does, pass by pass
+SCOPE_PROJ = "proj"             # attn, attn/conv, attn/ssm: the projections'
+                                # products and nothing else
+SCOPE_PREP = "prep"             # attn: all between the projections and the
+                                # core, both ways (head split and merge,
+                                # splits, concatenations, rotary, q/k norm)
+SCOPE_POINTWISE = "pointwise"   # attn/conv, attn/ssm: what a mixer does that
+                                # is neither a product nor the scan
+SCOPE_DISPATCH = "dispatch"     # ffn/experts: the tokens' rows into sorted
+                                # order; the per-token gather-and-sum back
+SCOPE_PRODUCTS = "products"     # ffn/experts: the grouped products alone
+SCOPE_GATED = "gated"           # ffn/experts: the activation between them
+SCOPE_COMBINE = "combine"       # ffn/experts: the weighted sum back per token
 REGIONS = (REGION_EMBED, REGION_ENCODER, REGION_ATTN, ATTN_CORE, REGION_FFN,
            REGION_LN, REGION_HEAD, REGION_LOSS, REGION_OPTIMIZER)
+# every scope under a region, as a path from it -> the scopes planted
+# directly inside: the one list that the program, its tests and the
+# benchmark's tools read.  The sort (`DroplessMoE._plan`) stays directly
+# under `experts`.
+SUBSCOPES = {
+    REGION_ATTN: (REGION_ATTN_CORE, SCOPE_PROJ, SCOPE_PREP, SCOPE_CONV,
+                  SCOPE_SSM),
+    f"{REGION_ATTN}/{SCOPE_CONV}": (SCOPE_PROJ, SCOPE_POINTWISE),
+    f"{REGION_ATTN}/{SCOPE_SSM}": (SCOPE_PROJ, SCOPE_POINTWISE, SCOPE_SSD),
+    REGION_FFN: (SCOPE_ROUTER, SCOPE_EXPERTS, SCOPE_SHARED),
+    f"{REGION_FFN}/{SCOPE_EXPERTS}": (SCOPE_DISPATCH, SCOPE_PRODUCTS,
+                                      SCOPE_GATED, SCOPE_COMBINE),
+}
+# the passes `step_region` tells apart
+PASSES = ("fwd", "remat", "bwd")
 
 
 def op_scope_name(op_type: str, block_idx: int, op_idx: int) -> str:
@@ -464,11 +492,13 @@ def step_region(op_name: str) -> Tuple[Optional[str], str]:
     """(region, pass) of one instruction's ``metadata.op_name``: the
     innermost of ``REGIONS`` among the path's scopes (``attn/core`` where
     ``core`` sits directly under ``attn``), None where the path names none;
-    pass is ``"bwd"`` where the path went through ``transpose(`` — the
-    backward of that scope, a ``jax.checkpoint``'s recomputed forward
-    included — else ``"fwd"``."""
-    which = "bwd" if "transpose(" in op_name else "fwd"
+    pass is one of ``PASSES``: ``"remat"`` where the path goes through
+    ``rematted_computation`` (a ``jax.checkpoint``'s recomputed forward, as
+    JAX itself marks it), else ``"bwd"`` where it went through
+    ``transpose(`` (the backward of that scope), else ``"fwd"``."""
     comps = _WRAPPERS_RE.sub("", _JIT_RE.sub("", op_name)).split("/")
+    which = ("remat" if "rematted_computation" in comps
+             else "bwd" if "transpose(" in op_name else "fwd")
     for i in range(len(comps) - 1, -1, -1):
         if comps[i] == REGION_ATTN_CORE and i and comps[i - 1] == REGION_ATTN:
             return ATTN_CORE, which
@@ -483,11 +513,11 @@ def _region_of(op_name: str) -> Tuple[str, str, bool]:
     Attributed regions come from user named scopes: the Executor's
     ``<op_type>.b<N>.i<M>`` encoding (innermost match wins — sub-block ops
     nest inside their control-flow op's scope), else a training step's
-    ``REGIONS`` (``<region>.fwd`` / ``<region>.bwd``, see ``step_region``),
-    else any named_scope path the user planted (dygraph Layers push their
-    layer names).  ``jit(...)`` components are jax function boundaries, not
-    user scopes, and the final component is the lowered primitive — both
-    are stripped."""
+    ``REGIONS`` (``<region>.fwd`` / ``.remat`` / ``.bwd``, see
+    ``step_region``), else any named_scope path the user planted (dygraph
+    Layers push their layer names).  ``jit(...)`` components are jax
+    function boundaries, not user scopes, and the final component is the
+    lowered primitive — both are stripped."""
     if not op_name or "/" not in op_name:
         return ("<unattributed>", op_name or "<none>", False)
     comps = op_name.split("/")

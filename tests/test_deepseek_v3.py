@@ -379,8 +379,15 @@ def tiny_lm():
     params = trainer.place_params(trainer.init_params())
     batch = {"input_ids": jnp.asarray(np.random.default_rng(0).integers(
         1, 96, (2, 32)), jnp.int32)}
-    text = step.lower(params, opt.init(params), batch,
-                      jax.random.PRNGKey(0)).compile().as_text()
+    # the persistent cache keys a program without its metadata: an entry
+    # compiled under other scopes would serve its own text
+    name = "jax_compilation_cache_include_metadata_in_key"
+    jax.config.update(name, True)
+    try:
+        text = step.lower(params, opt.init(params), batch,
+                          jax.random.PRNGKey(0)).compile().as_text()
+    finally:
+        jax.config.update(name, False)
     state, losses = opt.init(params), []
     for _ in range(4):
         params, state, loss = step(params, state, batch,
@@ -453,7 +460,7 @@ def test_every_instruction_of_the_step_lies_in_a_region(tiny_lm):
 
 
 @pytest.mark.parametrize("scope", ["ffn/router", "ffn/experts", "ffn/shared",
-                                   "attn/latent", "attn/core"])
+                                   "attn/proj", "attn/prep", "attn/core"])
 def test_the_finer_scopes_are_in_the_step_forward_and_backward(tiny_lm, scope):
     paths = set(re.findall(r'op_name="([^"]*)"', tiny_lm["text"]))
     region, sub = scope.split("/")
@@ -467,9 +474,10 @@ def test_the_finer_scopes_are_in_the_step_forward_and_backward(tiny_lm, scope):
 def test_no_layer_attribute_is_named_like_a_scope():
     cfg = ds.DeepseekV3Config(**TINY)
     model = ds.pretrain_model(cfg)
-    taken = {r.split("/")[-1] for r in xprof.REGIONS} | {
-        xprof.SCOPE_ROUTER, xprof.SCOPE_EXPERTS, xprof.SCOPE_SHARED,
-        xprof.SCOPE_LATENT, "attn"}
+    taken = {r.split("/")[-1] for r in xprof.REGIONS} | {"attn"} | {
+        c for children in xprof.SUBSCOPES.values() for c in children}
+    assert {"proj", "prep", "dispatch", "products", "gated",
+            "combine"} <= taken and "latent" not in taken
     layers = [model.embeddings, model.head] + [
         s.layers[0] for s in model.groups.values()]
     for layer in layers:

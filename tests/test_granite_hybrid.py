@@ -502,11 +502,11 @@ def test_the_mixers_products_lie_under_ssm_and_the_scans_under_ssd(tiny_lm):
 def test_no_layer_attribute_is_named_like_a_scope():
     """Shared by both hybrids: with `xprof_scopes` on an attribute's name is
     a scope, and a region reader would take it for one."""
-    taken = {r.split("/")[-1] for r in xprof.REGIONS} | {
-        xprof.SCOPE_ROUTER, xprof.SCOPE_EXPERTS, xprof.SCOPE_SHARED,
-        xprof.SCOPE_LATENT, xprof.SCOPE_CONV, xprof.SCOPE_SSM,
-        xprof.SCOPE_SSD, "attn", "scan"}
+    taken = {r.split("/")[-1] for r in xprof.REGIONS} | {"attn", "scan"} | {
+        c for children in xprof.SUBSCOPES.values() for c in children}
     assert (xprof.SCOPE_SSM, xprof.SCOPE_SSD) == ("ssm", "ssd")
+    assert {"ssm", "ssd", "proj", "prep", "pointwise"} <= taken \
+        and "latent" not in taken
     for model in (gh.pretrain_model(gh.GraniteHybridConfig(**TINY)),
                   lm.pretrain_model(lm.Lfm2MoeConfig(
                       vocab_size=96, hidden_size=64, num_hidden_layers=2,

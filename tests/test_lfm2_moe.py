@@ -345,11 +345,11 @@ def test_no_shared_scope_and_the_conv_products_lie_under_conv(tiny_lm):
 
 def test_no_layer_attribute_is_named_like_a_scope():
     model = lm.pretrain_model(lm.Lfm2MoeConfig(**TINY))
-    taken = {r.split("/")[-1] for r in xprof.REGIONS} | {
-        xprof.SCOPE_ROUTER, xprof.SCOPE_EXPERTS, xprof.SCOPE_SHARED,
-        xprof.SCOPE_LATENT, xprof.SCOPE_CONV, xprof.SCOPE_SSM,
-        xprof.SCOPE_SSD, "attn"}
+    taken = {r.split("/")[-1] for r in xprof.REGIONS} | {"attn"} | {
+        c for children in xprof.SUBSCOPES.values() for c in children}
     assert xprof.SCOPE_CONV == "conv"
+    assert {"conv", "proj", "prep", "pointwise", "dispatch", "products",
+            "gated", "combine"} <= taken and "latent" not in taken
     layers = [model.embeddings, model.head] + [
         s.layers[0] for s in model.groups.values()]
     for layer in layers:

@@ -2,7 +2,9 @@
 (`utils/xprof.REGIONS`): a tiny ERNIE step built through Fleet +
 HybridPretrainer carries every region in its compiled text, forward and
 backward, whatever `xprof_scopes` says and on both pipeline schedules;
-`step_region` reads a path; `parse_hlo` prices a dot from jax 0.9's
+the small step of each of the four families carries the second level of
+scopes (`utils/xprof.SUBSCOPES`) on a forward, a recomputed and a backward
+path; `step_region` reads a path; `parse_hlo` prices a dot from jax 0.9's
 shapeless-operand text."""
 import re
 
@@ -130,13 +132,25 @@ def test_the_1f1b_step_carries_the_regions():
 def test_step_region_reads_forward_backward_and_recomputed_paths():
     sr = xprof.step_region
     body = "while/body/closed_call"
+    assert xprof.PASSES == ("fwd", "remat", "bwd")
     assert sr(f"jit(train_step)/jvp(encoder)/{body}/ffn/linear1/dot_general") \
         == ("ffn", "fwd")
     assert sr(f"jit(train_step)/transpose(jvp(encoder))/{body}/ffn/mul") == \
         ("ffn", "bwd")
+    # a checkpoint's recomputed forward, as JAX marks it; its backward runs
+    # under `checkpoint` alone
     assert sr(f"jit(train_step)/transpose(jvp(encoder))/{body}/checkpoint/"
               "rematted_computation/attn/self_attn/attn/core/exp") == \
-        (xprof.ATTN_CORE, "bwd")
+        (xprof.ATTN_CORE, "remat")
+    assert sr(f"jit(train_step)/transpose(jvp(encoder))/{body}/checkpoint/"
+              "attn/self_attn/attn/core/mul") == (xprof.ATTN_CORE, "bwd")
+    # a checkpoint inside a recomputed block: recomputed, once
+    assert sr(f"jit(train_step)/transpose(jvp(encoder))/{body}/checkpoint/"
+              "rematted_computation/ffn/checkpoint/rematted_computation/"
+              "experts/gated/mul") == ("ffn", "remat")
+    # the marker is a scope of the path, not a part of a name
+    assert sr(f"jit(train_step)/transpose(jvp(encoder))/{body}/ffn/"
+              "rematted_computation_of_mine/mul") == ("ffn", "bwd")
     assert sr(f"jit(train_step)/jvp(encoder)/{body}/attn/self_attn/q_proj/"
               "dot_general") == ("attn", "fwd")
     assert sr("jit(train_step)/jvp(encoder)/while/body/dynamic_update_slice") \
@@ -148,9 +162,162 @@ def test_step_region_reads_forward_backward_and_recomputed_paths():
         ("ffn.fwd", "ffn", True)
     assert xprof._region_of("jit(s)/transpose(jvp(head))/cls/dot_general") \
         == ("head.bwd", "head", True)
+    assert xprof._region_of(
+        "jit(s)/transpose(jvp(encoder))/while/body/checkpoint/"
+        "rematted_computation/ffn/dot_general") == ("ffn.remat", "ffn", True)
     # the Executor's op scopes still win, other Layer paths stay as they were
     assert xprof._region_of("jit(s)/mul.b0.i3/dot_general")[0] == "mul.b0.i3"
     assert xprof._region_of("jit(s)/Net/proj/dot_general")[0] == "Net/proj"
+
+
+REMAT_HLO = """HloModule jit_f, is_scheduled=true
+
+ENTRY %main.1 (a.1: f32[32,64], b.1: f32[64,16]) -> f32[32,16] {
+  %a.1 = f32[32,64]{1,0} parameter(0), metadata={op_name="a"}
+  %b.1 = f32[64,16]{1,0} parameter(1), metadata={op_name="b"}
+  %dot.1 = f32[32,16]{1,0} dot(%a.1, %b.1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(f)/jvp(encoder)/while/body/closed_call/checkpoint/ffn/dot_general"}
+  %dot.2 = f32[32,16]{1,0} dot(%a.1, %b.1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(f)/transpose(jvp(encoder))/while/body/closed_call/checkpoint/rematted_computation/ffn/dot_general"}
+  ROOT %add.1 = f32[32,16]{1,0} add(%dot.1, %dot.2), metadata={op_name="jit(f)/transpose(jvp(encoder))/while/body/closed_call/checkpoint/ffn/add_any"}
+}
+"""
+
+
+def test_attribute_hlo_reports_the_recomputed_forward_apart():
+    regions = xprof.attribute_hlo(REMAT_HLO)
+    assert {"ffn.fwd", "ffn.remat", "ffn.bwd"} <= set(regions)
+    assert regions["ffn.remat"].flops == regions["ffn.fwd"].flops == \
+        2 * 32 * 16 * 64
+    assert regions["ffn.bwd"].flops == 32 * 16
+
+
+# ---------------------------------------------------------------------------
+# the second level of scopes, family by family
+# ---------------------------------------------------------------------------
+def _family_models():
+    from paddle_tpu.text import deepseek_v3 as ds
+    from paddle_tpu.text import granite_hybrid as gh
+    from paddle_tpu.text import lfm2_moe as lm
+    return {
+        "ernie": lambda: ErnieConfig(**CFG),
+        "deepseek_v3": lambda: ds.pretrain_model(ds.DeepseekV3Config(
+            vocab_size=96, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=2, intermediate_size=64,
+            moe_intermediate_size=16, n_routed_experts=16,
+            n_shared_experts=2, num_experts_per_tok=3, kv_lora_rank=16,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            held_experts=(4, 4))),
+        "lfm2_moe": lambda: lm.pretrain_model(lm.Lfm2MoeConfig(
+            vocab_size=96, hidden_size=64, num_hidden_layers=3,
+            num_attention_heads=4, num_key_value_heads=2,
+            intermediate_size=96, moe_intermediate_size=32, num_experts=8,
+            num_experts_per_tok=2, num_dense_layers=1,
+            layer_types=["conv", "full_attention", "conv"],
+            held_experts=(4, 4))),
+        "granite_hybrid": lambda: gh.pretrain_model(gh.GraniteHybridConfig(
+            vocab_size=96, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=2,
+            shared_intermediate_size=48, layer_types=["mamba", "attention"],
+            mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
+            mamba_chunk_size=8)),
+    }
+
+
+# the parents of `xprof.SUBSCOPES` that a family's step has; the ERNIE step
+# takes the packed attention path, which has no `prep`
+FAMILY_PARENTS = {
+    "ernie": ("attn",),
+    "deepseek_v3": ("attn", "ffn/experts"),
+    "lfm2_moe": ("attn", "attn/conv", "ffn/experts"),
+    "granite_hybrid": ("attn", "attn/ssm"),
+}
+SECOND_LEVEL = (xprof.SCOPE_PROJ, xprof.SCOPE_PREP, xprof.SCOPE_POINTWISE,
+                xprof.SCOPE_DISPATCH, xprof.SCOPE_PRODUCTS, xprof.SCOPE_GATED,
+                xprof.SCOPE_COMBINE)
+FAMILY_SCOPES = [
+    (family, f"{parent}/{child}")
+    for family, parents in FAMILY_PARENTS.items() for parent in parents
+    for child in xprof.SUBSCOPES[parent] if child in SECOND_LEVEL
+    and (family, child) != ("ernie", xprof.SCOPE_PREP)]
+
+
+def family_paths(family, scopes_flag):
+    """Every `op_name` of a family's small compiled step, every block under
+    `jax.checkpoint` with nothing saved (as the recomputed cell runs it)."""
+    saved = flags.get_flags(["xprof_scopes"])
+    flags.set_flags({"xprof_scopes": scopes_flag})
+    try:
+        strategy = DistributedStrategy()
+        strategy.hybrid_configs.dp_degree = 1
+        strategy.recompute = True
+        strategy.recompute_configs.policy = None
+        fleet = Fleet().init(strategy=strategy, devices=jax.devices()[:1])
+        trainer = HybridPretrainer(_family_models()[family](),
+                                   mesh=fleet.mesh, strategy=strategy)
+        opt = fleet.distributed_optimizer(Adam(learning_rate=1e-4))
+        step = jax.jit(trainer.make_train_step(opt,
+                                               compute_dtype=jnp.bfloat16))
+        params = trainer.place_params(trainer.init_params())
+        ids = np.random.default_rng(0).integers(1, 64, (2, 16)).astype(
+            np.int32)
+        batch = {"input_ids": ids}
+        if family == "ernie":
+            batch.update(token_type_ids=np.zeros((2, 16), np.int32),
+                         mlm_labels=ids, nsp_labels=np.zeros((2,), np.int32))
+        sh = trainer.data_shardings()
+        batch = {k: jax.device_put(v, sh[k]) for k, v in batch.items()}
+        text = step.lower(params, opt.init(params), batch,
+                          jax.random.PRNGKey(0)).compile().as_text()
+    finally:
+        flags.set_flags(saved)
+        mesh_mod.set_mesh(None)
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+@pytest.fixture(scope="module")
+def family_steps():
+    made = {}
+
+    def paths(family, scopes_flag):
+        if (family, scopes_flag) not in made:
+            made[family, scopes_flag] = family_paths(family, scopes_flag)
+        return made[family, scopes_flag]
+    return paths
+
+
+def passes_under(paths, scope):
+    """The passes (`xprof.PASSES`) of the paths that go through `scope`, a
+    Layer attribute's own scope (`self_attn`, `mlp`) allowed in between."""
+    under = re.compile("/" + r"/(?:[\w.]+/)*?".join(scope.split("/")) + "/")
+    return {xprof.step_region(p)[1] for p in paths if under.search(p)}
+
+
+@pytest.mark.parametrize("scopes_flag", [True, False],
+                         ids=["xprof_scopes", "no-xprof_scopes"])
+@pytest.mark.parametrize("family, scope", FAMILY_SCOPES)
+def test_family_step_carries_the_second_level_in_every_pass(
+        family_steps, family, scope, scopes_flag):
+    assert passes_under(family_steps(family, scopes_flag), scope) == \
+        set(xprof.PASSES)
+
+
+def test_every_second_level_scope_is_planted_in_some_family():
+    planted = {scope.split("/")[-1] for _, scope in FAMILY_SCOPES}
+    assert planted == set(SECOND_LEVEL)
+    # and the table names nothing else beside the first level's scopes
+    named = {c for kids in xprof.SUBSCOPES.values() for c in kids}
+    assert named - set(SECOND_LEVEL) == {
+        xprof.REGION_ATTN_CORE, xprof.SCOPE_CONV, xprof.SCOPE_SSM,
+        xprof.SCOPE_SSD, xprof.SCOPE_ROUTER, xprof.SCOPE_EXPERTS,
+        xprof.SCOPE_SHARED}
+    assert not hasattr(xprof, "SCOPE_LATENT")
+
+
+@pytest.mark.parametrize("family", ["deepseek_v3", "lfm2_moe"])
+def test_the_sort_stays_directly_under_experts(family_steps, family):
+    paths = family_steps(family, True)
+    sorts = [p for p in paths if "/experts/" in p and p.endswith("/sort")]
+    assert sorts
+    assert not any(set(p.split("/")) & set(SECOND_LEVEL) for p in sorts)
 
 
 HEAD = """HloModule jit_f, is_scheduled=true
