@@ -74,13 +74,13 @@ def scan_inputs(chunk_sum, s=48, b=2, h=3, p=4, n=5):
     return x, dt, A, B, C, D
 
 
-@pytest.mark.parametrize("chunks", [1, 3, 8])
+@pytest.mark.parametrize("chunks", [1, 3, 8, 16])
 @pytest.mark.parametrize("chunk_sum", [-0.3, -200.0])
 def test_chunked_scan_is_the_recurrence(chunks, chunk_sum):
     """Values and the gradient of every input, at chunk lengths that cut 48
-    positions into 1, 3 and 8 chunks, with a chunk's decay sum near 0 and
-    near -200 (where exp(cs_i)·exp(-cs_j) would be 0·inf): finite and equal
-    to the recurrence's to float32 round-off."""
+    positions into 1, 3, 8 and 16 chunks (the cell's count), with a chunk's
+    decay sum near 0 and near -200 (where exp(cs_i)·exp(-cs_j) would be
+    0·inf): finite and equal to the recurrence's to float32 round-off."""
     args = scan_inputs(chunk_sum * chunks)      # per chunk: about chunk_sum
     chunk = 48 // chunks
     per_chunk = float(jnp.mean(jnp.sum(
@@ -492,7 +492,9 @@ def test_the_mixers_products_lie_under_ssm_and_the_scans_under_ssd(tiny_lm):
     ssm = [p for p in paths if re.search(r"/attn/(?:[\w.]+/)*?ssm/", p)]
     assert any("dot_general" in p and "/ssd/" not in p for p in ssm)
     assert any("dot_general" in p and "/ssd/" in p for p in ssm)
-    assert any("/ssd/" in p and "while" in p.split("/ssd/")[1] for p in ssm)
+    # the state passes from chunk to chunk in one product, not in a loop
+    assert not [p for p in ssm
+                if "/ssd/" in p and "while" in p.split("/ssd/")[1]]
     # nothing of the scan lies outside the mixer's scope, nothing of it is
     # attention's core, and `scan` stays the block stack's own
     assert not [p for p in paths if "/ssd/" in p and "/ssm/" not in p]
