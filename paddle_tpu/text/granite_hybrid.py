@@ -113,15 +113,17 @@ class GraniteHybridConfig:
             0.0, self.initializer_range)})()
 
 
-def causal_depthwise_conv(x, taps, bias):
+def causal_depthwise_conv(x, taps, bias=None):
     """y_t = bias + Σ_j taps[j] · x_{t−(n−1−j)} per channel, x before
     position 0 nought: a depthwise causal `Conv1d(kernel n, groups channels,
     padding n − 1)` cut to the first s outputs, on [b, s, channels] with the
     channels on lanes — one shifted multiply-add a tap over x padded once, in
-    x's own dtype (`lfm2_moe._gated_conv_out` says what that saves)."""
+    x's own dtype (`lfm2_moe._gated_conv_out` says what that saves).  No
+    `bias`, no add."""
     s, n = x.shape[-2], taps.shape[0]
     padded = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
-    return sum(taps[j] * padded[:, j:j + s] for j in range(n)) + bias
+    out = sum(taps[j] * padded[:, j:j + s] for j in range(n))
+    return out if bias is None else out + bias
 
 
 class Mamba2Mixer(Layer):

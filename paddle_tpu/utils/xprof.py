@@ -226,8 +226,9 @@ _ZERO_BYTES = frozenset((
 REGION_EMBED = "embed"          # lookups, position/type add, embedding LN
 REGION_ENCODER = "encoder"      # the whole block stack
 REGION_ATTN = "attn"            # the token mixer: q/k/v/out projections +
-                                # the core, a convolution mixer (`conv`) or
-                                # a state-space mixer (`ssm`)
+                                # the core, a convolution mixer (`conv`), a
+                                # state-space mixer (`ssm`) or a delta-rule
+                                # mixer (`gdn`)
 REGION_ATTN_CORE = "core"       # scores -> softmax -> values, under attn
 REGION_FFN = "ffn"              # both products and the activation
 REGION_LN = "ln"                # residual add, dropout, LayerNorm
@@ -247,6 +248,10 @@ SCOPE_SSM = "ssm"               # attn: a Mamba-2 mixer, whole (in-projection,
 SCOPE_SSD = "ssd"               # attn/ssm: the state-space scan alone
                                 # (`ops/ssd.py`; `scan` is the block stack's
                                 # bookkeeping, `core` is attention's)
+SCOPE_GDN = "gdn"               # attn: a Gated DeltaNet mixer, whole (its
+                                # `proj` and `pointwise` as under `ssm`)
+SCOPE_DELTA = "delta"           # attn/gdn: the gated delta rule alone
+                                # (`ops/delta_rule.py`)
 # the second level: what a mixer or the expert layer does, pass by pass
 SCOPE_PROJ = "proj"             # attn, attn/conv, attn/ssm: the projections'
                                 # products and nothing else

@@ -89,6 +89,8 @@ _KNOWN_NAMES = frozenset({
     "fleet.ranks",
     "fleet.scrape_errors",
     "fleet.scrapes",
+    # ops/delta_rule.py (the gated delta rule's traces; labels pass, chunk)
+    "gdn.delta_calls",
     # io/prefetch.py
     "io.prefetch_batches",
     "io.prefetch_depth",
